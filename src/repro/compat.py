@@ -1,10 +1,9 @@
-"""Version-portable jax surface.
+"""The jax mesh and ``shard_map`` surface every call site in this repo uses.
 
-``shard_map`` has moved homes across jax releases: old versions export it
-from ``jax.experimental.shard_map`` (with ``auto=``/``check_rep=`` kwargs),
-new ones export ``jax.shard_map`` (with ``axis_names=``/``check_vma=``).
-Everything in this repo imports it from here so the same call sites —
-including partial-manual calls that name their manual axes — run on both.
+``make_mesh`` pins every axis to ``AxisType.Auto``. ``shard_map`` takes
+``axis_names`` as any iterable (``jax.shard_map`` wants a set) and passes
+``check_vma`` through only when the caller sets it, so partial-manual call
+sites name their manual axes the same way everywhere.
 """
 from __future__ import annotations
 
@@ -14,41 +13,17 @@ __all__ = ["make_mesh", "shard_map"]
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` with explicit Auto axis types where supported
-    (``AxisType`` only exists on newer jax; older versions are Auto-only)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            tuple(shape), tuple(axes),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """``jax.make_mesh`` with explicit Auto axis types."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
-if hasattr(jax, "shard_map"):
-
-    def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-                  check_vma=None):
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-
-else:
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-                  check_vma=None):
-        # axis_names is accepted but not narrowed: legacy partial-manual
-        # (``auto=``) lowers ``axis_index`` to a PartitionId op that SPMD
-        # partitioning rejects (UNIMPLEMENTED) on CPU. Full-manual is
-        # semantically equivalent for our call sites — bodies only reference
-        # their manual axes and in_specs name no others — at the cost of
-        # resharding at the region boundary.
-        del axis_names
-        kw = {}
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, **kw)
+def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
+              check_vma=None):
+    kw = {}
+    if axis_names is not None:
+        kw["axis_names"] = set(axis_names)
+    if check_vma is not None:
+        kw["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)

@@ -298,7 +298,7 @@ class RunConfig:
     force_unroll_segments: bool = False
     moment_dtype: str = "float32"  # float32 | bfloat16 (>=100B models)
     kv_cache_dtype: str = "bfloat16"  # bfloat16 | int8
-    attention_impl: str = "chunked"   # chunked | naive | pallas
+    attention_impl: str = "chunked"   # chunked | naive
     attn_q_block: int = 512
     attn_kv_block: int = 512
 
@@ -317,6 +317,14 @@ class RunConfig:
     keep_checkpoints: int = 3
     async_checkpoint: bool = True
     straggler_factor: float = 3.0
+
+    def __post_init__(self):
+        # models/attention.py implements exactly these; any other name
+        # would silently run the chunked path
+        if self.attention_impl not in ("chunked", "naive"):
+            raise ValueError(
+                f"attention_impl must be 'chunked' or 'naive', got "
+                f"{self.attention_impl!r}")
 
 
 def reduce_for_smoke(cfg: ModelConfig) -> ModelConfig:

@@ -31,7 +31,7 @@ batched operations:
 Numerics: facade state (buckets, telemetry banks) is numpy float64 — the
 per-op scalar paths are bit-compatible with the object backend, which is
 what the hypothesis equivalence suites pin. The fused tick runs jitted
-under ``jax.experimental.enable_x64`` so allocations agree with the scalar
+under ``jax.enable_x64(True)`` so allocations agree with the scalar
 ``max_min_fair`` within 1e-6 x capacity even at 100k tenants.
 """
 from __future__ import annotations
@@ -62,8 +62,8 @@ def _x64():
     """The x64 trace context: the fused tick must do float64 math even
     when the embedding app runs the default f32 config (model code and
     the Pallas kernels stay f32 — only the control plane opts in)."""
-    from jax.experimental import enable_x64
-    return enable_x64()
+    import jax
+    return jax.enable_x64(True)
 
 
 # ---------------------------------------------------------------------------

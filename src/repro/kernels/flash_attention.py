@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import pallas_call
+
 NEG_INF = -2.0e30
 
 
@@ -85,7 +87,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, scale=None,
-                    q_block=256, kv_block=256, interpret=True):
+                    q_block=256, kv_block=256):
     """q: (BH, S, d); k, v: (BH, T, d). Returns (BH, S, d)."""
     bh, s, d = q.shape
     t = k.shape[1]
@@ -100,7 +102,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, scale=None,
         k = jnp.pad(k, ((0, 0), (0, t_pad - t), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, t_pad - t), (0, 0)))
     grid = (bh, s_pad // q_block, t_pad // kv_block)
-    out = pl.pallas_call(
+    out = pallas_call(
         functools.partial(_flash_kernel, causal=causal, window=window,
                           scale=scale, kv_block=kv_block, kv_len=t),
         grid=grid,
@@ -116,6 +118,5 @@ def flash_attention(q, k, v, *, causal=True, window=0, scale=None,
             pltpu.VMEM((q_block,), jnp.float32),
             pltpu.VMEM((q_block, d), jnp.float32),
         ],
-        interpret=interpret,
     )(q, k, v)
     return out[:, :s]

@@ -1,9 +1,8 @@
 """jit'd public wrappers for the Pallas kernels (impl dispatch + layout).
 
-``interpret`` defaults to True so everything validates on CPU; on a real
-TPU deployment the flag flips to False via RunConfig.attention_impl
-plumbing — model code never changes (the NetKernel property, applied to
-kernels: the operator owns the implementation behind a stable call).
+``impl="pallas"`` runs the Mosaic kernel where the call lowers for a TPU
+and the Pallas interpreter where it lowers for the CPU
+(``repro.kernels.platform``); ``impl="ref"`` runs the pure-jnp oracle.
 """
 from __future__ import annotations
 
@@ -33,7 +32,7 @@ def mha_forward(q, k, v, *, causal=True, window=0, impl="pallas",
     kf = k.reshape(b * h, -1, d)
     vf = v.reshape(b * h, -1, d)
     o = _flash_pallas(qf, kf, vf, causal=causal, window=window,
-                      q_block=q_block, kv_block=kv_block, interpret=True)
+                      q_block=q_block, kv_block=kv_block)
     return o.reshape(b, h, s, d)
 
 
@@ -42,7 +41,7 @@ def decode_step_attention(q, k, v, pos, *, impl="pallas", kv_block=512):
     """q: (B,H,d); k,v: (B,T,H,d); pos: (B,). Returns (o, m, l)."""
     if impl == "ref":
         return ref.decode_attention_ref(q, k, v, pos)
-    return _decode_pallas(q, k, v, pos, kv_block=kv_block, interpret=True)
+    return _decode_pallas(q, k, v, pos, kv_block=kv_block)
 
 
 @functools.partial(jax.jit, static_argnames=("impl", "head_block"))
@@ -52,14 +51,14 @@ def ssd_intra_chunk(xdt, dA, B, C, *, impl="pallas", head_block=8):
         f = jax.vmap(jax.vmap(
             lambda x, a, b_, c_: ref.ssd_chunk_ref(x, a, b_, c_)))
         return f(xdt, dA, B, C)
-    return _ssd_pallas(xdt, dA, B, C, head_block=head_block, interpret=True)
+    return _ssd_pallas(xdt, dA, B, C, head_block=head_block)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "impl"))
 def quantize(x, *, block=256, impl="pallas"):
     if impl == "ref":
         return ref.quantize_int8_ref(x, block)
-    return _q_pallas(x, block=block, interpret=True)
+    return _q_pallas(x, block=block)
 
 
 @functools.partial(jax.jit, static_argnames=("impl", "iters"))
@@ -72,12 +71,11 @@ def water_fill(demands, weights, capacity, *, impl="pallas", iters=48):
     bisection kernel (no sort on the hot path)."""
     if impl == "ref":
         return ref.water_fill_ref(demands, weights, capacity)
-    return _wf_pallas(demands, weights, capacity, iters=iters,
-                      interpret=True)
+    return _wf_pallas(demands, weights, capacity, iters=iters)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "impl", "dtype"))
 def dequantize(q, scales, *, block=256, impl="pallas", dtype=jnp.float32):
     if impl == "ref":
         return ref.dequantize_int8_ref(q, scales, block, dtype)
-    return _dq_pallas(q, scales, block=block, dtype=dtype, interpret=True)
+    return _dq_pallas(q, scales, block=block, dtype=dtype)

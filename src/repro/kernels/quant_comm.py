@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import pallas_call
+
 
 def _quant_kernel(x_ref, q_ref, s_ref, *, block: int):
     x = x_ref[...].astype(jnp.float32)            # (rb, C)
@@ -34,8 +36,7 @@ def _dequant_kernel(q_ref, s_ref, o_ref, *, block: int):
     o_ref[...] = o.astype(o_ref.dtype)
 
 
-def quantize_int8(x, *, block: int = 256, rows_block: int = 256,
-                  interpret=True):
+def quantize_int8(x, *, block: int = 256, rows_block: int = 256):
     """x: (R, C) with C % block == 0 -> (q int8 (R,C), scales f32 (R, C/block))."""
     r, c = x.shape
     assert c % block == 0, (c, block)
@@ -43,7 +44,7 @@ def quantize_int8(x, *, block: int = 256, rows_block: int = 256,
     r_pad = -(-r // rb) * rb
     if r_pad != r:
         x = jnp.pad(x, ((0, r_pad - r), (0, 0)))
-    q, s = pl.pallas_call(
+    q, s = pallas_call(
         functools.partial(_quant_kernel, block=block),
         grid=(r_pad // rb,),
         in_specs=[pl.BlockSpec((rb, c), lambda i: (i, 0))],
@@ -51,26 +52,24 @@ def quantize_int8(x, *, block: int = 256, rows_block: int = 256,
                    pl.BlockSpec((rb, c // block), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((r_pad, c), jnp.int8),
                    jax.ShapeDtypeStruct((r_pad, c // block), jnp.float32)],
-        interpret=interpret,
     )(x)
     return q[:r], s[:r]
 
 
 def dequantize_int8(q, scales, *, block: int = 256, rows_block: int = 256,
-                    dtype=jnp.float32, interpret=True):
+                    dtype=jnp.float32):
     r, c = q.shape
     rb = min(rows_block, r)
     r_pad = -(-r // rb) * rb
     if r_pad != r:
         q = jnp.pad(q, ((0, r_pad - r), (0, 0)))
         scales = jnp.pad(scales, ((0, r_pad - r), (0, 0)))
-    o = pl.pallas_call(
+    o = pallas_call(
         functools.partial(_dequant_kernel, block=block),
         grid=(r_pad // rb,),
         in_specs=[pl.BlockSpec((rb, c), lambda i: (i, 0)),
                   pl.BlockSpec((rb, c // block), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((rb, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r_pad, c), dtype),
-        interpret=interpret,
     )(q, scales)
     return o[:r]

@@ -1,7 +1,7 @@
 """Pure-jnp oracles for every Pallas kernel (the correctness contract).
 
 Each ``*_ref`` mirrors its kernel's semantics exactly; tests sweep shapes and
-dtypes asserting allclose between kernel (interpret=True on CPU) and oracle.
+dtypes asserting allclose between kernel (interpreted on the CPU) and oracle.
 """
 from __future__ import annotations
 

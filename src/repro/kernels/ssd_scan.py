@@ -11,12 +11,11 @@ comfortably inside VMEM with MXU-aligned last dims.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import pallas_call
 
 
 def _ssd_chunk_kernel(xdt_ref, dA_ref, b_ref, c_ref, y_ref, st_ref, dec_ref):
@@ -44,7 +43,7 @@ def _ssd_chunk_kernel(xdt_ref, dA_ref, b_ref, c_ref, y_ref, st_ref, dec_ref):
     dec_ref[...] = jnp.exp(cs[-1])
 
 
-def ssd_chunk_scan(xdt, dA, B, C, *, head_block=8, interpret=True):
+def ssd_chunk_scan(xdt, dA, B, C, *, head_block=8):
     """Intra-chunk SSD over all chunks.
 
     xdt: (nb, nc, Q, H, P); dA: (nb, nc, Q, H); B, C: (nb, nc, Q, N).
@@ -59,7 +58,7 @@ def ssd_chunk_scan(xdt, dA, B, C, *, head_block=8, interpret=True):
     dA_f = dA.reshape(nb * nc, Q, H)
     B_f = B.reshape(nb * nc, Q, N)
     C_f = C.reshape(nb * nc, Q, N)
-    y, st, dec = pl.pallas_call(
+    y, st, dec = pallas_call(
         _ssd_chunk_kernel,
         grid=grid,
         in_specs=[
@@ -78,7 +77,6 @@ def ssd_chunk_scan(xdt, dA, B, C, *, head_block=8, interpret=True):
             jax.ShapeDtypeStruct((nb * nc, H, P, N), jnp.float32),
             jax.ShapeDtypeStruct((nb * nc, H), jnp.float32),
         ],
-        interpret=interpret,
     )(xdt_f, dA_f, B_f, C_f)
     return (y.reshape(nb, nc, Q, H, P), st.reshape(nb, nc, H, P, N),
             dec.reshape(nb, nc, H))

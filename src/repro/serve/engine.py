@@ -68,7 +68,8 @@ class ServeEngine(SchedulerServeModule):
         self.controller = controller
         self.control_every = max(int(control_every), 1)
         self.params = params if params is not None else init_params(
-            model_schema(cfg, mesh), key or jax.random.PRNGKey(0))
+            model_schema(cfg, mesh),
+            key if key is not None else jax.random.PRNGKey(0))
         self.slots = self._make_slots()
         self.caches = None
         self._cache_nbytes = 0

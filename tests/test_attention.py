@@ -80,3 +80,13 @@ def test_ring_buffer_decode_window():
     o_ring = decode_attention(q, kr, vr, pos, kv_map=kv_map, window=w,
                               kv_pos=kv_pos)
     np.testing.assert_allclose(o_ring, o_lin, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("impl", ["pallas", "flash", ""])
+def test_unimplemented_attention_impl_raises(impl):
+    """An impl name the model does not implement must not fall through to
+    the chunked path."""
+    from repro.configs import RunConfig
+    with pytest.raises(ValueError, match="attention_impl"):
+        RunConfig(attention_impl=impl)
+    assert RunConfig(attention_impl="naive").attention_impl == "naive"

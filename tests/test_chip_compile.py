@@ -1,0 +1,204 @@
+"""Compiles for a described TPU v5e: what the chip's compiler would refuse.
+
+Every program here is built for a ``v5e:2x2`` topology that is described,
+not attached: shapes only, nothing runs. The tests pin what the serve path,
+the control tick, the NSM stacks and the Pallas kernels need to lower for
+the chip at real widths: internlm2-1.8b's decode and prefill within one
+chip's 16 GiB, the fused tick at 100k tenants, every psum routing policy
+on a (pod=2, data=2) mesh, and each kernel as a Mosaic ``tpu_custom_call``.
+
+The topology is described inside a module fixture (never at import), and
+the tests skip from there where the TPU compiler is not installed. JAX's
+persistent compilation cache is off around these compiles: an entry
+written for a described chip cannot be read back without one.
+"""
+import numpy as np
+import pytest
+
+GIB = 1 << 30
+V5E_HBM = 16 * GIB
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to rehearse
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo, no_persistent_cache):
+    from jax.sharding import Mesh
+    return Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+
+
+@pytest.fixture(scope="module")
+def pod_mesh(topo, no_persistent_cache):
+    from jax.sharding import Mesh
+    return Mesh(np.array(topo.devices).reshape(2, 2, 1),
+                ("pod", "data", "model"))
+
+
+def _on(mesh, tree):
+    """ShapeDtypeStructs of ``tree``, replicated over ``mesh``."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    rep = NamedSharding(mesh, P())
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=rep), tree)
+
+
+@pytest.fixture(scope="module")
+def internlm2(one_chip):
+    from repro.configs import RunConfig, get_config
+    from repro.distribution.sharding import ShardingCtx, abstract_params
+    from repro.models.model import model_schema
+    cfg = get_config("internlm2-1.8b")
+    params = _on(one_chip, abstract_params(model_schema(cfg, one_chip)))
+    return cfg, RunConfig(), ShardingCtx(one_chip), params
+
+
+def _device_bytes(compiled):
+    mem = compiled.memory_analysis()
+    return mem.argument_size_in_bytes + mem.temp_size_in_bytes
+
+
+def test_internlm2_decode_fits_one_chip(internlm2, one_chip):
+    import jax
+    import jax.numpy as jnp
+    from repro.distribution.sharding import abstract_params
+    from repro.models.model import cache_schema, forward_decode
+    cfg, rcfg, shd, params = internlm2
+    slots, max_seq = 16, 2048
+    caches = _on(one_chip, abstract_params(cache_schema(cfg, slots, max_seq)))
+    tokens, pos = _on(one_chip, (
+        jax.ShapeDtypeStruct((slots, 1), jnp.int32),
+        jax.ShapeDtypeStruct((slots,), jnp.int32)))
+
+    def step(params, caches, tokens, pos):
+        logits, caches = forward_decode(params, caches, tokens, pos, cfg,
+                                        shd, rcfg)
+        return jnp.argmax(logits, axis=-1), caches
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, caches, tokens, pos).compile()
+    assert _device_bytes(compiled) < V5E_HBM
+
+
+def test_internlm2_prefill_fits_one_chip(internlm2, one_chip):
+    import jax
+    import jax.numpy as jnp
+    from repro.models.model import forward_prefill
+    cfg, rcfg, shd, params = internlm2
+    tokens = _on(one_chip, jax.ShapeDtypeStruct((1, 512), jnp.int32))
+    compiled = jax.jit(
+        lambda p, t: forward_prefill(p, t, cfg, shd, rcfg, max_seq=2048)
+    ).lower(params, tokens).compile()
+    assert _device_bytes(compiled) < V5E_HBM
+
+
+@pytest.mark.parametrize("dtype", ["float32", "float64"])
+def test_fused_control_tick_100k_tenants(one_chip, dtype):
+    import jax
+    import jax.numpy as jnp
+    from repro.control.vectorized import _fused_tick_jitted
+    n = 100_000
+    with jax.enable_x64(dtype == "float64"):
+        f = jnp.dtype(dtype)
+        vec = jax.ShapeDtypeStruct((n,), f)
+        args = [vec] * 9 + [jax.ShapeDtypeStruct((n,), jnp.bool_),
+                            jax.ShapeDtypeStruct((3, n), f),
+                            jax.ShapeDtypeStruct((7,), f)]
+        compiled = _fused_tick_jitted().lower(
+            *_on(one_chip, args), iters=48, scheduler_buckets=True).compile()
+    assert _device_bytes(compiled) < V5E_HBM
+
+
+@pytest.mark.parametrize("policy", ["xla", "ring", "hierarchical",
+                                    "compressed", "shm-first"])
+def test_nsm_psum_policies_on_pod_mesh(pod_mesh, policy):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.compat import shard_map
+    from repro.core import make_engine, nk_psum, use_engine
+    axes = ("pod", "data")
+    spec = P(axes, None)
+    # 2 MiB per shard: above the ring policy's 1 MiB threshold
+    x = jax.ShapeDtypeStruct((4096, 512), jnp.float32,
+                             sharding=NamedSharding(pod_mesh, spec))
+    eng = make_engine(pod_mesh, policy)
+
+    def body(v):
+        with use_engine(eng):
+            return nk_psum(v, axes, gradient=True)
+    hlo = jax.jit(shard_map(body, mesh=pod_mesh, in_specs=spec,
+                            out_specs=spec)).lower(x).compile().as_text()
+    if policy == "ring":
+        assert "collective-permute" in hlo
+    else:
+        assert "all-reduce" in hlo
+    assert eng.total_bytes() > 0
+
+
+def _compile_kernel(one_chip, fn, *shapes):
+    import jax
+    from jax.sharding import SingleDeviceSharding
+    dev = SingleDeviceSharding(one_chip.devices.flat[0])
+    args = [jax.ShapeDtypeStruct(s, d, sharding=dev) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def test_flash_attention_kernel(one_chip):
+    import jax.numpy as jnp
+    from repro.kernels.flash_attention import flash_attention
+    qkv = ((16, 2048, 128), jnp.bfloat16)
+    hlo = _compile_kernel(one_chip, flash_attention, qkv, qkv, qkv)
+    assert "tpu_custom_call" in hlo
+
+
+def test_decode_attention_kernel(one_chip):
+    import jax.numpy as jnp
+    from repro.kernels.decode_attention import decode_attention
+    kv = ((8, 2048, 16, 128), jnp.bfloat16)
+    hlo = _compile_kernel(one_chip, decode_attention,
+                          ((8, 16, 128), jnp.bfloat16), kv, kv,
+                          ((8,), jnp.int32))
+    assert "tpu_custom_call" in hlo
+
+
+def test_int8_codec_kernels(one_chip):
+    import jax.numpy as jnp
+    from repro.kernels.quant_comm import dequantize_int8, quantize_int8
+    hlo = _compile_kernel(one_chip, quantize_int8,
+                          ((4096, 4096), jnp.bfloat16))
+    assert "tpu_custom_call" in hlo
+    hlo = _compile_kernel(one_chip, dequantize_int8,
+                          ((4096, 4096), jnp.int8), ((4096, 16), jnp.float32))
+    assert "tpu_custom_call" in hlo
+
+
+def test_water_fill_kernel_f32(one_chip):
+    import jax.numpy as jnp
+    from repro.kernels.waterfill import water_fill_pallas
+    vec = ((100_000,), jnp.float32)
+    hlo = _compile_kernel(one_chip,
+                          lambda d, w: water_fill_pallas(d, w, 1000.0),
+                          vec, vec)
+    assert "tpu_custom_call" in hlo

@@ -1,5 +1,5 @@
-"""Per-kernel validation vs the pure-jnp oracles (interpret=True on CPU),
-with shape/dtype sweeps and hypothesis property checks."""
+"""Per-kernel validation vs the pure-jnp oracles (the Pallas interpreter on
+the CPU), with shape/dtype sweeps and hypothesis property checks."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -90,9 +90,13 @@ def test_quantization_error_bound(r, cb, scale):
     c = cb * 128
     x = jax.random.normal(jax.random.PRNGKey(r), (r, c), jnp.float32) * scale
     q8, s = ops.quantize(x, block=128, impl="pallas")
-    xr = ops.dequantize(q8, s, block=128)
-    err = np.abs(np.asarray(xr) - np.asarray(x))
-    bound = np.repeat(np.asarray(s), 128, axis=1) * 0.5 + 1e-6
+    xr = np.asarray(ops.dequantize(q8, s, block=128))
+    x = np.asarray(x)
+    err = np.abs(xr - x)
+    # the f32 roundings of x / scale and q * scale add half an ulp each, of
+    # |x| and |xr|: a fixed 1e-6 is below one ulp once |x| passes 16
+    bound = (np.repeat(np.asarray(s), 128, axis=1) * 0.5
+             + np.spacing(np.maximum(np.abs(x), np.abs(xr))))
     assert (err <= bound).all()
 
 
@@ -102,3 +106,14 @@ def test_quantize_pallas_matches_ref():
     q8r, sr = ops.quantize(x, block=128, impl="ref")
     np.testing.assert_array_equal(np.asarray(q8), np.asarray(q8r))
     np.testing.assert_allclose(s, sr, rtol=1e-6)
+
+
+def test_water_fill_kernel_refuses_f64_on_tpu(monkeypatch):
+    """The TPU water-fill kernel is 32-bit only: a float64 call there
+    raises instead of running at another precision."""
+    from repro.kernels.waterfill import water_fill_pallas
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with jax.enable_x64(True):
+        d = jnp.ones((8,), jnp.float64)
+        with pytest.raises(TypeError, match="32-bit only"):
+            water_fill_pallas(d, d, 4.0)
