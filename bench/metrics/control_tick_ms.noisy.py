@@ -1,0 +1,5 @@
+"""Host milliseconds per RateController.tick, in the saturated cell: a tick
+holds up the step that runs it, so it moves the throughput there. The
+same reading as control_tick_ms, which moves the inter-token tail below
+the knee."""
+from bench.metrics.control_tick_ms import read  # noqa: F401
