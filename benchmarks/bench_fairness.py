@@ -666,12 +666,15 @@ def run_tracer_overhead(intervals: int = SMOKE_INTERVALS) -> Dict:
 
     Every instrumentation site is guarded by ``if tracing.TRACER.enabled``
     against a null-object tracer, so the disabled path is one module-attr
-    load and a branch. This bench measures that guard directly (micro
-    loop), counts how many trace points a real replayed decode step
-    actually hits (enabled run over the steady scenario), and bounds the
+    load and a branch; every region site (``with TRACER.region(...)``) is
+    an attribute load, a call returning one shared no-op context, and its
+    enter/exit. This bench measures both directly (micro loops), counts
+    how many trace points and region sites a real replayed decode step
+    actually hits (over the steady scenario), and bounds the
     disabled-path overhead as a fraction of the measured mean step time:
 
-        disabled_step_overhead_frac = guard_ns * events_per_step
+        disabled_step_overhead_frac = (guard_ns * events_per_step
+                                       + region_ns * regions_per_step)
                                       / mean_step_ns
 
     Gated at < 2% in bench_thresholds.json — the machine-independent form
@@ -698,6 +701,11 @@ def run_tracer_overhead(intervals: int = SMOKE_INTERVALS) -> Dict:
             hits += 1
     guard_ns = (time.perf_counter() - t0) / n * 1e9
     assert hits == 0
+    t0 = time.perf_counter()
+    for _ in range(n):
+        with tracing.TRACER.region("engine", "step"):
+            pass
+    region_ns = (time.perf_counter() - t0) / n * 1e9
 
     # 2. mean step time on the real datapath, tracer disabled. First run
     # warms the jit caches; the second, on a fresh engine with identical
@@ -720,17 +728,34 @@ def run_tracer_overhead(intervals: int = SMOKE_INTERVALS) -> Dict:
         _e2e_report(trace, cap)
     events_per_step = len(tr.events) / steps
 
-    frac = guard_ns * 1e-9 * events_per_step / mean_step_s
+    # 4. region sites per step, counted by a null tracer that tallies them
+    class _RegionCount(tracing.NullTracer):
+        n = 0
+
+        def region(self, track, name):
+            self.n += 1
+            return super().region(track, name)
+
+    with trace_to(_RegionCount()) as rc:
+        _e2e_report(trace, cap)
+    regions_per_step = rc.n / steps
+
+    frac = (guard_ns * events_per_step
+            + region_ns * regions_per_step) * 1e-9 / mean_step_s
     rows = [("tracer_overhead,disabled_guard_ns", guard_ns),
             ("tracer_overhead,events_per_step", events_per_step),
+            ("tracer_overhead,disabled_region_ns", region_ns),
+            ("tracer_overhead,regions_per_step", regions_per_step),
             ("tracer_overhead,mean_step_us", mean_step_s * 1e6),
             ("tracer_overhead,tokens_per_s_wall", tokens_per_s_wall),
             ("tracer_overhead,disabled_step_overhead_frac", frac)]
     return {"rows": rows, "ok": frac < 0.02,
             "claim": f"disabled-path guard {guard_ns:.0f}ns x "
-                     f"{events_per_step:.1f} trace points/step = "
-                     f"{frac:.5%} of the {mean_step_s * 1e6:.0f}us mean "
-                     f"step (< 2%): tracing off is free"}
+                     f"{events_per_step:.1f} trace points/step + no-op "
+                     f"region {region_ns:.0f}ns x {regions_per_step:.1f} "
+                     f"sites/step = {frac:.5%} of the "
+                     f"{mean_step_s * 1e6:.0f}us mean step (< 2%): "
+                     f"tracing off is free"}
 
 
 # ---------------------------------------------------------------------------

@@ -180,29 +180,30 @@ class RateController:
         ``now``: seconds (virtual or wall clock; defaults to wall clock).
         Returns the global per-tenant allocations in units/s ({} until the
         first interval with a usable rate signal)."""
-        t0 = time.perf_counter()
-        now = time.monotonic() if now is None else now
-        merged = self.observe(now)
-        self.tick_calls += 1
-        self.last_tenants = len(merged)
-        if not merged or not any(o.offered > 0 or o.queue > 0
-                                 for o in merged.values()):
-            # no rate signal yet (first tick only baselines the counters):
-            # pushing allocations computed from zeros would stall everyone
+        with tracing.TRACER.region("control", "tick"):
+            t0 = time.perf_counter()
+            now = time.monotonic() if now is None else now
+            merged = self.observe(now)
+            self.tick_calls += 1
+            self.last_tenants = len(merged)
+            if not merged or not any(o.offered > 0 or o.queue > 0
+                                     for o in merged.values()):
+                # no rate signal yet (first tick only baselines the counters):
+                # pushing allocations computed from zeros would stall everyone
+                self.tick_seconds_total += time.perf_counter() - t0
+                return {}
+            self.allocations = self.algo.allocate(merged, self.capacity)
+            calls_before = self.push_calls
+            self._push(now)
+            if tracing.TRACER.enabled:
+                tracing.TRACER.instant(
+                    "controller", "rate.push", now,
+                    tenants=len(self.allocations),
+                    calls=self.push_calls - calls_before)
+            self.history.append(dict(self.allocations))
+            self.ticks += 1
             self.tick_seconds_total += time.perf_counter() - t0
-            return {}
-        self.allocations = self.algo.allocate(merged, self.capacity)
-        calls_before = self.push_calls
-        self._push(now)
-        if tracing.TRACER.enabled:
-            tracing.TRACER.instant(
-                "controller", "rate.push", now,
-                tenants=len(self.allocations),
-                calls=self.push_calls - calls_before)
-        self.history.append(dict(self.allocations))
-        self.ticks += 1
-        self.tick_seconds_total += time.perf_counter() - t0
-        return self.allocations
+            return self.allocations
 
     def _changed(self, kind: str, idx: int, tenant: int, rate: float) -> bool:
         """Delta gate: has this (enforcement point, tenant) target moved

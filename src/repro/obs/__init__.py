@@ -6,8 +6,8 @@ from repro.obs.metrics import (METRIC_HELP, MetricsRegistry,
                                escape_label_value, format_value,
                                parse_prometheus_text, parse_series_key,
                                render_prometheus, render_series)
-from repro.obs.tracing import (TRACER, NullTracer, Tracer, get_tracer,
-                               set_tracer, trace_to)
+from repro.obs.tracing import (TRACER, NullTracer, ProfilerTracer, Tracer,
+                               get_tracer, set_tracer, trace_to)
 from repro.obs.timeseries import SeriesStore, series_key
 from repro.obs.slo import (Alert, AlertEngine, AlertRule, AbsenceRule,
                            AdmitWaitSloRule, BurnRateRule,
@@ -21,7 +21,8 @@ __all__ = [
     "METRIC_HELP", "MetricsRegistry", "escape_label_value", "format_value",
     "parse_prometheus_text", "parse_series_key", "render_prometheus",
     "render_series",
-    "TRACER", "NullTracer", "Tracer", "get_tracer", "set_tracer",
+    "TRACER", "NullTracer", "ProfilerTracer", "Tracer", "get_tracer",
+    "set_tracer",
     "trace_to",
     "SeriesStore", "series_key",
     "Alert", "AlertEngine", "AlertRule", "AbsenceRule", "AdmitWaitSloRule",

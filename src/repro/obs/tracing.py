@@ -20,18 +20,34 @@ each becomes a tid with an "M" thread_name metadata record. Timestamps
 are seconds — the replay's virtual clock or ``time.monotonic()`` — and
 export as integer microseconds, so a whole scenario browses as a real
 timeline. Stdlib only.
+
+Regions are the served path's host work on the profiler's clock:
+``with TRACER.region(track, name):`` wraps one call (a pick, a prefill
+dispatch, a read-back) and is a shared no-op context on every tracer but
+``ProfilerTracer``, which opens a ``jax.profiler.TraceAnnotation`` named
+``nk.<track>.<name>`` — lined up with the device's ops in a profile.
+Regions never enter the Chrome JSON, whose clock may be virtual.
 """
 from __future__ import annotations
 
 import json
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Dict, List, Optional
+
+# the one context every disabled region returns: no allocation per call
+_NO_REGION = nullcontext()
 
 
 class NullTracer:
-    """The disabled tracer: every hook is an attribute call + pass."""
+    """The disabled tracer: every hook is an attribute call + pass.
+
+    ``enabled`` says whether span/instant/async events are recorded;
+    regions are independent of it."""
 
     enabled = False
+
+    def region(self, track, name):
+        return _NO_REGION
 
     def span(self, track, name, start, end, **args) -> None:
         pass
@@ -113,6 +129,18 @@ class Tracer(NullTracer):
     def counters(self) -> Dict[str, float]:
         return {"nk_trace_events_total": float(
             sum(1 for e in self.events if e["ph"] != "M"))}
+
+
+class ProfilerTracer(NullTracer):
+    """Regions as profiler annotations (``nk.<track>.<name>``), for a
+    ``jax.profiler`` capture; span/instant/async events stay no-ops."""
+
+    def __init__(self):
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
+
+    def region(self, track, name):
+        return self._annotation(f"nk.{track}.{name}")
 
 
 TRACER: NullTracer = NullTracer()

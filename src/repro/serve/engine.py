@@ -24,6 +24,7 @@ from repro.fabric import SchedulerServeModule
 from repro.models.model import (
     cache_schema, forward_decode, forward_prefill, model_schema,
 )
+from repro.obs import tracing
 from repro.serve.scheduler import Request, TenantScheduler
 
 
@@ -42,7 +43,7 @@ class ServeEngine(SchedulerServeModule):
     via ``SchedulerServeModule``: tenant export/import delegate to the
     scheduler, ``billed_ground_truth`` reads completed requests + live
     slots, and ``suspend``/``resume`` make parking a real memory saving —
-    suspend drops the KV-cache, slot table and step scratch; resume
+    suspend drops the KV-cache and the slot table; resume
     re-materializes the cache lazily from the shared ``cache_schema`` on
     the first admission after unpark.
     """
@@ -77,7 +78,6 @@ class ServeEngine(SchedulerServeModule):
         self.steps = 0
         self.decode_steps = 0
         self.completed: List[Request] = []
-        self.step_times: List[float] = []
 
         cfg_, rcfg_, shd_ = cfg, rcfg, self.shd
 
@@ -116,7 +116,6 @@ class ServeEngine(SchedulerServeModule):
 
     def _release_buffers(self) -> None:
         self.caches = None
-        self.step_times = []
 
     # ------------------------------------------------------------------
     def submit(self, req: Request):
@@ -130,83 +129,100 @@ class ServeEngine(SchedulerServeModule):
         return None
 
     def _admit(self, now=None):
-        while True:
-            i = self._free_slot()
-            if i is None:
-                return
-            req = self.scheduler.next_request(now)
-            if req is None:
-                return
-            if self.caches is None:
-                # lazy resume: the KV-cache dropped at park re-materializes
-                # only when a request actually lands here
-                self._init_caches()
-            prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
-            last_logits, caches1 = self._prefill(self.params, prompt)
-            # install the single-sequence cache into slot i
-            self.caches = jax.tree.map(
-                lambda big, one: big.at[:, i].set(one[:, 0].astype(big.dtype)),
-                self.caches, caches1)
-            first = int(jnp.argmax(last_logits[0]))
-            req.generated.append(first)
-            req.admit_time = time.monotonic() if now is None else now
-            self.observe_admitted(req)
-            # prompt tokens + the first generated token: prefill produced
-            # both, so the ledger must bill them here — decode steps only
-            # account the tokens they themselves produce (leaving the
-            # prefill token out undercounts every request by one and caps
-            # measured throughput below the enforced allocation)
-            self.scheduler.account(req.tenant_id, len(req.prompt) + 1)
-            if req.max_new_tokens <= 1:
-                # prefill already produced the only requested token; a slot
-                # would run one decode step anyway and over-generate (and
-                # over-bill) past the bucket's prompt+max_new price
-                req.finish_time = req.admit_time
-                self.completed.append(req)
-                self.observe_finished(req)
-                continue
-            self.slots[i] = Slot(active=True, req=req,
-                                 pos=len(req.prompt),
-                                 remaining=req.max_new_tokens - 1)
+        region = tracing.TRACER.region
+        with region("engine", "admit"):
+            while True:
+                i = self._free_slot()
+                if i is None:
+                    return
+                req = self.scheduler.next_request(now)
+                if req is None:
+                    return
+                if self.caches is None:
+                    # lazy resume: the KV-cache dropped at park
+                    # re-materializes only when a request actually lands
+                    self._init_caches()
+                prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
+                with region("engine", "prefill"):
+                    last_logits, caches1 = self._prefill(self.params, prompt)
+                # install the single-sequence cache into slot i
+                with region("engine", "install"):
+                    self.caches = jax.tree.map(
+                        lambda big, one: big.at[:, i].set(
+                            one[:, 0].astype(big.dtype)),
+                        self.caches, caches1)
+                with region("engine", "first_token"):
+                    first = int(jnp.argmax(last_logits[0]))
+                req.generated.append(first)
+                req.admit_time = time.monotonic() if now is None else now
+                self.observe_admitted(req)
+                # prompt tokens + the first generated token: prefill
+                # produced both, so the ledger must bill them here — decode
+                # steps only account the tokens they themselves produce
+                # (leaving the prefill token out undercounts every request
+                # by one and caps measured throughput below the enforced
+                # allocation)
+                self.scheduler.account(req.tenant_id, len(req.prompt) + 1)
+                if req.max_new_tokens <= 1:
+                    # prefill already produced the only requested token; a
+                    # slot would run one decode step anyway and
+                    # over-generate (and over-bill) past the bucket's
+                    # prompt+max_new price
+                    req.finish_time = req.admit_time
+                    self.completed.append(req)
+                    self.observe_finished(req)
+                    continue
+                self.slots[i] = Slot(active=True, req=req,
+                                     pos=len(req.prompt),
+                                     remaining=req.max_new_tokens - 1)
 
     def step(self, now=None) -> int:
         """Admit + one decode step for all active slots. Returns #active."""
         if self.suspended:
             raise RuntimeError(
                 "engine is suspended (parked); resume() before stepping")
-        t0 = time.monotonic()
-        self.steps += 1
-        # tick before admission (and before the no-work early return): a
-        # fully-throttled engine must still get rate updates or it livelocks
-        if self.controller is not None and self.steps % self.control_every == 0:
-            self.controller.tick(time.monotonic() if now is None else now)
-        self._admit(now)
-        active = [i for i, s in enumerate(self.slots) if s.active]
-        if not active:
-            return 0
-        tokens = np.zeros((self.B, 1), np.int32)
-        pos = np.zeros((self.B,), np.int32)
-        for i, s in enumerate(self.slots):
-            if s.active:
-                tokens[i, 0] = s.req.generated[-1]
-                pos[i] = s.pos
-        nxt, self.caches = self._decode(self.params, self.caches,
-                                        jnp.asarray(tokens), jnp.asarray(pos))
-        nxt = np.asarray(nxt)
-        for i in active:
-            s = self.slots[i]
-            s.req.generated.append(int(nxt[i]))
-            s.pos += 1
-            s.remaining -= 1
-            self.scheduler.account(s.req.tenant_id, 1)
-            if s.remaining <= 0 or s.pos >= self.max_seq - 1:
-                s.req.finish_time = time.monotonic() if now is None else now
-                self.completed.append(s.req)
-                self.observe_finished(s.req)
-                self.slots[i] = Slot()
-        self.decode_steps += 1
-        self.step_times.append(time.monotonic() - t0)
-        return len(active)
+        region = tracing.TRACER.region
+        with region("engine", "step"):
+            self.steps += 1
+            # tick before admission (and before the no-work early return):
+            # a fully-throttled engine must still get rate updates or it
+            # livelocks
+            if self.controller is not None and \
+                    self.steps % self.control_every == 0:
+                self.controller.tick(time.monotonic() if now is None
+                                     else now)
+            self._admit(now)
+            active = [i for i, s in enumerate(self.slots) if s.active]
+            if not active:
+                return 0
+            with region("engine", "prepare"):
+                tokens = np.zeros((self.B, 1), np.int32)
+                pos = np.zeros((self.B,), np.int32)
+                for i, s in enumerate(self.slots):
+                    if s.active:
+                        tokens[i, 0] = s.req.generated[-1]
+                        pos[i] = s.pos
+                tokens, pos = jnp.asarray(tokens), jnp.asarray(pos)
+            with region("engine", "decode"):
+                nxt, self.caches = self._decode(self.params, self.caches,
+                                                tokens, pos)
+            with region("engine", "readback"):
+                nxt = np.asarray(nxt)
+            with region("engine", "commit"):
+                for i in active:
+                    s = self.slots[i]
+                    s.req.generated.append(int(nxt[i]))
+                    s.pos += 1
+                    s.remaining -= 1
+                    self.scheduler.account(s.req.tenant_id, 1)
+                    if s.remaining <= 0 or s.pos >= self.max_seq - 1:
+                        s.req.finish_time = time.monotonic() if now is None \
+                            else now
+                        self.completed.append(s.req)
+                        self.observe_finished(s.req)
+                        self.slots[i] = Slot()
+            self.decode_steps += 1
+            return len(active)
 
     def run_until_drained(self, max_steps: int = 10000) -> Dict:
         n = 0
@@ -217,12 +233,3 @@ class ServeEngine(SchedulerServeModule):
         return {"decode_steps": self.decode_steps,
                 "completed": len(self.completed),
                 "shares": self.scheduler.shares()}
-
-    # -- utilization metrics ------------------------------------------------
-    def slot_utilization(self) -> float:
-        """Fraction of slot-steps that produced a token (1.0 = no idle
-        slots across the run)."""
-        if not self.decode_steps:
-            return 0.0
-        served = sum(len(r.generated) for r in self.completed)
-        return served / max(self.decode_steps * self.B, 1)

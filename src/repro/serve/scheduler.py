@@ -12,6 +12,7 @@ Implements the paper's isolation/fairness mechanisms at the request level:
 from __future__ import annotations
 
 import itertools
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Deque, Dict, List, Optional
@@ -62,7 +63,7 @@ class TenantScheduler:
         self.served_tokens: Dict[int, int] = {}
         # admission ledger (what the replay harness reads): requests admitted,
         # polls where a queued tenant was blocked by its bucket, and the
-        # summed arrival->admission wait (needs ``now`` passed through)
+        # summed arrival->admission wait (of requests with an ``arrival``)
         self.admitted_requests: Dict[int, int] = {}
         self.deferred_polls: Dict[int, int] = {}
         self.admit_wait_sum: Dict[int, float] = {}
@@ -422,22 +423,23 @@ class TenantScheduler:
     def next_request(self, now: Optional[float] = None) -> Optional[Request]:
         """Pick the next request to admit (or None; always None while
         ``paused`` — the hot-swap quiesce window)."""
-        if self.paused:
-            return None
-        cands = [t for t in self.queues if self._admissible(t, now)]
-        if not cands:
-            return None
-        if self.policy == "rr":
-            # rotate round-robin order
-            for _ in range(len(self._rr_order)):
-                t = self._rr_order.pop(0)
-                self._rr_order.append(t)
-                if t in cands:
-                    return self._take(t, now)
-            return None
-        # WFQ: smallest virtual time wins; vtime advances by served work
-        t = min(cands, key=lambda q: (self.vtime[q], q))
-        return self._take(t, now)
+        with tracing.TRACER.region("scheduler", "pick"):
+            if self.paused:
+                return None
+            cands = [t for t in self.queues if self._admissible(t, now)]
+            if not cands:
+                return None
+            if self.policy == "rr":
+                # rotate round-robin order
+                for _ in range(len(self._rr_order)):
+                    t = self._rr_order.pop(0)
+                    self._rr_order.append(t)
+                    if t in cands:
+                        return self._take(t, now)
+                return None
+            # WFQ: smallest virtual time wins; vtime advances by served work
+            t = min(cands, key=lambda q: (self.vtime[q], q))
+            return self._take(t, now)
 
     def _cost(self, req: Request) -> int:
         return req.max_new_tokens + \
@@ -449,14 +451,17 @@ class TenantScheduler:
         if b is not None:
             b.consume(self._cost(req), now)
         self.admitted_requests[t] = self.admitted_requests.get(t, 0) + 1
-        if now is not None and req.arrival >= 0.0:
-            wait = max(now - req.arrival, 0.0)
+        if req.arrival >= 0.0:
+            # served on the wall clock (no ``now``), the wait is measured
+            # on the clock the engine stamps ``admit_time`` with
+            at = time.monotonic() if now is None else now
+            wait = max(at - req.arrival, 0.0)
             self.admit_wait_sum[t] = \
                 self.admit_wait_sum.get(t, 0.0) + wait
             self.admit_wait_hist.observe(t, wait)
             if tracing.TRACER.enabled:
                 tracing.TRACER.instant(self.trace_track, "request.admit",
-                                       now, tenant=t, req=req.req_id,
+                                       at, tenant=t, req=req.req_id,
                                        wait_s=round(wait, 6))
         return req
 

@@ -320,6 +320,51 @@ def test_null_tracer_is_default_and_inert():
     assert tracing.TRACER.async_end("t", "x", 1, 1.0) is None
 
 
+def test_null_region_is_one_shared_no_op():
+    null = NullTracer()
+    r = null.region("engine", "step")
+    assert r is null.region("scheduler", "pick") is tracing.TRACER.region(
+        "engine", "readback")
+    with r as entered:
+        with r:                            # reusable and re-entrant
+            assert entered is None
+
+
+def test_regions_stay_out_of_the_recorded_json():
+    with trace_to() as tr:
+        with tracing.TRACER.region("engine", "step"):
+            tr.instant("engine", "request.admit", 1.0)
+    assert [e["name"] for e in tr.events if e["ph"] != "M"] == \
+        ["request.admit"]
+
+
+def test_profiler_tracer_regions_are_named_annotations():
+    import jax
+    pt = tracing.ProfilerTracer()
+    assert not pt.enabled                  # span/instant stay no-ops
+    assert pt.instant("t", "x", 0.0) is None
+    assert pt.span("t", "x", 0.0, 1.0) is None
+    r = pt.region("engine", "readback")
+    assert isinstance(r, jax.profiler.TraceAnnotation)
+    with r:
+        pass
+
+
+def test_admit_wait_recorded_on_the_wall_clock_path():
+    """Served with no ``now`` (the wall-clock path), the pick still
+    measures each request's wait, on time.monotonic()."""
+    import time
+    from repro.serve.scheduler import TenantScheduler
+    s = TenantScheduler()
+    s.submit(Request(0, [1, 2], 4, req_id=0,
+                     arrival=time.monotonic() - 0.25))
+    s.submit(Request(0, [1, 2], 4, req_id=1))        # arrival unknown
+    assert s.next_request().req_id == 0
+    assert s.next_request().req_id == 1
+    assert s.admit_wait_hist.get(0).total == 1
+    assert 0.25 <= s.admit_wait_sum[0] < 60.0
+
+
 def test_trace_to_swaps_and_restores_the_global():
     before = tracing.TRACER
     with trace_to() as tr:
