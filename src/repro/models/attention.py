@@ -255,6 +255,32 @@ def decode_attention(q, k_cache, v_cache, pos, *, kv_map, window=0,
     return o[:, None].reshape(b, 1, hq, hd)
 
 
+def write_decode_rows(cache, rows, slot, layer=None):
+    """Write one row per batch slot: ``cache[b, slot[b]] = rows[b]``.
+
+    ``layer`` None: ``cache`` is one layer's (B,S,...) buffer, rewritten
+    with a one-hot masked select, NOT a scatter: scattering at a traced
+    per-row index on the model-sharded seq dim makes the partitioner
+    all-gather the whole cache every step (measured 8.3 GB/chip on
+    chameleon decode_32k — EXPERIMENTS §Perf). The select is elementwise
+    and stays context-parallel.
+
+    ``layer`` given: ``cache`` is a segment's stacked (L,B,S,...) buffer
+    carried through the decode scan, and the rows are scattered into it at
+    ``(layer, b, slot[b])``, in place. Only for a cache whose seq dim is not
+    sharded (``models.model.decode_writes_in_place``); where the batch dim
+    is sharded, the partitioner gathers the B rows and their indices, not
+    the cache.
+    """
+    rows = rows.astype(cache.dtype)
+    if layer is None:
+        b, s = cache.shape[:2]
+        wmask = (jnp.arange(s)[None, :] == slot[:, None]).reshape(
+            (b, s) + (1,) * (cache.ndim - 2))
+        return jnp.where(wmask, rows[:, None], cache)
+    return cache.at[layer, jnp.arange(cache.shape[1]), slot].set(rows)
+
+
 # ---------------------------------------------------------------------------
 # Full GQA attention block (projections + core + out-proj)
 # ---------------------------------------------------------------------------
@@ -319,11 +345,15 @@ def decode_attention_cp(q, k_c, v_c, pos, *, kv_map, window, n_real_heads,
 def gqa_attention(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg, *,
                   positions, kv_x=None, causal=True, window=0,
                   cache: Optional[Dict] = None, decode_pos=None,
-                  return_cache=False, cross_decode=False):
+                  cache_layer=None, return_cache=False, cross_decode=False):
     """Unified GQA attention.
 
     Training/prefill: ``positions`` is (S,) or (B,S); returns (out[, cache]).
-    Decode: pass ``cache`` + ``decode_pos`` (B,); x is (B,1,D).
+    Decode: pass ``cache`` + ``decode_pos`` (B,); x is (B,1,D). With
+    ``cache_layer``, ``cache`` holds the segment's stacked (L,B,S,KV,hd)
+    k/v and this layer is index ``cache_layer``: the new rows are written
+    into the stack in place, attention reads the layer back from it, and
+    the returned cache is the stack.
     Cross-attention: ``kv_x`` is the encoder output (prefill/train);
     ``cross_decode`` reads the cached encoder k/v without updating.
     """
@@ -384,7 +414,7 @@ def gqa_attention(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg, *,
         cos, sin = rope_tables(decode_pos[:, None], hd, cfg.rope_theta)
         q = apply_rope(q, cos, sin)
         knew = apply_rope(knew, cos, sin)
-    n_slots = cache["k"].shape[1]
+    n_slots = cache["k"].shape[-3]
     ring = bool(window) and n_slots <= window       # ring-buffer window cache
     if ring:
         slot = decode_pos % n_slots
@@ -394,16 +424,12 @@ def gqa_attention(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg, *,
     else:
         slot = decode_pos
         kv_pos = None
-    # one-hot masked update, NOT a scatter: scattering at a traced per-row
-    # index on the model-sharded seq dim makes the partitioner all-gather
-    # the whole cache every step (measured 8.3 GB/chip on chameleon
-    # decode_32k — EXPERIMENTS §Perf). The masked select is elementwise and
-    # stays context-parallel.
-    wmask = (jnp.arange(n_slots)[None, :] == slot[:, None])[..., None, None]
-    k_c = jnp.where(wmask, knew[:, 0][:, None].astype(cache["k"].dtype),
-                    cache["k"])
-    v_c = jnp.where(wmask, vnew[:, 0][:, None].astype(cache["v"].dtype),
-                    cache["v"])
+    new_cache = {name: write_decode_rows(cache[name], rows[:, 0], slot,
+                                         cache_layer)
+                 for name, rows in (("k", knew), ("v", vnew))}
+    k_c, v_c = new_cache["k"], new_cache["v"]
+    if cache_layer is not None:
+        k_c, v_c = k_c[cache_layer], v_c[cache_layer]
     if not ring:
         # linear cache: context-parallel flash-decode over the model axis
         o = decode_attention_cp(q, k_c.astype(x.dtype), v_c.astype(x.dtype),
@@ -415,7 +441,7 @@ def gqa_attention(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg, *,
                              kv_pos=kv_pos, n_real_heads=h)
     o = o * mask[None, None, :, None]
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
-    return out, {"k": k_c, "v": v_c}
+    return out, new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -476,10 +502,7 @@ def mla_attention(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg, *,
     k_pe = apply_rope(k_pe_new[:, :, None, :], cos, sin)[:, :, 0, :]
     new_lat = jnp.concatenate([c_kv_new[:, 0], k_pe[:, 0]], -1)
     # masked update (not scatter) — keeps the latent cache context-parallel
-    wmask = (jnp.arange(cache["lat"].shape[1])[None, :]
-             == decode_pos[:, None])[..., None]
-    lat = jnp.where(wmask, new_lat[:, None].astype(cache["lat"].dtype),
-                    cache["lat"])
+    lat = write_decode_rows(cache["lat"], new_lat, decode_pos)
     latx = lat.astype(x.dtype)
     c_c, pe_c = latx[..., :r], latx[..., r:]
     # scores: q_nope absorbed through w_uk  +  decoupled rope channel

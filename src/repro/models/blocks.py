@@ -106,8 +106,12 @@ def _attn(p, x, cfg, shd, rcfg, **kw):
 
 def apply_block(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg, kind: str, *,
                 positions=None, window: int = 0, cache: Optional[Dict] = None,
-                decode_pos=None, enc_out=None, mode: str = "train"):
-    """Returns (x', new_cache_or_None, aux_dict)."""
+                decode_pos=None, enc_out=None, mode: str = "train",
+                cache_layer=None):
+    """Returns (x', new_cache_or_None, aux_dict).
+
+    ``cache_layer``: decode against a segment's stacked k/v cache, this
+    layer being that index (``gqa_attention``)."""
     aux: Dict = {}
     decode = mode == "decode"
     want_cache = mode in ("prefill", "decode")
@@ -129,6 +133,8 @@ def apply_block(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg, kind: str, *,
             akw.update(cache={k: cache[k] for k in ("k", "v", "lat")
                               if k in cache} if cache else None,
                        decode_pos=decode_pos)
+            if cache_layer is not None:
+                akw["cache_layer"] = cache_layer
         if want_cache and not decode:
             res = _attn(p["attn"], h, cfg, shd, rcfg, return_cache=True, **akw)
             a, ac = res

@@ -4,7 +4,10 @@ A model is a list of *segments*; each segment is ``count`` layers of one
 block kind. Homogeneous segments are scanned (``lax.scan`` over stacked
 params — one traced body regardless of depth); heterogeneous layers
 (deepseek's dense layer 0, hymba's 3 global-attention layers) break the
-stack into segments. Caches mirror the segment structure.
+stack into segments. Caches mirror the segment structure; a decode step
+writes a segment's new K/V rows into its stacked cache in place where
+``decode_writes_in_place`` allows, and rewrites each layer's cache with a
+masked select elsewhere.
 
 Three entry points per model — ``forward_train``, ``forward_prefill``,
 ``forward_decode`` (= serve_step's body) — all pure functions of
@@ -116,6 +119,31 @@ def _remat(fn, rcfg):
     return jax.checkpoint(fn, policy=pol)
 
 
+def _scanned(seg: Segment, rcfg) -> bool:
+    return seg.scanned and seg.count > 1 and not rcfg.force_unroll_segments
+
+
+def decode_writes_in_place(seg: Segment, cache_seg, shd: ShardingCtx,
+                           rcfg) -> bool:
+    """Whether a decode step writes this segment's new K/V rows into its
+    stacked cache in place.
+
+    Yes for a scanned segment whose cache is a linear (non-ring) k/v cache,
+    on no mesh or one whose ``model`` axis is 1: the stack rides in the
+    scan's carry, so the cache that leaves the loop is the donated one that
+    entered it. Everywhere else (ring windows, MLA latents, cross-attention
+    or SSM state in the cache, unrolled segments, a model-sharded sequence
+    axis) each layer's cache is rewritten with the masked select of
+    ``attention.write_decode_rows``. ``cache_seg``: the segment's cache
+    leaves, arrays or schema descriptors (only shapes are read).
+    """
+    if not _scanned(seg, rcfg) or cache_seg is None \
+            or set(cache_seg) != {"k", "v"}:
+        return False
+    ring = bool(seg.window) and cache_seg["k"].shape[2] <= seg.window
+    return not ring and (shd.mesh is None or shd.tp == 1)
+
+
 def run_segment(seg: Segment, p_seg, x, cfg, shd, rcfg, *, mode,
                 positions=None, cache_seg=None, decode_pos=None, enc_out=None):
     """Returns (x, new_cache_seg, aux)."""
@@ -128,12 +156,28 @@ def run_segment(seg: Segment, p_seg, x, cfg, shd, rcfg, *, mode,
                                  enc_out=enc_out, mode=mode)
         return y, (c2, aux)
 
-    if seg.scanned and seg.count > 1 and not rcfg.force_unroll_segments:
+    def body_in_place(carry, per):
+        xc, c = carry
+        p_l, layer = per
+        y, c, aux = apply_block(p_l, xc, cfg, shd, rcfg, seg.kind,
+                                positions=positions, window=seg.window,
+                                cache=c, decode_pos=decode_pos, mode=mode,
+                                cache_layer=layer)
+        return (y, c), aux
+
+    def layer_mean(auxs):
+        return (jax.tree.map(lambda a: jnp.mean(a, axis=0), auxs)
+                if auxs else {})
+
+    if mode == "decode" and decode_writes_in_place(seg, cache_seg, shd, rcfg):
+        (x, caches), auxs = jax.lax.scan(
+            _remat(body_in_place, rcfg), (x, cache_seg),
+            (p_seg, jnp.arange(seg.count)))
+        return x, caches, layer_mean(auxs)
+    if _scanned(seg, rcfg):
         x, (caches, auxs) = jax.lax.scan(
             _remat(body, rcfg), x, (p_seg, cache_seg))
-        aux = (jax.tree.map(lambda a: jnp.mean(a, axis=0), auxs)
-               if auxs else {})
-        return x, caches, aux
+        return x, caches, layer_mean(auxs)
     # unrolled (heterogeneous or single-layer segments; params still stacked)
     new_caches = []
     aux_acc: Dict = {}

@@ -91,6 +91,8 @@ METRIC_HELP: Dict[str, str] = {
     "nk_engine_load": "Per-engine queued + in-flight requests",
     "nk_engine_parked": "1 if the engine is parked",
     "nk_engine_decode_steps_total": "Decode steps taken per engine",
+    "nk_decode_cache_inplace_segments":
+        "Cache segments the decode step writes in place (rest: masked select)",
     "nk_placement_ticks_total": "Placement autopilot ticks",
     "nk_placement_plans_applied_total": "Non-empty placement plans applied",
     "nk_placement_moves_total": "Autopilot migrations applied",

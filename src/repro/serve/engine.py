@@ -22,7 +22,8 @@ from repro.configs.base import ModelConfig, RunConfig
 from repro.distribution.sharding import ShardingCtx, init_params
 from repro.fabric import SchedulerServeModule
 from repro.models.model import (
-    cache_schema, forward_decode, forward_prefill, model_schema,
+    build_schedule, cache_schema, decode_writes_in_place, forward_decode,
+    forward_prefill, model_schema,
 )
 from repro.obs import tracing
 from repro.serve.scheduler import Request, TenantScheduler
@@ -78,6 +79,13 @@ class ServeEngine(SchedulerServeModule):
         self.steps = 0
         self.decode_steps = 0
         self.completed: List[Request] = []
+        # cache segments whose new K/V rows the decode program writes in
+        # place (the rest take the masked select): fixed by the model and
+        # the mesh, so counted once here
+        self.decode_inplace_segments = sum(
+            decode_writes_in_place(seg, c, self.shd, rcfg)
+            for seg, c in zip(build_schedule(cfg),
+                              cache_schema(cfg, batch_slots, max_seq)))
 
         cfg_, rcfg_, shd_ = cfg, rcfg, self.shd
 
@@ -116,6 +124,12 @@ class ServeEngine(SchedulerServeModule):
 
     def _release_buffers(self) -> None:
         self.caches = None
+
+    def counters(self) -> Dict[str, float]:
+        """The decode program's static facts (Prometheus naming):
+        ``registry.register_provider(engine, name="engine")``."""
+        return {"nk_decode_cache_inplace_segments":
+                float(self.decode_inplace_segments)}
 
     # ------------------------------------------------------------------
     def submit(self, req: Request):

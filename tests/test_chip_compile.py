@@ -80,7 +80,9 @@ def _device_bytes(compiled):
     return mem.argument_size_in_bytes + mem.temp_size_in_bytes
 
 
-def test_internlm2_decode_fits_one_chip(internlm2, one_chip):
+@pytest.fixture(scope="module")
+def internlm2_decode(internlm2, one_chip):
+    """The serve step's decode at 16 slots x 2048, cache donated."""
     import jax
     import jax.numpy as jnp
     from repro.distribution.sharding import abstract_params
@@ -96,9 +98,23 @@ def test_internlm2_decode_fits_one_chip(internlm2, one_chip):
         logits, caches = forward_decode(params, caches, tokens, pos, cfg,
                                         shd, rcfg)
         return jnp.argmax(logits, axis=-1), caches
-    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+    return jax.jit(step, donate_argnums=(1,)).lower(
         params, caches, tokens, pos).compile()
-    assert _device_bytes(compiled) < V5E_HBM
+
+
+def test_internlm2_decode_fits_one_chip(internlm2_decode):
+    assert _device_bytes(internlm2_decode) < V5E_HBM
+
+
+def test_internlm2_decode_writes_the_cache_in_place(internlm2_decode):
+    """The new K/V rows go into the donated cache in place: no temp the
+    size of a cache stack, and no copy of a whole [24,16,2048,8,128] stack
+    after the layer loop."""
+    import re
+    assert internlm2_decode.memory_analysis().temp_size_in_bytes < 64 << 20
+    stack_copies = re.findall(r"= bf16\[24,16,2048,8,128\]\S* copy\(",
+                              internlm2_decode.as_text())
+    assert stack_copies == []
 
 
 def test_internlm2_prefill_fits_one_chip(internlm2, one_chip):

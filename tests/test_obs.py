@@ -197,6 +197,27 @@ def test_registry_instruments_and_providers_export_together():
     assert parsed[("nk_test_wait_seconds_count", (("tenant", "0"),))] == 1.0
 
 
+@pytest.mark.parametrize("model_axis,in_place", [(1, 1.0), (2, 0.0)])
+def test_engine_gauges_its_in_place_decode_segments(model_axis, in_place,
+                                                    rcfg_small):
+    """internlm2's one cache segment takes the in-place decode write on one
+    device, and the masked select where the model axis shards the cache."""
+    from repro.configs import get_smoke_config
+    from repro.launch.mesh import make_host_mesh
+    from repro.models import build_schedule
+    from repro.serve.engine import ServeEngine
+    cfg = get_smoke_config("internlm2-1.8b")
+    assert len(build_schedule(cfg)) == 1
+    eng = ServeEngine(cfg, rcfg_small, make_host_mesh(1, model_axis),
+                      batch_slots=2, max_seq=32)
+    reg = MetricsRegistry()
+    reg.register_provider(eng, name="engine")
+    text = reg.export_prometheus()
+    assert "# TYPE nk_decode_cache_inplace_segments gauge" in text
+    assert parse_prometheus_text(text) == {
+        ("nk_decode_cache_inplace_segments", ()): in_place}
+
+
 # ---------------------------------------------------------------------------
 # latency histograms
 # ---------------------------------------------------------------------------
