@@ -26,7 +26,6 @@ so that a control run comes out not correct.
 """
 from __future__ import annotations
 
-import importlib
 from typing import Dict, List
 
 import numpy as np
@@ -79,10 +78,6 @@ def ledger(submitted: List, sched) -> Dict[str, int]:
               for t, n in want.items())
     gap += sum(n for t, n in sched.served_tokens.items() if t not in want)
     return {"ledger_gap": int(gap), "lost": int(lost)}
-
-
-def reference_module(name: str):
-    return importlib.import_module(f"bench.reference.{name}")
 
 
 def gaps(ref, w, m: Dict, reqs: List, pad_to: int, control: bool = False

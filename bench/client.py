@@ -65,12 +65,37 @@ class _Readback:
 
 def program_config(m: Dict):
     """The program's ModelConfig for the configuration file's model: the
-    program's own entry with the file's sizes applied (a cut in depth)."""
+    program's own entry (``program_name``) with the file's sizes applied.
+    A dict under ``moe`` or ``ssm`` is applied to that sub-config, field
+    by field. A key that the program has no field for raises: a published
+    size that the program cannot take shows, and does not vanish."""
     from repro.configs import get_config
+    from repro.configs.base import MoEConfig, SSMConfig
     cfg = get_config(m["program_name"])
     names = {f.name for f in dataclasses.fields(cfg)}
-    over = {k: v for k, v in m.items()
-            if k in names and getattr(cfg, k) != v and k != "name"}
+    subs = {"moe": MoEConfig, "ssm": SSMConfig}
+    over = {}
+    for k, v in m.items():
+        if k in ("program_name", "name"):
+            continue
+        if k == "mla":
+            raise ValueError(
+                "model key 'mla': the program attaches its latent attention "
+                "by name (repro.configs.base.MLA_BY_NAME), so a file cannot "
+                "set it until the program takes an mla field")
+        if k not in names:
+            raise ValueError(f"model key {k!r} is no field of the program's "
+                             f"ModelConfig")
+        if k in subs and isinstance(v, dict):
+            sub_names = {f.name for f in dataclasses.fields(subs[k])}
+            bad = sorted(set(v) - sub_names)
+            if bad:
+                raise ValueError(f"model key {k}.{bad[0]} is no field of "
+                                 f"the program's {subs[k].__name__}")
+            sub = getattr(cfg, k)
+            v = subs[k](**v) if sub is None else dataclasses.replace(sub, **v)
+        if getattr(cfg, k) != v:
+            over[k] = v
     return dataclasses.replace(cfg, **over) if over else cfg
 
 

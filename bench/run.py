@@ -4,7 +4,12 @@
 
 The cell names a configuration (``bench/configs/<config>.json``) and a
 traffic mix (``bench/traffic/<traffic>.json``); its metrics are readers in
-``bench/metrics/<metric>.py``. A run:
+``bench/metrics/<metric>.py``. The configuration names its architecture
+(``bench/arch/<arch>.py``: weight layout, the program's parameter tree,
+operation counts, smoke widths) and its plain reference
+(``bench/reference/<reference>.py``). Every such file is found by its name
+under the checkout's ``bench/``, so a configuration, a mix or a metric of
+a new kind is added as new files. A run:
 
   1. names the device on its first line, and exits 2 without a result
      where JAX finds no TPU or fewer chips than the cell asks for;
@@ -12,7 +17,8 @@ traffic mix (``bench/traffic/<traffic>.json``); its metrics are readers in
      path, and warms every shape the mix uses: each prompt length's
      prefill, the cache install into every slot, and the decode step;
   3. runs the mix open-loop through a warm-up span, then measures for
-     ``--seconds`` (with ``--trace 1`` under the profiler);
+     ``--seconds`` (with ``--trace 1`` under the profiler, with the
+     program's own regions on: ``repro.obs.tracing.ProfilerTracer``);
   4. reads the device's peak memory, frees the engine, and checks what the
      window served against the plain reference (bench/check.py);
   5. prints the checks, each beside its limit, as the last lines of
@@ -31,6 +37,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -67,15 +74,24 @@ def load_cell(name: str):
             [m for m in spec["per_layer"] if applies(m)])
 
 
-def reader(metric_name: str):
-    """The ``read`` function of ``bench/metrics/<metric_name>.py`` (a name
-    may hold dots, so the file is loaded by its path)."""
-    path = ROOT / "bench" / "metrics" / f"{metric_name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench.metrics.{metric_name}", path)
+@functools.lru_cache(maxsize=None)
+def _load(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def module(kind: str, name: str):
+    """``bench/<kind>/<name>.py`` under the checkout, loaded once by its
+    path (a metric's name may hold dots): ``arch``, ``reference`` or
+    ``metrics``."""
+    return _load(ROOT / "bench" / kind / f"{name}.py", f"bench.{kind}.{name}")
+
+
+def reader(metric_name: str):
+    """The ``read`` function of ``bench/metrics/<metric_name>.py``."""
+    return module("metrics", metric_name).read
 
 
 class CompileCounter:
@@ -94,7 +110,15 @@ class CompileCounter:
 
 
 class Ctx:
-    """What a metric reader reads."""
+    """What a metric reader reads: the window's edges (client clock), the
+    requests and the client's records; ``model`` (the configuration's
+    sizes) and ``arch`` (its ``bench/arch`` module: ``decode_call``,
+    ``prefill_flops``); ``peak`` (``bench/peaks.json``); the engine's
+    counters (``ServeEngine.counters()``) at the window's open and close;
+    and in a traced run ``trace`` (``bench/trace.py``'s record: device ops
+    and modules, ``bench.*`` spans under ``host``, the program's ``nk.*``
+    regions under ``program``; ``trace.op_scopes`` gives the ops their
+    name scopes) with ``trace_window``, else None."""
 
     def __init__(self, **kw):
         self.__dict__.update(kw)
@@ -117,17 +141,17 @@ class Ctx:
                 if s >= lo and e <= hi and 0 <= k < len(self.steps)]
 
 
-def build(conf, mix, seed, counter, log):
+def build(conf, mix, seed, counter, log, arch):
     """Weights, the serving path, and every shape the mix uses, warm."""
     import jax
     from bench import traffic, weights
     from bench.client import Serving, drain
     m = conf["model"]
-    w = weights.make_weights(m, seed)
+    w = weights.make_weights(arch.layout(m), m["param_dtype"], seed)
     jax.block_until_ready(w)
     log(f"weights: {sum(x.size for x in jax.tree.leaves(w))} parameters "
         f"from the seed")
-    srv = Serving(conf, traffic.weights(mix), weights.program_params(w))
+    srv = Serving(conf, traffic.weights(mix), arch.program_params(w, m))
     # one request per slot, cycling through the mix's prompt lengths and
     # its tenants: every prefill shape, the install into every slot, decode
     lens = traffic.prompt_lengths(mix)
@@ -154,12 +178,14 @@ def build(conf, mix, seed, counter, log):
 def run_cell(args, cell, conf, mix, e2e, per_layer, peak, device, log):
     """Everything after the look for a chip. Returns the result dict."""
     import jax
-    from bench import check, trace, traffic
+    from bench import check, program_spans, trace, traffic
     from bench.client import clock, run_open_loop
+    from repro.obs import tracing
 
     counter = CompileCounter()
     m = conf["model"]
-    w, srv = build(conf, mix, args.seed, counter, log)
+    arch = module("arch", conf["arch"])
+    w, srv = build(conf, mix, args.seed, counter, log, arch)
 
     reqs = traffic.schedule(mix, seed=args.seed, seconds=args.seconds,
                             vocab=m["vocab_size"], knee_rps=conf["knee_rps"])
@@ -171,6 +197,7 @@ def run_cell(args, cell, conf, mix, e2e, per_layer, peak, device, log):
     i = run_open_loop(srv, reqs, w_open)
     setup_s = clock() - T_START
     billed_open = dict(srv.sched.served_tokens)
+    counters_open = srv.eng.counters()
     compiles_open = counter.n
     tdir = None
     if args.trace:
@@ -178,12 +205,17 @@ def run_cell(args, cell, conf, mix, e2e, per_layer, peak, device, log):
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         opts.host_tracer_level = 2
+        prev_tracer = tracing.set_tracer(tracing.ProfilerTracer())
         jax.profiler.start_trace(tdir, profiler_options=opts)
-    with jax.profiler.TraceAnnotation("bench.window"):
-        run_open_loop(srv, reqs, w_close, i)
-    if args.trace:
-        jax.profiler.stop_trace()
+    try:
+        with jax.profiler.TraceAnnotation("bench.window"):
+            run_open_loop(srv, reqs, w_close, i)
+    finally:
+        if args.trace:
+            jax.profiler.stop_trace()
+            tracing.set_tracer(prev_tracer)
     billed_close = dict(srv.sched.served_tokens)
+    counters_close = srv.eng.counters()
     compiles_in_window = counter.n - compiles_open
     print(f"compiles_in_window: {compiles_in_window}", flush=True)
     late = sorted(x for x in srv.rec.late)
@@ -202,7 +234,9 @@ def run_cell(args, cell, conf, mix, e2e, per_layer, peak, device, log):
                   mix["latency_tenants"]),
               weights=traffic.weights(mix), billed_at_open=billed_open,
               billed_at_close=billed_close, setup_s=setup_s, model=m,
-              peak=peak, trace=None, trace_window=None)
+              arch=arch, peak=peak, counters_at_open=counters_open,
+              counters_at_close=counters_close, trace=None,
+              trace_window=None)
     breakdown = None
     if args.trace:
         rec = trace.load(tdir)
@@ -214,6 +248,7 @@ def run_cell(args, cell, conf, mix, e2e, per_layer, peak, device, log):
         dev["window_s"] = (hi - lo) / 1e9
         breakdown = {"device_ops": trace.top_ops(rec, lo, hi),
                      "idle_gaps": trace.idle_by_span(rec, lo, hi)}
+        log(f"program idle: {json.dumps(program_spans.split(rec, lo, hi))}")
     metrics = {}
     for met in (per_layer if args.trace else e2e):
         v = reader(met["name"])(ctx)
@@ -230,7 +265,7 @@ def run_cell(args, cell, conf, mix, e2e, per_layer, peak, device, log):
     srv.eng.caches = None
     del srv
     gc.collect()
-    ref = check.reference_module(conf["reference"])
+    ref = module("reference", conf["reference"])
     t_ref = clock()
     g = check.gaps(ref, w, m, sample, conf["engine"]["max_seq"],
                    control=bool(args.control))
@@ -271,7 +306,8 @@ def sweep(args, conf, mix, log):
     from bench.client import clock, run_open_loop
     counter = CompileCounter()
     m = conf["model"]
-    _, srv = build(conf, mix, args.seed, counter, log)
+    _, srv = build(conf, mix, args.seed, counter, log,
+                   module("arch", conf["arch"]))
     base = 0
     for k, rate in enumerate(float(x) for x in args.sweep.split(",")):
         one = dict(mix, streams=[dict(s, rate={"rps": rate})

@@ -13,11 +13,13 @@ DEVICE = {"platform": "cpu", "kind": "cpu", "count": 1}
 
 
 def smoke_conf(name="internlm2-1.8b"):
-    conf = json.loads((ROOT / "bench" / "configs" / f"{name}.json")
+    """The configuration file ``name`` under the harness's checkout
+    (``run.ROOT``) at its architecture's smoke widths
+    (``bench/arch/<arch>.py`` ``SMOKE``), with a small engine."""
+    from bench import run
+    conf = json.loads((run.ROOT / "bench" / "configs" / f"{name}.json")
                       .read_text())
-    conf["model"].update(num_layers=2, d_model=64, num_heads=4,
-                         num_kv_heads=2, head_dim=16, d_ff=128,
-                         vocab_size=256)
+    conf["model"].update(run.module("arch", conf["arch"]).SMOKE)
     conf["engine"].update(batch_slots=4, max_seq=64)
     conf["knee_rps"] = 40.0
     conf["check"] = {"served_gap_max": 0.05}
@@ -46,3 +48,17 @@ def args(**kw):
                 control=0, sweep="")
     base.update(kw)
     return types.SimpleNamespace(**base)
+
+
+def record_ctx(monkeypatch):
+    """Make ``run.run_cell`` keep each ``run.Ctx`` it builds in the list
+    returned."""
+    from bench import run
+    kept = []
+
+    class Ctx(run.Ctx):
+        def __init__(self, **kw):
+            super().__init__(**kw)
+            kept.append(self)
+    monkeypatch.setattr(run, "Ctx", Ctx)
+    return kept

@@ -5,6 +5,7 @@ run (weights, warm-up, open-loop window, metrics, the reference check).
 With the served path broken underneath, ``correct`` comes out false, and
 so it does for the float8 control.
 """
+import json
 import os
 import shutil
 import subprocess
@@ -111,3 +112,48 @@ def test_the_float8_control_is_not_correct():
     assert c["control_gap_max"]["value"] > c["control_gap_max"]["limit"]
     # the program's own tokens, in the same run, are within the limit
     assert c["served_gap_max"]["value"] <= c["served_gap_max"]["limit"]
+
+
+def test_a_new_architecture_needs_only_new_files(tmp_path, monkeypatch):
+    """In a copy of the benchmark's files, a configuration of a new
+    architecture name comes with new files alone: its architecture module,
+    its configuration file and the cell's entries. The harness, pointed at
+    the copy, runs it and finds it correct."""
+    shutil.copy(smoke.ROOT / "BENCHMARK.json", tmp_path)
+    shutil.copytree(smoke.ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = {p: p.read_bytes() for p in (tmp_path / "bench").rglob("*")
+              if p.is_file()}
+    (tmp_path / "bench" / "arch" / "twin_gqa.py").write_text(
+        '"""A second architecture name over the dense decoder."""\n'
+        "from bench.arch.dense_gqa import (  # noqa: F401\n"
+        "    SMOKE, decode_call, layout, prefill_flops, program_params)\n")
+    conf = json.loads((smoke.ROOT / "bench" / "configs"
+                       / "internlm2-1.8b.json").read_text())
+    conf.update(name="twin-1.8b", arch="twin_gqa")
+    (tmp_path / "bench" / "configs" / "twin-1.8b.json").write_text(
+        json.dumps(conf))
+    spec = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    spec["configs"].append(dict(spec["configs"][0], name="twin-1.8b",
+                                file="bench/configs/twin-1.8b.json"))
+    spec["workloads"].append(dict(spec["workloads"][0], name="twin-1.8b.chat",
+                                  config="twin-1.8b"))
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        if CELL in m.get("workloads", []):
+            m["workloads"].append("twin-1.8b.chat")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    # nothing the benchmark had is changed
+    assert all(p.read_bytes() == b for p, b in before.items())
+
+    monkeypatch.setattr(run, "ROOT", tmp_path)
+    ctxs = smoke.record_ctx(monkeypatch)
+    _, c, _, _, e2e, per = run.load_cell("twin-1.8b.chat")
+    out = run.run_cell(smoke.args(), c, smoke.smoke_conf("twin-1.8b"),
+                       smoke.smoke_mix("chat"), e2e, per, smoke.PEAK,
+                       smoke.DEVICE, lambda m: None)
+    assert out["correct"], out["checks"]
+    assert set(out["metrics"]) == {"ttft_p80_s", "itl_p99_ms", "setup_s"}
+    arch = ctxs[0].arch
+    assert arch.__file__ == str(tmp_path / "bench" / "arch" / "twin_gqa.py")
+    assert arch is run.module("arch", "twin_gqa")
+    assert ctxs[0].model["num_layers"] == arch.SMOKE["num_layers"]

@@ -47,12 +47,18 @@ def test_each_cell_finds_its_files(cell):
 def test_each_configuration_file_is_complete(path):
     # a file may wait for its cell (PERF.md, Open questions): a later PR
     # then adds only BENCHMARK.json entries
+    from bench import run
     conf = json.loads(path.read_text())
     assert conf["name"] == path.stem and NAME.match(conf["name"])
     assert (ROOT / "bench" / "reference" / f"{conf['reference']}.py").exists()
     assert conf["knee_rps"] > 0 and 0 < conf["check"]["served_gap_max"]
-    assert {"num_layers", "d_model", "num_heads", "num_kv_heads", "head_dim",
-            "d_ff", "vocab_size"} <= set(conf["model"])
+    # the architecture module lays out the file's model, and counts it
+    arch = run.module("arch", conf["arch"])
+    m = conf["model"]
+    assert arch.layout(m) and all(len(t) == 3 for t in arch.layout(m))
+    assert arch.prefill_flops(m, 8) > 0
+    assert arch.decode_call(m, [0, 7])["bytes"] > 0
+    assert set(arch.SMOKE) <= set(m)
     for key, change in conf["reduced"].items():
         assert NAME.match(key) and change["published"] != change["here"]
 

@@ -78,3 +78,100 @@ def test_recorded_v5e_trace():
     names = [n for n, _ in trace.top_ops(rec, lo, hi)]
     assert names and not any(n.startswith("while") for n in names)
     assert all(" = " not in n for n in names)
+
+
+def test_an_op_takes_its_scope_from_the_program_that_holds_it():
+    ms = 1_000_000
+    rec = hand_made()
+    ops = rec["devices"]["/device:TPU:0"]["XLA Ops"]
+    ops[0][0] = "%fusion.1 = bf16[2]{0} fusion(bf16[2]{0} %p), kind=kLoop"
+    mods = rec["devices"]["/device:TPU:0"]["XLA Modules"]
+    scopes = {"jit__decode(7)": {"fusion.1": "jit(_decode)/while/body/mlp",
+                                 "copy": "jit(_decode)/copy"},
+              "jit__prefill(3)": {"fusion.2": "jit(_prefill)/attn"}}
+    trace.add_scopes(ops, mods, scopes)
+    # [0, 6) decode, [10, 12) prefill, [15, 25) decode
+    assert [op[3:] for op in ops] == [["jit(_decode)/while/body/mlp"], [],
+                                      [], ["jit(_decode)/copy"]]
+    assert ops[1][1] == 3 * ms           # fusion.2 runs under decode
+    assert len(ops[2]) == 3              # fusion.1 is not prefill's
+
+
+@pytest.fixture(scope="module")
+def probe_profile(tmp_path_factory):
+    """A profile of one jitted call under a named scope, recorded on the
+    CPU (whose ops are not on a device plane): its directory and bytes."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def probe(x):
+        with jax.named_scope("bench_probe"):
+            return jnp.tanh(x @ x).sum()
+    x = jnp.ones((8, 8))
+    probe(x).block_until_ready()
+    d = tmp_path_factory.mktemp("probe")
+    jax.profiler.start_trace(str(d))
+    probe(x).block_until_ready()
+    jax.profiler.stop_trace()
+    path, = d.glob("**/*.xplane.pb")
+    return d, path.read_bytes()
+
+
+def test_hlo_scopes_from_a_profile(probe_profile):
+    """The programs' HLO that a profile keeps names each instruction's
+    scope."""
+    d, raw = probe_profile
+    scopes = trace.plane_scopes(trace.metadata_plane(raw))
+    name, = [m for m in scopes if m.startswith("jit_probe(")]
+    assert "jit(probe)/bench_probe/tanh" in scopes[name].values()
+    assert trace.metadata_plane(b"") == b"" and trace.plane_scopes(b"") == {}
+    rec = trace.load(str(d))
+    assert rec["devices"] == {} and rec["host"] == rec["program"] == []
+
+
+def test_op_scopes_are_joined_on_demand(probe_profile):
+    """``load`` keeps the profile's HLO and joins nothing; ``op_scopes``
+    gives the ops their scopes once, and drops the HLO."""
+    d, raw = probe_profile
+    kept = trace.load(str(d))["hlo"]
+    assert kept and kept == trace.metadata_plane(raw)
+    scopes = trace.plane_scopes(kept)
+    program, = [m for m in scopes if m.startswith("jit_probe(")]
+    inst, path = next((i, s) for i, s in scopes[program].items()
+                      if s.endswith("/tanh"))
+    rec = hand_made()
+    dev = rec["devices"]["/device:TPU:0"]
+    for mod in dev["XLA Modules"]:
+        mod[0] = program
+    for k, op in enumerate(dev["XLA Ops"]):
+        op[0] = f"no_such_op.{k}"
+    dev["XLA Ops"][0][0] = f"%{inst} = f32[8,8]{{1,0}} tanh(f32[8,8] %p)"
+    rec["hlo"] = kept
+    assert trace.op_scopes(rec) is rec and "hlo" not in rec
+    assert [op[3:] for op in dev["XLA Ops"]] == [[path], [], [], []]
+    trace.op_scopes(rec)
+    assert [len(op) for op in dev["XLA Ops"]] == [4, 3, 3, 3]
+
+
+def test_op_scopes_without_hlo_leave_the_ops_alone():
+    rec = hand_made()
+    assert trace.op_scopes(rec) == hand_made()
+    with gzip.open(FIXTURE, "rt") as f:
+        fixture = json.load(f)
+    for lines in trace.op_scopes(fixture)["devices"].values():
+        assert all(len(op) == 3 for op in lines["XLA Ops"])
+
+
+def test_ops_with_scopes_read_as_without():
+    plain = hand_made()
+    scoped = hand_made()
+    ops = scoped["devices"]["/device:TPU:0"]["XLA Ops"]
+    for i, op in enumerate(ops):
+        if i % 2 == 0:
+            op.append(f"jit(_decode)/while/body/layer{i}")
+    lo, hi = trace.host_span(plain, "bench.window")
+    assert trace.busy(scoped, lo, hi) == trace.busy(plain, lo, hi)
+    assert trace.top_ops(scoped, lo, hi) == trace.top_ops(plain, lo, hi)
+    assert trace.idle_by_span(scoped, lo, hi) == \
+        trace.idle_by_span(plain, lo, hi)
