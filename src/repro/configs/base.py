@@ -29,6 +29,30 @@ class MoEConfig:
     router_zloss: float = 1e-3
     # dense residual branch computed in parallel with the MoE branch (arctic)
     parallel_dense: bool = False
+    # routing (deepseek-v2: group-limited greedy over 8 groups, 3 kept,
+    # weights scaled by 16 and not renormalised)
+    router_experts: int = 0          # router outputs; 0 => num_experts
+    first_expert: int = 0            # router index of the first held expert
+    expert_groups: int = 1           # the router's experts in equal groups
+    top_k_groups: int = 1            # groups a token's top_k is drawn from
+    routed_scale: float = 1.0        # multiplies the top-k weights
+    renormalize_top_k: bool = True   # top-k weights divided by their sum
+
+    def __post_init__(self):
+        if self.router_experts == 0:
+            object.__setattr__(self, "router_experts", self.num_experts)
+        if self.first_expert + self.num_experts > self.router_experts:
+            raise ValueError("held experts lie beyond the router's outputs")
+        if self.router_experts % self.expert_groups or \
+                not 1 <= self.top_k_groups <= self.expert_groups:
+            raise ValueError("expert groups must split the router evenly "
+                             "and keep between 1 and all of them")
+
+    @property
+    def holds_share(self) -> bool:
+        """The layer holds only some of the router's experts (expert
+        parallelism): it computes their part of the result, dropless."""
+        return self.num_experts < self.router_experts
 
 
 @dataclass(frozen=True)
@@ -52,13 +76,24 @@ class SSMConfig:
 
 @dataclass(frozen=True)
 class MLAConfig:
-    """DeepSeek-V2 multi-head latent attention."""
+    """DeepSeek-V2 multi-head latent attention, with YaRN rope scaling.
+
+    ``rope_factor`` 1 is plain rope; above 1 the rope frequencies are
+    YaRN's blend of interpolated and original ones (``layers.yarn_inv_freq``)
+    and the softmax scale gains ``yarn_mscale(factor, mscale_all_dim)**2``.
+    """
 
     kv_lora_rank: int = 512
     q_lora_rank: int = 0          # 0 => full-rank q projection
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    rope_factor: float = 1.0
+    rope_original_max_positions: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
 
 
 # ---------------------------------------------------------------------------
@@ -84,6 +119,10 @@ class ModelConfig:
     qk_norm: bool = False            # chameleon uses qk layernorm
     tie_embeddings: bool = True
     logit_softcap: float = 0.0
+    norm_eps: float = 1e-5
+
+    # --- latent attention (deepseek-v2) ---
+    mla: Optional[MLAConfig] = None
 
     # --- MoE ---
     moe: Optional[MoEConfig] = None
@@ -122,17 +161,18 @@ class ModelConfig:
         """Eligible for the long_500k cell (per assignment instructions)."""
         return self.family in ("ssm", "hybrid")
 
-    @property
-    def mla(self) -> Optional[MLAConfig]:
-        return MLA_BY_NAME.get(self.name)
-
     def attn_params_per_layer(self) -> int:
         d, h, kv, hd = self.d_model, self.num_heads, self.num_kv_heads, self.head_dim
         mla = self.mla
         if mla is not None:
             qk_hd = mla.qk_nope_head_dim + mla.qk_rope_head_dim
-            p = d * h * qk_hd                                   # q proj
+            if mla.q_lora_rank:                                 # low-rank q
+                p = d * mla.q_lora_rank + mla.q_lora_rank       # + its norm
+                p += mla.q_lora_rank * h * qk_hd
+            else:
+                p = d * h * qk_hd                               # q proj
             p += d * (mla.kv_lora_rank + mla.qk_rope_head_dim)  # down proj
+            p += mla.kv_lora_rank                               # its norm
             p += mla.kv_lora_rank * h * (mla.qk_nope_head_dim + mla.v_head_dim)
             p += h * mla.v_head_dim * d                         # out proj
             return p
@@ -143,7 +183,7 @@ class ModelConfig:
             m = self.moe
             e = m.num_experts * self._expert_ffn(m.expert_ff)
             e += m.num_shared_experts * self._expert_ffn(m.shared_ff or m.expert_ff)
-            e += self.d_model * m.num_experts                    # router
+            e += self.d_model * m.router_experts                 # router
             if m.parallel_dense:
                 e += self._expert_ffn(self.d_ff)
             return e
@@ -174,7 +214,7 @@ class ModelConfig:
         m = self.moe
         a = m.top_k * self._expert_ffn(m.expert_ff)
         a += m.num_shared_experts * self._expert_ffn(m.shared_ff or m.expert_ff)
-        a += self.d_model * m.num_experts
+        a += self.d_model * m.router_experts
         if m.parallel_dense:
             a += self._expert_ffn(self.d_ff)
         return a
@@ -223,17 +263,6 @@ class ModelConfig:
 
     def num_layers_moe(self) -> int:
         return 0 if self.moe is None else self.num_layers - self.dense_layer_prefix
-
-
-# MLA is attached per-arch here (keeps ModelConfig generic/flat).
-MLA_BY_NAME = {
-    "deepseek-v2-236b": MLAConfig(kv_lora_rank=512, q_lora_rank=0,
-                                  qk_nope_head_dim=128, qk_rope_head_dim=64,
-                                  v_head_dim=128),
-    "deepseek-v2-smoke": MLAConfig(kv_lora_rank=32, q_lora_rank=0,
-                                   qk_nope_head_dim=16, qk_rope_head_dim=8,
-                                   v_head_dim=16),
-}
 
 
 # ---------------------------------------------------------------------------
@@ -342,7 +371,14 @@ def reduce_for_smoke(cfg: ModelConfig) -> ModelConfig:
     if cfg.moe is not None:
         kw["moe"] = dataclasses.replace(
             cfg.moe, num_experts=4, top_k=min(cfg.moe.top_k, 2), expert_ff=64,
-            shared_ff=64 if cfg.moe.num_shared_experts else 0)
+            shared_ff=64 if cfg.moe.num_shared_experts else 0,
+            router_experts=4, first_expert=0,
+            expert_groups=min(cfg.moe.expert_groups, 2),
+            top_k_groups=min(cfg.moe.top_k_groups, 1),
+            # the scale that offsets small scores over many experts, cut
+            # with them (deepseek: 16 over 160 -> 0.4 over 4)
+            routed_scale=cfg.moe.routed_scale * 4 / cfg.moe.router_experts
+            if cfg.moe.routed_scale != 1.0 else 1.0)
         kw["dense_prefix_ff"] = 128 if cfg.dense_layer_prefix else 0
         if cfg.dense_layer_prefix:
             kw["num_layers"] = max(kw["num_layers"], cfg.dense_layer_prefix + 2)
@@ -354,6 +390,8 @@ def reduce_for_smoke(cfg: ModelConfig) -> ModelConfig:
     if cfg.encoder_layers:
         kw["encoder_layers"] = 2
         kw["encoder_seq"] = 24
-    if cfg.name == "deepseek-v2-236b":
-        kw["name"] = "deepseek-v2-smoke"   # picks up the smoke MLA config
+    if cfg.mla is not None:
+        kw["mla"] = dataclasses.replace(
+            cfg.mla, kv_lora_rank=32, q_lora_rank=24 if cfg.mla.q_lora_rank
+            else 0, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16)
     return dataclasses.replace(cfg, **kw)

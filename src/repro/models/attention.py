@@ -38,7 +38,9 @@ import jax.numpy as jnp
 
 from repro.configs.base import MLAConfig, ModelConfig
 from repro.distribution.sharding import ParamDesc, ShardingCtx, padded_heads
-from repro.models.layers import apply_norm, apply_rope, f32, norm_schema, rope_tables
+from repro.models.layers import (
+    apply_norm, apply_rope, f32, norm_schema, rope_tables, yarn_mscale,
+)
 
 NEG_INF = -2.0e30
 
@@ -70,14 +72,31 @@ def mla_schema(cfg: ModelConfig, mesh) -> Dict:
     hp = padded_heads(h, mesh) if mesh is not None else h
     qk_hd = mla.qk_nope_head_dim + mla.qk_rope_head_dim
     r = mla.kv_lora_rank
-    return {
-        "wq": ParamDesc((d, hp, qk_hd), ("embed", "heads", "head_dim"), cfg.param_dtype),
+    s = {
         "w_dkv": ParamDesc((d, r + mla.qk_rope_head_dim), ("embed", None), cfg.param_dtype),
         "w_uk": ParamDesc((r, hp, mla.qk_nope_head_dim), (None, "heads", "head_dim"), cfg.param_dtype),
         "w_uv": ParamDesc((r, hp, mla.v_head_dim), (None, "heads", "head_dim"), cfg.param_dtype),
         "wo": ParamDesc((hp, mla.v_head_dim, d), ("heads", "head_dim", "embed"), cfg.param_dtype),
         "kv_norm": norm_schema(r, "rmsnorm", cfg.param_dtype),
     }
+    if mla.q_lora_rank:
+        # low-rank q: x W_qa, RMSNorm, then W_qb up to every head
+        qr = mla.q_lora_rank
+        s["wq_a"] = ParamDesc((d, qr), ("embed", None), cfg.param_dtype)
+        s["q_norm"] = norm_schema(qr, "rmsnorm", cfg.param_dtype)
+        s["wq_b"] = ParamDesc((qr, hp, qk_hd), (None, "heads", "head_dim"), cfg.param_dtype)
+    else:
+        s["wq"] = ParamDesc((d, hp, qk_hd), ("embed", "heads", "head_dim"), cfg.param_dtype)
+    return s
+
+
+def mla_cache_width(mla: MLAConfig) -> int:
+    """Values a latent cache row holds: [c_kv, k_pe], zero-padded to a
+    multiple of 128. With an unpadded 576-value row the TPU lays the
+    stacked cache out sequence-minor, while the decode's in-place row
+    write needs it row-minor, so the program would copy the whole stack
+    into the other layout and back on every step."""
+    return -(-(mla.kv_lora_rank + mla.qk_rope_head_dim) // 128) * 128
 
 
 def head_mask(num_real: int, num_padded: int, dtype):
@@ -364,7 +383,7 @@ def gqa_attention(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg, *,
 
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     if cfg.qk_norm:
-        q = apply_norm(p["q_norm"], q, "rmsnorm")
+        q = apply_norm(p["q_norm"], q, "rmsnorm", cfg.norm_eps)
     use_rope = cfg.rope_theta > 0 and kv_x is None and not cross_decode
 
     if cross_decode:
@@ -382,7 +401,7 @@ def gqa_attention(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg, *,
     knew = jnp.einsum("bsd,dhk->bshk", src, p["wk"])
     vnew = jnp.einsum("bsd,dhk->bshk", src, p["wv"])
     if cfg.qk_norm:
-        knew = apply_norm(p["k_norm"], knew, "rmsnorm")
+        knew = apply_norm(p["k_norm"], knew, "rmsnorm", cfg.norm_eps)
 
     if cache is None or decode_pos is None:
         # ---- training / prefill / encoder ----
@@ -449,25 +468,62 @@ def gqa_attention(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg, *,
 # ---------------------------------------------------------------------------
 
 
+def _latent_rows(c_kv, k_pe, mla: MLAConfig):
+    """[c_kv, k_pe] along the last axis, zero-padded to the cache row."""
+    pad = mla_cache_width(mla) - c_kv.shape[-1] - k_pe.shape[-1]
+    parts = [c_kv, k_pe.astype(c_kv.dtype)]
+    if pad:
+        parts.append(jnp.zeros(c_kv.shape[:-1] + (pad,), c_kv.dtype))
+    return jnp.concatenate(parts, -1)
+
+
 def mla_attention(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg, *,
-                  positions, cache=None, decode_pos=None, return_cache=False):
+                  positions, cache=None, decode_pos=None, cache_layer=None,
+                  return_cache=False):
+    """DeepSeek-V2 latent attention (arXiv:2405.04434 §2.1).
+
+    q from x (full rank, or low rank: RMSNorm(x W_qa) W_qb); a shared
+    latent ``c_kv = RMSNorm(x W_dkv[:r])`` with one rope key ``k_pe`` for
+    all heads; rope (YaRN where configured) on the last ``rope`` channels
+    of q and on k_pe (rotate-half); the softmax scaled by
+    mscale^2 / sqrt(nope + rope). The cache row per token is
+    ``[c_kv, rotated k_pe]``, zero-padded (``mla_cache_width``). Decode
+    scores in the absorbed form:
+    q_nope W_uk against c_kv, W_uv after the weighted sum. With
+    ``cache_layer``, ``cache["lat"]`` is the segment's stacked (L,B,S,R)
+    latent and this layer's new rows are written into it in place.
+    """
+    with jax.named_scope("mla"):
+        return _mla(p, x, cfg, shd, rcfg, positions=positions, cache=cache,
+                    decode_pos=decode_pos, cache_layer=cache_layer,
+                    return_cache=return_cache)
+
+
+def _mla(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg, *, positions,
+         cache, decode_pos, cache_layer, return_cache):
     mla = cfg.mla
     h = cfg.num_heads
-    hp = p["wq"].shape[1]
+    hp = p["wo"].shape[0]
     mask = head_mask(h, hp, x.dtype)
     nope, rope_d, r = mla.qk_nope_head_dim, mla.qk_rope_head_dim, mla.kv_lora_rank
-    scale = 1.0 / math.sqrt(nope + rope_d)
+    mscale = yarn_mscale(mla.rope_factor, mla.rope_mscale_all_dim)
+    scale = mscale * mscale / math.sqrt(nope + rope_d)
 
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
+    if mla.q_lora_rank:
+        c_q = apply_norm(p["q_norm"], jnp.einsum("bsd,dr->bsr", x, p["wq_a"]),
+                         "rmsnorm", cfg.norm_eps)
+        q = jnp.einsum("bsr,rhk->bshk", c_q, p["wq_b"])
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     q_nope, q_rope = q[..., :nope], q[..., nope:]
 
     dkv = jnp.einsum("bsd,dr->bsr", x, p["w_dkv"])
     c_kv_new, k_pe_new = dkv[..., :r], dkv[..., r:]
-    c_kv_new = apply_norm(p["kv_norm"], c_kv_new, "rmsnorm")
+    c_kv_new = apply_norm(p["kv_norm"], c_kv_new, "rmsnorm", cfg.norm_eps)
 
     if cache is None or decode_pos is None:
         # ---- train / prefill: explicit k, v ----
-        cos, sin = rope_tables(positions, rope_d, cfg.rope_theta)
+        cos, sin = rope_tables(positions, rope_d, cfg.rope_theta, mla)
         q_rope = apply_rope(q_rope, cos, sin)
         k_pe = apply_rope(k_pe_new[:, :, None, :], cos, sin)   # (B,S,1,rope)
         k_nope = jnp.einsum("bsr,rhk->bshk", c_kv_new, p["w_uk"])
@@ -491,33 +547,31 @@ def mla_attention(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg, *,
         o = o * mask[None, None, :, None]
         out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
         if return_cache:
-            lat = jnp.concatenate([c_kv_new, k_pe[:, :, 0, :]], -1)  # (B,S,R+rope)
-            return out, {"lat": lat}
+            return out, {"lat": _latent_rows(c_kv_new, k_pe[:, :, 0, :], mla)}
         return out
 
     # ---- decode: absorbed form against the latent cache ----
-    b = x.shape[0]
-    cos, sin = rope_tables(decode_pos[:, None], rope_d, cfg.rope_theta)
+    cos, sin = rope_tables(decode_pos[:, None], rope_d, cfg.rope_theta, mla)
     q_rope = apply_rope(q_rope, cos, sin)
     k_pe = apply_rope(k_pe_new[:, :, None, :], cos, sin)[:, :, 0, :]
-    new_lat = jnp.concatenate([c_kv_new[:, 0], k_pe[:, 0]], -1)
-    # masked update (not scatter) — keeps the latent cache context-parallel
-    lat = write_decode_rows(cache["lat"], new_lat, decode_pos)
+    new_lat = _latent_rows(c_kv_new[:, 0], k_pe[:, 0], mla)
+    lat_all = write_decode_rows(cache["lat"], new_lat, decode_pos, cache_layer)
+    lat = lat_all if cache_layer is None else lat_all[cache_layer]
     latx = lat.astype(x.dtype)
-    c_c, pe_c = latx[..., :r], latx[..., r:]
-    # scores: q_nope absorbed through w_uk  +  decoupled rope channel
+    # scores: q_nope absorbed through w_uk beside the decoupled rope
+    # channels, against whole cache rows [c_kv, k_pe] in one product (no
+    # slice of the cache is copied out); the weighted sum likewise, of
+    # which the c_kv part goes on through w_uv
     q_lat = jnp.einsum("bqhk,rhk->bqhr", q_nope, p["w_uk"])
-    s_lat = jnp.einsum("bqhr,btr->bhqt", q_lat, c_c,
-                       preferred_element_type=jnp.float32)
-    s_pe = jnp.einsum("bqhk,btk->bhqt", q_rope, pe_c,
-                      preferred_element_type=jnp.float32)
-    sres = (s_lat + s_pe) * scale
+    q_cat = _latent_rows(q_lat, q_rope.astype(q_lat.dtype), mla)
+    sres = jnp.einsum("bqhr,btr->bhqt", q_cat, latx,
+                      preferred_element_type=jnp.float32) * scale
     t_pos = jnp.arange(lat.shape[1])[None, :]
     valid = t_pos <= decode_pos[:, None]
     sres = jnp.where(valid[:, None, None, :], sres, NEG_INF)
     pr = jax.nn.softmax(sres, axis=-1)
-    o_lat = jnp.einsum("bhqt,btr->bqhr", pr.astype(x.dtype), c_c)
+    o_lat = jnp.einsum("bhqt,btr->bqhr", pr.astype(x.dtype), latx)[..., :r]
     o = jnp.einsum("bqhr,rhk->bqhk", o_lat, p["w_uv"])
     o = o * mask[None, None, :, None]
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
-    return out, {"lat": lat}
+    return out, {"lat": lat_all}

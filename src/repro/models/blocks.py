@@ -75,7 +75,7 @@ def block_cache_schema(cfg: ModelConfig, kind: str, batch: int, seq: int,
     s: Dict = {}
     if kind in ("dense", "moe", "dense_prefix", "dec", "hybrid"):
         if _is_mla(cfg):
-            r = cfg.mla.kv_lora_rank + cfg.mla.qk_rope_head_dim
+            r = attn_mod.mla_cache_width(cfg.mla)
             s["lat"] = ParamDesc((batch, seq, r), ("batch", "kv_seq", None),
                                  dtype, "zeros")
         else:
@@ -110,15 +110,16 @@ def apply_block(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg, kind: str, *,
                 cache_layer=None):
     """Returns (x', new_cache_or_None, aux_dict).
 
-    ``cache_layer``: decode against a segment's stacked k/v cache, this
-    layer being that index (``gqa_attention``)."""
+    ``cache_layer``: decode against a segment's stacked k/v or latent
+    cache, this layer being that index (``gqa_attention``,
+    ``mla_attention``)."""
     aux: Dict = {}
     decode = mode == "decode"
     want_cache = mode in ("prefill", "decode")
     new_cache: Dict = {} if want_cache else None
 
     if kind == "ssm":
-        h = apply_norm(p["ln1"], x, cfg.norm)
+        h = apply_norm(p["ln1"], x, cfg.norm, cfg.norm_eps)
         y, c2 = ssm_mod.ssm_block(p["ssm"], h, cfg, shd, rcfg,
                                   cache=cache, decode=decode)
         x = x + y
@@ -126,7 +127,7 @@ def apply_block(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg, kind: str, *,
 
     # ---- attention half ----
     if kind in ("dense", "moe", "dense_prefix", "enc", "dec", "hybrid"):
-        h = apply_norm(p["ln1"], x, cfg.norm)
+        h = apply_norm(p["ln1"], x, cfg.norm, cfg.norm_eps)
         akw: Dict = dict(positions=positions, window=window,
                          causal=(kind != "enc"))
         if decode:
@@ -153,15 +154,16 @@ def apply_block(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg, kind: str, *,
                       ("state", "conv_x", "conv_B", "conv_C")}
             sout, sc2 = ssm_mod.ssm_block(p["ssm"], h, cfg, shd, rcfg,
                                           cache=sc, decode=decode)
-            a = 0.5 * (apply_norm(p["attn_out_norm"], a, cfg.norm)
-                       + apply_norm(p["ssm_out_norm"], sout, cfg.norm))
+            a = 0.5 * (
+                apply_norm(p["attn_out_norm"], a, cfg.norm, cfg.norm_eps)
+                + apply_norm(p["ssm_out_norm"], sout, cfg.norm, cfg.norm_eps))
             if new_cache is not None and sc2 is not None:
                 new_cache.update(sc2)
         x = x + a
 
     # ---- cross attention (whisper decoder) ----
     if kind == "dec":
-        h = apply_norm(p["ln_cross"], x, cfg.norm)
+        h = apply_norm(p["ln_cross"], x, cfg.norm, cfg.norm_eps)
         if mode == "decode":
             c, _ = gqa_attention(p["cross"], h, cfg, shd, rcfg,
                                  positions=positions,
@@ -179,7 +181,7 @@ def apply_block(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg, kind: str, *,
         x = x + c
 
     # ---- FFN half ----
-    h = apply_norm(p["ln2"], x, cfg.norm)
+    h = apply_norm(p["ln2"], x, cfg.norm, cfg.norm_eps)
     if kind == "moe":
         y, aux = apply_moe(p["moe"], h, cfg, shd, rcfg)
     else:

@@ -6,10 +6,12 @@ the sharding layer maps them onto whatever mesh the operator provides
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.distribution.sharding import ParamDesc, ShardingCtx
 
@@ -75,13 +77,52 @@ def apply_mlp(p, x, activation: str, shd: Optional[ShardingCtx] = None):
 # ---------------------------------------------------------------------------
 
 
-def rope_tables(positions: jax.Array, head_dim: int, theta: float):
-    """positions: (...,) int -> (cos, sin) of shape positions.shape+(head_dim,)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term: 0.1 * mscale * ln(factor) + 1."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(head_dim: int, theta: float, mla) -> np.ndarray:
+    """YaRN's rope frequencies (``mla``: an MLAConfig with rope_factor > 1).
+
+    Channels that turn more than ``beta_fast`` times over the original
+    context keep their frequency, those that turn fewer than
+    ``beta_slow`` times are divided by the factor, and a linear ramp
+    blends the two between those channels."""
     half = head_dim // 2
-    freq = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    extra = 1.0 / theta ** (np.arange(half, dtype=np.float64) * 2 / head_dim)
+    inter = extra / mla.rope_factor
+    orig = mla.rope_original_max_positions
+
+    def corr(rotations):
+        return head_dim * math.log(orig / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(corr(mla.rope_beta_fast)), 0)
+    high = min(math.ceil(corr(mla.rope_beta_slow)), head_dim - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
+    keep = 1.0 - ramp
+    return (inter * (1 - keep) + extra * keep).astype(np.float32)
+
+
+def rope_tables(positions: jax.Array, head_dim: int, theta: float,
+                mla=None):
+    """positions: (...,) int -> (cos, sin) of shape positions.shape+(head_dim//2,).
+
+    ``mla`` with ``rope_factor`` > 1: YaRN's frequencies, and cos/sin
+    scaled by mscale(factor, mscale) / mscale(factor, mscale_all_dim)."""
+    half = head_dim // 2
+    if mla is not None and mla.rope_factor > 1.0:
+        freq = jnp.asarray(yarn_inv_freq(head_dim, theta, mla))
+        scale = yarn_mscale(mla.rope_factor, mla.rope_mscale) / \
+            yarn_mscale(mla.rope_factor, mla.rope_mscale_all_dim)
+    else:
+        freq = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+        scale = 1.0
     angles = positions[..., None].astype(jnp.float32) * freq
     cos = jnp.cos(angles)
     sin = jnp.sin(angles)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     return cos, sin
 
 

@@ -125,22 +125,23 @@ def _scanned(seg: Segment, rcfg) -> bool:
 
 def decode_writes_in_place(seg: Segment, cache_seg, shd: ShardingCtx,
                            rcfg) -> bool:
-    """Whether a decode step writes this segment's new K/V rows into its
+    """Whether a decode step writes this segment's new cache rows into its
     stacked cache in place.
 
-    Yes for a scanned segment whose cache is a linear (non-ring) k/v cache,
-    on no mesh or one whose ``model`` axis is 1: the stack rides in the
-    scan's carry, so the cache that leaves the loop is the donated one that
-    entered it. Everywhere else (ring windows, MLA latents, cross-attention
-    or SSM state in the cache, unrolled segments, a model-sharded sequence
+    Yes for a linear (non-ring) k/v cache or an MLA latent cache, on no
+    mesh or one whose ``model`` axis is 1: a scanned segment carries the
+    stack in the scan's carry, an unrolled one (deepseek's single dense
+    layer) threads it through its layers, so the cache that leaves is the
+    donated one that entered. Everywhere else (ring windows,
+    cross-attention or SSM state in the cache, a model-sharded sequence
     axis) each layer's cache is rewritten with the masked select of
     ``attention.write_decode_rows``. ``cache_seg``: the segment's cache
     leaves, arrays or schema descriptors (only shapes are read).
     """
-    if not _scanned(seg, rcfg) or cache_seg is None \
-            or set(cache_seg) != {"k", "v"}:
+    if cache_seg is None or set(cache_seg) not in ({"k", "v"}, {"lat"}):
         return False
-    ring = bool(seg.window) and cache_seg["k"].shape[2] <= seg.window
+    ring = "k" in cache_seg and bool(seg.window) \
+        and cache_seg["k"].shape[2] <= seg.window
     return not ring and (shd.mesh is None or shd.tp == 1)
 
 
@@ -165,33 +166,46 @@ def run_segment(seg: Segment, p_seg, x, cfg, shd, rcfg, *, mode,
                                 cache_layer=layer)
         return (y, c), aux
 
-    def layer_mean(auxs):
-        return (jax.tree.map(lambda a: jnp.mean(a, axis=0), auxs)
+    def over_layers(auxs):
+        # integer aux are counts and add up over the layers; the rest
+        # (losses, fractions) are averaged
+        return ({k: jnp.sum(a, 0) if jnp.issubdtype(a.dtype, jnp.integer)
+                 else jnp.mean(a, 0) for k, a in auxs.items()}
                 if auxs else {})
 
-    if mode == "decode" and decode_writes_in_place(seg, cache_seg, shd, rcfg):
+    in_place = mode == "decode" and decode_writes_in_place(
+        seg, cache_seg, shd, rcfg)
+    if in_place and _scanned(seg, rcfg):
         (x, caches), auxs = jax.lax.scan(
             _remat(body_in_place, rcfg), (x, cache_seg),
             (p_seg, jnp.arange(seg.count)))
-        return x, caches, layer_mean(auxs)
+        return x, caches, over_layers(auxs)
     if _scanned(seg, rcfg):
         x, (caches, auxs) = jax.lax.scan(
             _remat(body, rcfg), x, (p_seg, cache_seg))
-        return x, caches, layer_mean(auxs)
-    # unrolled (heterogeneous or single-layer segments; params still stacked)
+        return x, caches, over_layers(auxs)
+    # unrolled (heterogeneous or single-layer segments; params still
+    # stacked); in place, the stacked cache threads through the layers
     new_caches = []
-    aux_acc: Dict = {}
+    per_layer: List[Dict] = []
+    c = cache_seg
     for i in range(seg.count):
         p_l = jax.tree.map(lambda a: a[i], p_seg)
-        c_l = (jax.tree.map(lambda a: a[i], cache_seg)
-               if cache_seg is not None else None)
-        x, (c2, aux) = _remat(body, rcfg)(x, (p_l, c_l))
-        new_caches.append(c2)
-        for k2, v2 in (aux or {}).items():
-            aux_acc[k2] = aux_acc.get(k2, 0.0) + v2 / seg.count
+        if in_place:
+            (x, c), aux = _remat(body_in_place, rcfg)((x, c), (p_l, i))
+        else:
+            c_l = (jax.tree.map(lambda a: a[i], cache_seg)
+                   if cache_seg is not None else None)
+            x, (c2, aux) = _remat(body, rcfg)(x, (p_l, c_l))
+            new_caches.append(c2)
+        per_layer.append(aux or {})
+    auxs = (jax.tree.map(lambda *xs: jnp.stack(xs), *per_layer)
+            if per_layer and per_layer[0] else {})
+    if in_place:
+        return x, c, over_layers(auxs)
     nc = (jax.tree.map(lambda *xs: jnp.stack(xs), *new_caches)
           if new_caches and new_caches[0] else None)
-    return x, nc, aux_acc
+    return x, nc, over_layers(auxs)
 
 
 # ---------------------------------------------------------------------------
@@ -208,7 +222,7 @@ def encode(params, frames, cfg: ModelConfig, shd: ShardingCtx, rcfg):
     seg = Segment("enc", cfg.encoder_layers, True)
     x, _, _ = run_segment(seg, enc["segments"][0], x, cfg, shd, rcfg,
                           mode="train", positions=pos)
-    return apply_norm(enc["final_norm"], x, cfg.norm)
+    return apply_norm(enc["final_norm"], x, cfg.norm, cfg.norm_eps)
 
 
 def _embed_in(params, tokens, cfg, shd):
@@ -234,7 +248,7 @@ def forward_train(params, batch: Dict, cfg: ModelConfig, shd: ShardingCtx,
                                 positions=positions, enc_out=enc_out)
         for k, v in (aux or {}).items():
             aux_all[k] = aux_all.get(k, 0.0) + v
-    x = apply_norm(params["final_norm"], x, cfg.norm)
+    x = apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
     logits = lm_logits(params["embed"], x, shd, cfg.logit_softcap)
     return logits, aux_all
 
@@ -249,10 +263,20 @@ def _to_ring(cache_leaf_kv, window: int, seq: int):
     return tail
 
 
+def _add_counts(total: Dict, aux: Dict) -> Dict:
+    """Sum the integer aux (counts, e.g. an expert layer's routed
+    assignments) of one segment into ``total``."""
+    for k, v in (aux or {}).items():
+        if jnp.issubdtype(v.dtype, jnp.integer):
+            total[k] = total[k] + v if k in total else v
+    return total
+
+
 def forward_prefill(params, tokens, cfg: ModelConfig, shd: ShardingCtx,
                     rcfg: RunConfig, *, max_seq: int, frames=None,
-                    cache_dtype: str = "bfloat16"):
-    """Full-sequence prefill. Returns (last_logits (B,V), caches)."""
+                    cache_dtype: str = "bfloat16", return_counts=False):
+    """Full-sequence prefill. Returns (last_logits (B,V), caches), and
+    with ``return_counts`` the layers' counts (``_add_counts``) third."""
     b, s = tokens.shape
     x = _embed_in(params, tokens, cfg, shd)
     positions = jnp.arange(s)
@@ -261,16 +285,20 @@ def forward_prefill(params, tokens, cfg: ModelConfig, shd: ShardingCtx,
         enc_out = encode(params, frames, cfg, shd, rcfg)
     schedule = build_schedule(cfg)
     caches_out = []
+    counts: Dict = {}
     for seg, p_seg in zip(schedule, params["segments"]):
-        x, cache_new, _ = run_segment(seg, p_seg, x, cfg, shd, rcfg,
-                                      mode="prefill", positions=positions,
-                                      enc_out=enc_out,
-                                      cache_seg=_prefill_cache_placeholder(
-                                          cfg, seg, b, cache_dtype))
+        x, cache_new, aux = run_segment(seg, p_seg, x, cfg, shd, rcfg,
+                                        mode="prefill", positions=positions,
+                                        enc_out=enc_out,
+                                        cache_seg=_prefill_cache_placeholder(
+                                            cfg, seg, b, cache_dtype))
         caches_out.append(_finalize_prefill_cache(
             cache_new, cfg, seg, s, max_seq, cache_dtype))
-    x = apply_norm(params["final_norm"], x, cfg.norm)
+        _add_counts(counts, aux)
+    x = apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
     logits = lm_logits(params["embed"], x[:, -1:], shd, cfg.logit_softcap)
+    if return_counts:
+        return logits[:, 0], tuple(caches_out), counts
     return logits[:, 0], tuple(caches_out)
 
 
@@ -322,21 +350,26 @@ def _to_ring_stacked(v, window, s):
 
 
 def forward_decode(params, caches, tokens, pos, cfg: ModelConfig,
-                   shd: ShardingCtx, rcfg: RunConfig):
-    """One decode step. tokens: (B,1); pos: (B,). Returns (logits, caches')."""
+                   shd: ShardingCtx, rcfg: RunConfig, return_counts=False):
+    """One decode step. tokens: (B,1); pos: (B,). Returns (logits, caches'),
+    and with ``return_counts`` the layers' counts (``_add_counts``) third."""
     x = embed_tokens(params["embed"], tokens, jnp.dtype(cfg.dtype))
     if cfg.family == "encdec":
         x = x + jax.vmap(lambda p: sinusoid_positions(p, cfg.d_model))(
             pos)[:, None].astype(x.dtype)
     x = shd.constrain_act(x)
     new_caches = []
+    counts: Dict = {}
     for seg, p_seg, c_seg in zip(build_schedule(cfg), params["segments"], caches):
-        x, c2, _ = run_segment(seg, p_seg, x, cfg, shd, rcfg, mode="decode",
-                               positions=pos, cache_seg=c_seg,
-                               decode_pos=pos)
+        x, c2, aux = run_segment(seg, p_seg, x, cfg, shd, rcfg,
+                                 mode="decode", positions=pos,
+                                 cache_seg=c_seg, decode_pos=pos)
         new_caches.append(c2)
-    x = apply_norm(params["final_norm"], x, cfg.norm)
+        _add_counts(counts, aux)
+    x = apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
     logits = lm_logits(params["embed"], x, shd, cfg.logit_softcap)
+    if return_counts:
+        return logits[:, 0], tuple(new_caches), counts
     return logits[:, 0], tuple(new_caches)
 
 

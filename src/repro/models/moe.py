@@ -11,9 +11,18 @@ materialized, which would be infeasible at prefill_32k scale):
      all-to-all / gather pattern the roofline's collective term reports),
   5. batched expert GEMMs, weighted scatter-add back.
 
+A layer told that it holds a share of the router's experts (expert
+parallelism, ``MoEConfig.holds_share``: experts ``first_expert`` ..
+``first_expert + num_experts - 1`` of ``router_experts``) routes over all
+of them and computes only its own experts' part, with no capacity and so
+no drops; the exchange with the chips that hold the rest is not part of
+this layer.
+
 Supports DeepSeek-V2 shared experts (always-on dense branch of size
-num_shared*shared_ff) and Arctic's parallel dense-residual branch.
-Aux losses: switch load-balance + router z-loss.
+num_shared*shared_ff), its group-limited greedy routing, and Arctic's
+parallel dense-residual branch. Aux losses: switch load-balance + router
+z-loss. Stable name scopes: ``moe``, ``moe/route``, ``moe/experts``,
+``moe/shared``.
 """
 from __future__ import annotations
 
@@ -33,7 +42,8 @@ def moe_schema(cfg: ModelConfig, mesh) -> Dict:
     pd = cfg.param_dtype
     glu = cfg.activation == "silu_glu"
     s: Dict = {
-        "router": ParamDesc((d, m.num_experts), ("embed", "experts"), "float32"),
+        "router": ParamDesc((d, m.router_experts), ("embed", "experts"),
+                            "float32"),
         "w_in": ParamDesc((m.num_experts, d, m.expert_ff),
                           ("experts", "embed", None), pd),
         "w_out": ParamDesc((m.num_experts, m.expert_ff, d),
@@ -56,13 +66,30 @@ def _capacity(tokens: int, m) -> int:
 
 
 def route_topk(router_w, x_flat, m) -> Tuple[jax.Array, jax.Array, Dict]:
-    """Returns (gate_weights (T,k), expert_idx (T,k), aux metrics)."""
+    """Returns (gate_weights (T,k), expert_idx (T,k), aux metrics).
+
+    Softmax scores over the router's ``router_experts`` outputs (f32).
+    With ``expert_groups`` > 1, group-limited greedy (DeepSeek-V2): each
+    group scores its best expert, the ``top_k_groups`` best groups are
+    kept and every other group's scores are zeroed before the top-k.
+    The top-k weights are the scores, divided by their sum where
+    ``renormalize_top_k``, times ``routed_scale``."""
     logits = jnp.einsum("td,de->te", f32(x_flat), f32(router_w))
     probs = jax.nn.softmax(logits, axis=-1)
-    gate, eidx = jax.lax.top_k(probs, m.top_k)
-    gate = gate / jnp.maximum(jnp.sum(gate, -1, keepdims=True), 1e-9)
-    # switch-style load-balance loss + router z-loss
     T, E = probs.shape
+    scores = probs
+    if m.expert_groups > 1:
+        grouped = probs.reshape(T, m.expert_groups, E // m.expert_groups)
+        _, keep = jax.lax.top_k(jnp.max(grouped, -1), m.top_k_groups)
+        kept = jnp.any(jax.nn.one_hot(keep, m.expert_groups,
+                                      dtype=jnp.bool_), axis=1)
+        scores = jnp.where(kept[..., None], grouped, 0.0).reshape(T, E)
+    gate, eidx = jax.lax.top_k(scores, m.top_k)
+    if m.renormalize_top_k:
+        gate = gate / jnp.maximum(jnp.sum(gate, -1, keepdims=True), 1e-9)
+    if m.routed_scale != 1.0:
+        gate = gate * m.routed_scale
+    # switch-style load-balance loss + router z-loss
     frac = jnp.zeros((E,), jnp.float32).at[eidx.reshape(-1)].add(1.0) / (T * m.top_k)
     imp = jnp.mean(probs, axis=0)
     lb_loss = E * jnp.sum(frac * imp)
@@ -101,12 +128,76 @@ def _dispatch_tables(eidx_g, gate_g, E: int, C: int, T_g: int, k: int):
 def apply_moe(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg) -> Tuple[jax.Array, Dict]:
     """x: (B, S, D) -> (y, aux).
 
-    GShard-style grouped dispatch: tokens are split into G groups (G = data
-    axis size), each group routes/sorts/truncates locally, so every
-    intermediate carries a leading group dim sharded over 'data' and an
-    expert dim sharded over 'model' — nothing is ever replicated. Capacity
-    is enforced per group (standard practice).
+    A layer that holds a share of the router's experts
+    (``MoEConfig.holds_share``) computes that share's part, dropless
+    (``_apply_held``). Otherwise GShard-style grouped dispatch: tokens are
+    split into G groups (G = data axis size), each group routes/sorts/
+    truncates locally, so every intermediate carries a leading group dim
+    sharded over 'data' and an expert dim sharded over 'model' — nothing
+    is ever replicated. Capacity is enforced per group (standard practice).
     """
+    with jax.named_scope("moe"):
+        if cfg.moe.holds_share:
+            return _apply_held(p, x, cfg, shd)
+        return _apply_capacity(p, x, cfg, shd)
+
+
+def _apply_held(p, x, cfg: ModelConfig, shd: ShardingCtx):
+    """The part of the layer's result that its held experts give, for
+    every token, with no capacity: routing is over all
+    ``router_experts``; an assignment to expert ``first_expert + j``
+    (j < ``num_experts``) is computed here with its weight, any other is
+    left to the chip that holds that expert. Every held expert runs on
+    every token and a token's output sums them with its weights (zero
+    where not routed), so no batch can drop a token. The shared experts
+    are added whole. ``aux`` carries int32 counts, summed over layers by
+    the segment runner: ``moe_assignments`` (T x top_k),
+    ``moe_assignments_held`` (those that land on a held expert) and
+    ``moe_experts_touched`` (held experts with at least one)."""
+    m = cfg.moe
+    b, s, d = x.shape
+    xf = x.reshape(b * s, d)
+    with jax.named_scope("route"):
+        gate, eidx, _ = route_topk(p["router"], xf, m)
+        local = eidx - m.first_expert                             # (T,k)
+        held = (local >= 0) & (local < m.num_experts)
+        onehot = jax.nn.one_hot(jnp.where(held, local, -1), m.num_experts,
+                                dtype=jnp.float32)                # (T,k,E)
+        w = jnp.einsum("tk,tke->te", gate, onehot)                # (T,E)
+        touched = jnp.any(jnp.sum(onehot, 1) > 0, axis=0)
+        aux = {"moe_assignments": jnp.asarray(eidx.size, jnp.int32),
+               "moe_assignments_held": jnp.sum(held, dtype=jnp.int32),
+               "moe_experts_touched": jnp.sum(touched, dtype=jnp.int32)}
+    with jax.named_scope("experts"):
+        h = jnp.einsum("td,edf->etf", xf, p["w_in"])
+        if cfg.activation == "silu_glu":
+            g = jnp.einsum("td,edf->etf", xf, p["w_gate"])
+            h = jax.nn.silu(f32(g)) * f32(h)
+        elif cfg.activation == "relu2":
+            h = jnp.square(jax.nn.relu(f32(h)))
+        else:
+            h = jax.nn.gelu(f32(h))
+        # the routing weight folds into the hidden activations, so the
+        # sum over experts is the contraction of the down projection
+        h = (h * w.T[..., None]).astype(x.dtype)
+        y = jnp.einsum("etf,efd->td", h, p["w_out"],
+                       preferred_element_type=jnp.float32)
+        y = y.astype(x.dtype).reshape(b, s, d)
+    y = _add_dense_branches(p, x, y, cfg, shd)
+    return y, aux
+
+
+def _add_dense_branches(p, x, y, cfg: ModelConfig, shd: ShardingCtx):
+    m = cfg.moe
+    if m.num_shared_experts:
+        with jax.named_scope("shared"):
+            y = y + apply_mlp(p["shared"], x, cfg.activation, shd)
+    if m.parallel_dense:
+        y = y + apply_mlp(p["dense"], x, cfg.activation, shd)
+    return y
+
+
+def _apply_capacity(p, x, cfg: ModelConfig, shd: ShardingCtx):
     m = cfg.moe
     b, s, d = x.shape
     T = b * s
@@ -118,7 +209,8 @@ def apply_moe(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg) -> Tuple[jax.Array
     C = _capacity(T_g, m)
 
     xf = x.reshape(T, d)
-    gate, eidx, aux = route_topk(p["router"], xf, m)
+    with jax.named_scope("route"):
+        gate, eidx, aux = route_topk(p["router"], xf, m)
     xg = xf.reshape(G, T_g, d)
     gate_g = gate.reshape(G, T_g, k)
     eidx_g = eidx.reshape(G, T_g, k)
@@ -156,9 +248,6 @@ def apply_moe(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg) -> Tuple[jax.Array
     y = jnp.sum(f32(picked) * w_flat.reshape(G, T_g, k)[..., None], axis=2)
     y = y.astype(x.dtype).reshape(b, s, d)
 
-    if m.num_shared_experts:
-        y = y + apply_mlp(p["shared"], x, cfg.activation, shd)
-    if m.parallel_dense:
-        y = y + apply_mlp(p["dense"], x, cfg.activation, shd)
+    y = _add_dense_branches(p, x, y, cfg, shd)
     aux["moe_drop_frac"] = jnp.mean(drop)
     return y, aux

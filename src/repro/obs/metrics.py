@@ -93,6 +93,13 @@ METRIC_HELP: Dict[str, str] = {
     "nk_engine_decode_steps_total": "Decode steps taken per engine",
     "nk_decode_cache_inplace_segments":
         "Cache segments the decode step writes in place (rest: masked select)",
+    "nk_moe_experts_held": "Routed experts this engine's expert layers hold",
+    "nk_moe_assignments_total":
+        "Routed (token, layer, expert) assignments, held or not",
+    "nk_moe_assignments_held_total":
+        "Routed assignments that land on a held expert",
+    "nk_moe_experts_touched_total":
+        "(program, layer, held expert) triples with at least one token",
     "nk_placement_ticks_total": "Placement autopilot ticks",
     "nk_placement_plans_applied_total": "Non-empty placement plans applied",
     "nk_placement_moves_total": "Autopilot migrations applied",

@@ -17,6 +17,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.configs.base import ModelConfig, RunConfig
 from repro.distribution.sharding import ShardingCtx, init_params
@@ -27,6 +28,13 @@ from repro.models.model import (
 )
 from repro.obs import tracing
 from repro.serve.scheduler import Request, TenantScheduler
+
+
+# the counts an expert layer that holds a share returns (``models.moe``),
+# summed over its layers, and the counters that total them
+ROUTED = {"moe_assignments": "nk_moe_assignments_total",
+          "moe_assignments_held": "nk_moe_assignments_held_total",
+          "moe_experts_touched": "nk_moe_experts_touched_total"}
 
 
 @dataclass
@@ -87,20 +95,65 @@ class ServeEngine(SchedulerServeModule):
             for seg, c in zip(build_schedule(cfg),
                               cache_schema(cfg, batch_slots, max_seq)))
 
-        cfg_, rcfg_, shd_ = cfg, rcfg, self.shd
+        # an expert layer that holds a share of the router's experts: its
+        # programs also sum the routed counts (ROUTED) on the device
+        self.counts_routing = cfg.moe is not None and cfg.moe.holds_share
+        self._routed = None
+        self._routed_total = np.zeros(len(ROUTED), np.int64)
 
-        def _prefill(params, tokens):
-            return forward_prefill(params, tokens, cfg_, shd_, rcfg_,
-                                   max_seq=max_seq)
+        self._programs(max_seq)
 
-        def _decode(params, caches, tokens, pos):
-            logits, caches = forward_decode(params, caches, tokens, pos,
-                                            cfg_, shd_, rcfg_)
+    def _programs(self, max_seq: int) -> None:
+        """The jitted prefill and (cache-donating) decode programs. Where
+        the expert layers hold a share, each program also takes the
+        running sum of the routed counts (``ROUTED``) as a last argument
+        and returns it with its own counts added, so the sum stays on the
+        device (no host sync per step; ``counters()`` reads it); the
+        engine's ``_prefill`` and ``_decode`` keep their signatures and
+        carry that sum in ``self._routed``."""
+        cfg_, rcfg_, shd_ = self.cfg, self.rcfg, self.shd
+
+        def summed(out, routed):
+            if not routed:
+                return out
+            *out, got = out
+            return (*out, routed[0] + jnp.stack([got[k] for k in ROUTED]))
+
+        def _prefill(params, tokens, *routed):
+            return summed(forward_prefill(
+                params, tokens, cfg_, shd_, rcfg_, max_seq=max_seq,
+                return_counts=bool(routed)), routed)
+
+        def _decode(params, caches, tokens, pos, *routed):
+            logits, caches, *got = forward_decode(
+                params, caches, tokens, pos, cfg_, shd_, rcfg_,
+                return_counts=bool(routed))
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return nxt, caches
+            return summed((nxt, caches, *got), routed)
 
-        self._prefill = jax.jit(_prefill)
-        self._decode = jax.jit(_decode, donate_argnums=(1,))
+        prefill = jax.jit(_prefill)
+        decode = jax.jit(_decode, donate_argnums=(1,))
+        if not self.counts_routing:
+            self._prefill, self._decode = prefill, decode
+            return
+        self._routed = self._zero_counts()
+
+        def counted(program):
+            def call(*args):
+                *out, self._routed = program(*args, self._routed)
+                return tuple(out)
+            return call
+
+        self._prefill, self._decode = counted(prefill), counted(decode)
+
+    def _zero_counts(self):
+        """Zero counts placed as the programs return them (replicated over
+        the mesh), so that the programs see one argument layout and never
+        compile again for it."""
+        z = jnp.zeros(len(ROUTED), jnp.int32)
+        if self.mesh is None:
+            return z
+        return jax.device_put(z, NamedSharding(self.mesh, PartitionSpec()))
 
     # -- StackModule buffer hooks (the suspend/resume memory story) --------
     def _make_slots(self):
@@ -126,10 +179,24 @@ class ServeEngine(SchedulerServeModule):
         self.caches = None
 
     def counters(self) -> Dict[str, float]:
-        """The decode program's static facts (Prometheus naming):
-        ``registry.register_provider(engine, name="engine")``."""
-        return {"nk_decode_cache_inplace_segments":
-                float(self.decode_inplace_segments)}
+        """The decode program's static facts and, for an expert layer that
+        holds a share, the routed counts of every prefill and decode so
+        far (Prometheus naming):
+        ``registry.register_provider(engine, name="engine")``.
+
+        The counts are summed on the device inside the programs and read
+        (one host sync) only here; the device's running sum then restarts
+        from zero, so it cannot wrap between two reads of 2**31
+        assignments."""
+        out = {"nk_decode_cache_inplace_segments":
+               float(self.decode_inplace_segments)}
+        if self.counts_routing:
+            self._routed_total += np.asarray(self._routed)
+            self._routed = self._zero_counts()
+            out["nk_moe_experts_held"] = float(self.cfg.moe.num_experts)
+            for name, v in zip(ROUTED.values(), self._routed_total):
+                out[name] = float(v)
+        return out
 
     # ------------------------------------------------------------------
     def submit(self, req: Request):

@@ -4,7 +4,8 @@ Every program here is built for a ``v5e:2x2`` topology that is described,
 not attached: shapes only, nothing runs. The tests pin what the serve path,
 the control tick, the NSM stacks and the Pallas kernels need to lower for
 the chip at real widths: internlm2-1.8b's decode and prefill within one
-chip's 16 GiB, the fused tick at 100k tenants, every psum routing policy
+chip's 16 GiB, DeepSeek-V2's decode at one chip's share with its latent
+cache written in place, the fused tick at 100k tenants, every psum routing policy
 on a (pod=2, data=2) mesh, and each kernel as a Mosaic ``tpu_custom_call``.
 
 The topology is described inside a module fixture (never at import), and
@@ -114,6 +115,51 @@ def test_internlm2_decode_writes_the_cache_in_place(internlm2_decode):
     assert internlm2_decode.memory_analysis().temp_size_in_bytes < 64 << 20
     stack_copies = re.findall(r"= bf16\[24,16,2048,8,128\]\S* copy\(",
                               internlm2_decode.as_text())
+    assert stack_copies == []
+
+
+@pytest.fixture(scope="module")
+def deepseek_v2_decode(one_chip):
+    """DeepSeek-V2 at one chip's share (9 layers, 10 of the router's 160
+    experts, the benchmark's cut), decode at 102 slots x 2048, cache
+    donated, with the routed counts."""
+    import dataclasses
+    import jax
+    import jax.numpy as jnp
+    from repro.configs import RunConfig, get_config
+    from repro.distribution.sharding import ShardingCtx, abstract_params
+    from repro.models.model import cache_schema, forward_decode, model_schema
+    cfg = get_config("deepseek-v2-236b")
+    cfg = dataclasses.replace(cfg, num_layers=9, moe=dataclasses.replace(
+        cfg.moe, num_experts=10))
+    shd, rcfg = ShardingCtx(one_chip), RunConfig()
+    params = _on(one_chip, abstract_params(model_schema(cfg, one_chip)))
+    slots, max_seq = 102, 2048
+    caches = _on(one_chip, abstract_params(cache_schema(cfg, slots, max_seq)))
+    tokens, pos = _on(one_chip, (
+        jax.ShapeDtypeStruct((slots, 1), jnp.int32),
+        jax.ShapeDtypeStruct((slots,), jnp.int32)))
+
+    def step(params, caches, tokens, pos):
+        logits, caches, counts = forward_decode(
+            params, caches, tokens, pos, cfg, shd, rcfg, return_counts=True)
+        return jnp.argmax(logits, axis=-1), caches, counts
+    return jax.jit(step, donate_argnums=(1,)).lower(
+        params, caches, tokens, pos).compile()
+
+
+def test_deepseek_v2_decode_writes_the_latent_cache_in_place(
+        deepseek_v2_decode):
+    """Both segments' latent rows go into the donated cache in place: the
+    program fits the chip, has no temp the size of a cache stack, and
+    copies no whole [8,96,2048,640] stack (with an unpadded 576-value row
+    the chip's layout made it copy the stack in and out: 2.5 GB of
+    temp)."""
+    import re
+    assert _device_bytes(deepseek_v2_decode) < V5E_HBM
+    assert deepseek_v2_decode.memory_analysis().temp_size_in_bytes < 64 << 20
+    stack_copies = re.findall(r"= bf16\[8,96,2048,640\]\S* copy\(",
+                              deepseek_v2_decode.as_text())
     assert stack_copies == []
 
 

@@ -82,8 +82,10 @@ def test_prefill_decode_parity(name, mesh1, rcfg_small):
 
 
 def test_param_counts_match_analytic():
-    """Analytic num_params (used by the roofline) vs materialized params."""
-    for name in ("llama3.2-3b", "internlm2-1.8b", "mamba2-370m"):
+    """Analytic num_params (used by the roofline) vs materialized params
+    (deepseek-v2: latent attention with the low-rank q)."""
+    for name in ("llama3.2-3b", "internlm2-1.8b", "mamba2-370m",
+                 "deepseek-v2-236b"):
         cfg = get_smoke_config(name)
         from repro.launch.mesh import make_single_device_mesh
         mesh = make_single_device_mesh()
