@@ -17,6 +17,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.configs.base import ModelConfig, RunConfig
@@ -35,6 +36,24 @@ from repro.serve.scheduler import Request, TenantScheduler
 ROUTED = {"moe_assignments": "nk_moe_assignments_total",
           "moe_assignments_held": "nk_moe_assignments_held_total",
           "moe_experts_touched": "nk_moe_experts_touched_total"}
+
+
+def _install_slot(caches, one, slot):
+    """Write a batch-1 prefill's cache ``one`` into slot ``slot`` of the
+    engine's ``caches``. Every cache leaf (dense K/V, the MLA latent, SSM
+    state; scanned, unrolled and ring segments alike) is stacked as
+    ``[layers, slots, ...]``, so the slot is axis 1 of each."""
+    return jax.tree.map(
+        lambda big, o: lax.dynamic_update_slice_in_dim(
+            big, o.astype(big.dtype), slot, axis=1),
+        caches, one)
+
+
+# the cache is donated and the slot traced: the update lands in the engine's
+# own buffers, with no second cache alive, and one program per cache shape
+# serves every slot of every engine (cluster replicas and swapped-in
+# engines included)
+install_slot = jax.jit(_install_slot, donate_argnums=0)
 
 
 @dataclass
@@ -86,6 +105,7 @@ class ServeEngine(SchedulerServeModule):
         self._init_caches()
         self.steps = 0
         self.decode_steps = 0
+        self.installs = 0      # prefill caches written into a slot
         self.completed: List[Request] = []
         # cache segments whose new K/V rows the decode program writes in
         # place (the rest take the masked select): fixed by the model and
@@ -226,12 +246,11 @@ class ServeEngine(SchedulerServeModule):
                 prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
                 with region("engine", "prefill"):
                     last_logits, caches1 = self._prefill(self.params, prompt)
-                # install the single-sequence cache into slot i
+                # install the single-sequence cache into slot i, in place
                 with region("engine", "install"):
-                    self.caches = jax.tree.map(
-                        lambda big, one: big.at[:, i].set(
-                            one[:, 0].astype(big.dtype)),
-                        self.caches, caches1)
+                    self.caches = install_slot(self.caches, caches1,
+                                               np.int32(i))
+                self.installs += 1
                 with region("engine", "first_token"):
                     first = int(jnp.argmax(last_logits[0]))
                 req.generated.append(first)

@@ -5,8 +5,10 @@ not attached: shapes only, nothing runs. The tests pin what the serve path,
 the control tick, the NSM stacks and the Pallas kernels need to lower for
 the chip at real widths: internlm2-1.8b's decode and prefill within one
 chip's 16 GiB, DeepSeek-V2's decode at one chip's share with its latent
-cache written in place, the fused tick at 100k tenants, every psum routing policy
-on a (pod=2, data=2) mesh, and each kernel as a Mosaic ``tpu_custom_call``.
+cache written in place, the engine's install of a prefill cache into its
+slot in place for both, the fused tick at 100k tenants, every psum routing
+policy on a (pod=2, data=2) mesh, and each kernel as a Mosaic
+``tpu_custom_call``.
 
 The topology is described inside a module fixture (never at import), and
 the tests skip from there where the TPU compiler is not installed. JAX's
@@ -81,16 +83,53 @@ def _device_bytes(compiled):
     return mem.argument_size_in_bytes + mem.temp_size_in_bytes
 
 
-@pytest.fixture(scope="module")
-def internlm2_decode(internlm2, one_chip):
-    """The serve step's decode at 16 slots x 2048, cache donated."""
+def _caches(cfg, mesh, slots, max_seq=2048):
+    """The engine's cache of ``slots`` slots, as shapes on ``mesh``."""
+    from repro.distribution.sharding import abstract_params
+    from repro.models.model import cache_schema
+    return _on(mesh, abstract_params(cache_schema(cfg, slots, max_seq)))
+
+
+def _stack_copies(compiled, caches):
+    """The copies of a whole cache stack in the compiled program, for every
+    leaf shape of ``caches``."""
+    import re
+    import jax
+    hlo = compiled.as_text()
+    found = []
+    for leaf in jax.tree.leaves(caches):
+        dtype = {"bfloat16": "bf16", "float32": "f32"}[str(leaf.dtype)]
+        shape = ",".join(map(str, leaf.shape))
+        found += re.findall(rf"= {dtype}\[{shape}\]\S* copy\(", hlo)
+    return found
+
+
+def _install(cfg, mesh, caches):
+    """The engine's install of a batch-1 prefill cache into one slot of
+    ``caches``, cache donated and slot traced."""
     import jax
     import jax.numpy as jnp
-    from repro.distribution.sharding import abstract_params
-    from repro.models.model import cache_schema, forward_decode
+    from repro.serve.engine import install_slot
+    one = _caches(cfg, mesh, 1)
+    slot = _on(mesh, jax.ShapeDtypeStruct((), jnp.int32))
+    return install_slot.lower(caches, one, slot).compile()
+
+
+@pytest.fixture(scope="module")
+def internlm2_caches(internlm2, one_chip):
+    """The serve step's cache: 16 slots x 2048."""
+    return _caches(internlm2[0], one_chip, 16)
+
+
+@pytest.fixture(scope="module")
+def internlm2_decode(internlm2, internlm2_caches, one_chip):
+    """The serve step's decode, cache donated."""
+    import jax
+    import jax.numpy as jnp
+    from repro.models.model import forward_decode
     cfg, rcfg, shd, params = internlm2
-    slots, max_seq = 16, 2048
-    caches = _on(one_chip, abstract_params(cache_schema(cfg, slots, max_seq)))
+    caches = internlm2_caches
+    slots = jax.tree.leaves(caches)[0].shape[1]
     tokens, pos = _on(one_chip, (
         jax.ShapeDtypeStruct((slots, 1), jnp.int32),
         jax.ShapeDtypeStruct((slots,), jnp.int32)))
@@ -107,35 +146,48 @@ def test_internlm2_decode_fits_one_chip(internlm2_decode):
     assert _device_bytes(internlm2_decode) < V5E_HBM
 
 
-def test_internlm2_decode_writes_the_cache_in_place(internlm2_decode):
+def test_internlm2_decode_writes_the_cache_in_place(internlm2_decode,
+                                                internlm2_caches):
     """The new K/V rows go into the donated cache in place: no temp the
     size of a cache stack, and no copy of a whole [24,16,2048,8,128] stack
     after the layer loop."""
-    import re
     assert internlm2_decode.memory_analysis().temp_size_in_bytes < 64 << 20
-    stack_copies = re.findall(r"= bf16\[24,16,2048,8,128\]\S* copy\(",
-                              internlm2_decode.as_text())
-    assert stack_copies == []
+    assert _stack_copies(internlm2_decode, internlm2_caches) == []
+
+
+def test_internlm2_install_writes_the_slot_in_place(internlm2, one_chip,
+                                                    internlm2_caches):
+    """An admission's prefill cache goes into its slot of the donated
+    cache: no temp the size of a cache stack, no copy of a whole stack."""
+    compiled = _install(internlm2[0], one_chip, internlm2_caches)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    assert _stack_copies(compiled, internlm2_caches) == []
 
 
 @pytest.fixture(scope="module")
-def deepseek_v2_decode(one_chip):
+def deepseek_v2(one_chip):
     """DeepSeek-V2 at one chip's share (9 layers, 10 of the router's 160
-    experts, the benchmark's cut), decode at 102 slots x 2048, cache
-    donated, with the routed counts."""
+    experts, the benchmark's cut) and its cache of 102 slots x 2048."""
     import dataclasses
-    import jax
-    import jax.numpy as jnp
     from repro.configs import RunConfig, get_config
     from repro.distribution.sharding import ShardingCtx, abstract_params
-    from repro.models.model import cache_schema, forward_decode, model_schema
+    from repro.models.model import model_schema
     cfg = get_config("deepseek-v2-236b")
     cfg = dataclasses.replace(cfg, num_layers=9, moe=dataclasses.replace(
         cfg.moe, num_experts=10))
     shd, rcfg = ShardingCtx(one_chip), RunConfig()
     params = _on(one_chip, abstract_params(model_schema(cfg, one_chip)))
-    slots, max_seq = 102, 2048
-    caches = _on(one_chip, abstract_params(cache_schema(cfg, slots, max_seq)))
+    return cfg, rcfg, shd, params, _caches(cfg, one_chip, 102)
+
+
+@pytest.fixture(scope="module")
+def deepseek_v2_decode(deepseek_v2, one_chip):
+    """DeepSeek-V2's decode, cache donated, with the routed counts."""
+    import jax
+    import jax.numpy as jnp
+    from repro.models.model import forward_decode
+    cfg, rcfg, shd, params, caches = deepseek_v2
+    slots = jax.tree.leaves(caches)[0].shape[1]
     tokens, pos = _on(one_chip, (
         jax.ShapeDtypeStruct((slots, 1), jnp.int32),
         jax.ShapeDtypeStruct((slots,), jnp.int32)))
@@ -149,18 +201,26 @@ def deepseek_v2_decode(one_chip):
 
 
 def test_deepseek_v2_decode_writes_the_latent_cache_in_place(
-        deepseek_v2_decode):
+        deepseek_v2_decode, deepseek_v2):
     """Both segments' latent rows go into the donated cache in place: the
     program fits the chip, has no temp the size of a cache stack, and
-    copies no whole [8,96,2048,640] stack (with an unpadded 576-value row
-    the chip's layout made it copy the stack in and out: 2.5 GB of
+    copies no whole [1 or 8,102,2048,640] stack (with an unpadded 576-value
+    row the chip's layout made it copy the stack in and out: 2.5 GB of
     temp)."""
-    import re
+    caches = deepseek_v2[-1]
     assert _device_bytes(deepseek_v2_decode) < V5E_HBM
     assert deepseek_v2_decode.memory_analysis().temp_size_in_bytes < 64 << 20
-    stack_copies = re.findall(r"= bf16\[8,96,2048,640\]\S* copy\(",
-                              deepseek_v2_decode.as_text())
-    assert stack_copies == []
+    assert _stack_copies(deepseek_v2_decode, caches) == []
+
+
+def test_deepseek_v2_install_writes_the_slot_in_place(deepseek_v2, one_chip):
+    """An admission's latent cache goes into its slot of the donated
+    102-slot cache: no temp the size of a cache stack, no copy of a whole
+    stack."""
+    cfg, caches = deepseek_v2[0], deepseek_v2[-1]
+    compiled = _install(cfg, one_chip, caches)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    assert _stack_copies(compiled, caches) == []
 
 
 def test_internlm2_prefill_fits_one_chip(internlm2, one_chip):
