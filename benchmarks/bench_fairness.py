@@ -167,17 +167,9 @@ ALL = (run_convergence, run_isolation, run_backfill)
 E2E_TENANTS = 4
 E2E_INTERVALS = 18
 
-# control-plane backend for every e2e engine/cluster this process builds:
-# "object" (per-tenant Python state) or "vectorized" (flat-array telemetry
-# banks, BucketStore admission buckets, the fused jitted water-fill).
-# Set by --backend; the e2e claims must hold under either.
-BACKEND = "object"
-
-
 def _e2e_report(trace, capacity, push_mode="full"):
     from repro.serve.replay import TraceReplayer, make_replay_engine
-    eng = make_replay_engine(capacity=capacity, push_mode=push_mode,
-                             backend=BACKEND)
+    eng = make_replay_engine(capacity=capacity, push_mode=push_mode)
     return TraceReplayer(eng, capacity=capacity).run(trace)
 
 
@@ -274,8 +266,7 @@ def run_e2e_multi_engine(engines: int = 3) -> Dict:
     base_trace = adversarial_baseline(trace)
 
     def run(tr, events=None):
-        cl = make_replay_cluster(capacity=cap, engines=engines,
-                                 backend=BACKEND)
+        cl = make_replay_cluster(capacity=cap, engines=engines)
         return TraceReplayer(cl, capacity=cap).run(tr, events=events), cl
 
     base, _ = run(base_trace)
@@ -337,8 +328,7 @@ def _autopilot_cluster(capacity, engines, policy):
     conservation-checks) both planes."""
     from repro.serve.replay import make_replay_cluster
     return make_replay_cluster(capacity=capacity, engines=engines,
-                               autopilot=policy, core_plane=True,
-                               backend=BACKEND)
+                               autopilot=policy, core_plane=True)
 
 
 def _byte_pump(cluster, op_bytes=4096):
@@ -527,7 +517,7 @@ def run_e2e_stack_swap(engines: int = 3,
 
     def run(with_swaps):
         cl = make_replay_cluster(capacity=cap, engines=engines,
-                                 core_plane=True, backend=BACKEND)
+                                 core_plane=True)
         pump, pumped = _byte_pump(cl)
         events = [(i, pump) for i in range(intervals)]
         if with_swaps:
@@ -596,7 +586,7 @@ def run_e2e_failover(engines: int = 3,
     trace, cap = scenario_spec("failover", n_tenants=n,
                                intervals=intervals)
     cl = make_replay_cluster(capacity=cap, engines=engines,
-                             core_plane=True, backend=BACKEND)
+                             core_plane=True)
     op_bytes = 4096
     pump, pumped = _byte_pump(cl, op_bytes=op_bytes)
     rep = TraceReplayer(cl, capacity=cap).run(
@@ -798,17 +788,16 @@ def run_e2e_watchdog(engines: int = 3,
     hog = str(n - 1)
 
     t0 = time.perf_counter()
-    replay_scenario("steady", n_tenants=n, intervals=intervals,
-                    backend=BACKEND)
+    replay_scenario("steady", n_tenants=n, intervals=intervals)
     base_wall = time.perf_counter() - t0
     steady = replay_scenario("steady", n_tenants=n, intervals=intervals,
-                             watch=True, backend=BACKEND)
+                             watch=True)
     adv = replay_scenario("adversarial", n_tenants=n, intervals=intervals,
-                          watch=True, backend=BACKEND)
+                          watch=True)
     fail = replay_scenario("failover", n_tenants=n, intervals=intervals,
-                           engines=engines, watch="record", backend=BACKEND)
+                           engines=engines, watch="record")
     swap = replay_scenario("stack_swap", n_tenants=n, intervals=intervals,
-                           engines=engines, watch=True, backend=BACKEND)
+                           engines=engines, watch=True)
     _WATCHDOG_REPORTS.update(steady=steady, adversarial=adv,
                              failover=fail, stack_swap=swap)
 
@@ -890,10 +879,9 @@ def _parse_args(argv):
     opts = {"e2e": "--e2e" in argv, "smoke": "--smoke" in argv,
             "autopilot": "--autopilot" in argv, "engines": 1,
             "json": None, "trace": None, "swap-trace": None,
-            "failover-trace": None, "alerts": None, "scrapes": None,
-            "backend": "object"}
+            "failover-trace": None, "alerts": None, "scrapes": None}
     for flag in ("--engines", "--json", "--trace", "--swap-trace",
-                 "--failover-trace", "--alerts", "--scrapes", "--backend"):
+                 "--failover-trace", "--alerts", "--scrapes"):
         if flag in argv:
             i = argv.index(flag)
             if i + 1 >= len(argv) or argv[i + 1].startswith("--"):
@@ -922,19 +910,11 @@ def _parse_args(argv):
     if (opts["alerts"] or opts["scrapes"]) and not opts["autopilot"]:
         raise SystemExit("--alerts/--scrapes dump the watchdog claim's "
                          "artifacts: add --e2e --autopilot")
-    if opts["backend"] not in ("object", "vectorized"):
-        raise SystemExit(f"--backend must be 'object' or 'vectorized', "
-                         f"got {opts['backend']!r}")
-    if opts["backend"] != "object" and not opts["e2e"]:
-        raise SystemExit("--backend selects the e2e control plane: "
-                         "add --e2e")
     return opts
 
 
 def main(argv=None) -> None:
-    global BACKEND
     opts = _parse_args(sys.argv[1:] if argv is None else argv)
-    BACKEND = opts["backend"]
     intervals = SMOKE_INTERVALS if opts["smoke"] else E2E_INTERVALS
     benches = []
     if not opts["smoke"]:
@@ -975,8 +955,7 @@ def main(argv=None) -> None:
         from repro.serve.replay import replay_scenario
         replay_scenario("migration", n_tenants=E2E_TENANTS,
                         intervals=max(intervals, SMOKE_INTERVALS),
-                        trace_path=opts["trace"],
-                        backend=BACKEND)
+                        trace_path=opts["trace"])
         print(f"wrote {opts['trace']} (migration scenario trace)",
               file=sys.stderr)
     if opts["swap-trace"]:
@@ -986,8 +965,7 @@ def main(argv=None) -> None:
         from repro.serve.replay import replay_scenario
         replay_scenario("stack_swap", n_tenants=E2E_TENANTS,
                         intervals=max(intervals, SMOKE_INTERVALS),
-                        trace_path=opts["swap-trace"],
-                        backend=BACKEND)
+                        trace_path=opts["swap-trace"])
         print(f"wrote {opts['swap-trace']} (stack_swap scenario trace)",
               file=sys.stderr)
     if opts["failover-trace"]:
@@ -997,8 +975,7 @@ def main(argv=None) -> None:
         from repro.serve.replay import replay_scenario
         replay_scenario("failover", n_tenants=E2E_TENANTS,
                         intervals=max(intervals, SMOKE_INTERVALS),
-                        trace_path=opts["failover-trace"],
-                        backend=BACKEND)
+                        trace_path=opts["failover-trace"])
         print(f"wrote {opts['failover-trace']} (failover scenario trace)",
               file=sys.stderr)
     if opts["alerts"]:
@@ -1036,7 +1013,6 @@ def main(argv=None) -> None:
         doc = {"ok": failures == 0,
                "suite": ("smoke" if opts["smoke"] else
                          "e2e" if opts["e2e"] else "fluid"),
-               "backend": opts["backend"],
                "engines": opts["engines"],
                "intervals": intervals if opts["e2e"] else None,
                "results": results,

@@ -13,8 +13,6 @@ published width of internlm2-1.8b (24 layers, d_model 2048, 16 heads with
            compiled prefill/decode: one live migrate and one serve-plane
            stack swap (wfq -> rr) under traffic, then ledger conservation
            for every tenant and no dropped tokens.
-  control  the vectorized control tick at 10k tenants, against the scalar
-           max_min_fair water-fill.
 
 ``--four-chips`` runs only the NSM collective phase, on a (pod=2, data=2)
 mesh of four chips: nk_psum(gradient=True) under all five routing
@@ -44,10 +42,6 @@ MAX_SEQ = 2048
 PROMPT_LENS = (16, 128, 512)
 NEW_TOKENS = 32
 CAPACITY = 1e6            # tokens/s: the controller shapes, never stalls
-CONTROL_TENANTS = 10_000
-CONTROL_CAPACITY = 1e6
-# the fused tick's bound against max_min_fair, as a share of capacity
-CONTROL_TOL = 1e-6
 # Served and reference logits are bf16, each rounded from a hidden state
 # that 24 layers of bf16 arithmetic reached in a different order (cached
 # decode against one full forward). Random weights make the logits flat
@@ -285,43 +279,6 @@ def fabric_phase(cfg, rcfg, mesh, base, rng, clock):
     return reqs
 
 
-def control_phase(rng, clock):
-    import numpy as np
-    from repro.control.congestion import max_min_fair
-    from repro.control.vectorized import VectorizedControlPlane
-
-    t0, c0 = time.perf_counter(), clock.seconds
-    n = CONTROL_TENANTS
-    weights = rng.choice([1.0, 2.0, 4.0], size=n)
-    steps = np.maximum(np.round(rng.uniform(0.2, 2.0, size=n)
-                                * (CONTROL_CAPACITY / n)), 1.0)
-    backlogged = rng.random(n) < 0.1
-    headroom = 1.25
-    plane = VectorizedControlPlane(CONTROL_CAPACITY, alpha=0.5,
-                                   headroom=headroom)
-    for t in range(n):
-        plane.add_tenant(t, weight=float(weights[t]))
-    queue = np.where(backlogged, 1.0, 0.0)
-    served = np.zeros(n)
-    for now in (0.0, 1.0, 2.0):
-        served = served + steps
-        alloc = plane.tick(served, queue=queue, now=now)
-    demands = {t: (np.inf if backlogged[t] else float(steps[t]) * headroom)
-               for t in range(n)}
-    ref = max_min_fair(CONTROL_CAPACITY, demands,
-                       {t: float(weights[t]) for t in range(n)})
-    want = np.array([ref[t] for t in range(n)])
-    err = float(np.max(np.abs(alloc[:n] - want)))
-    print(f"[control] {n} tenants: max |alloc - "
-          f"max_min_fair| = {err!r} ({err / CONTROL_CAPACITY!r} x capacity;"
-          f" bound {CONTROL_TOL} x capacity); sum {float(alloc.sum())!r} of "
-          f"{CONTROL_CAPACITY}")
-    check(err <= CONTROL_TOL * CONTROL_CAPACITY,
-          f"control tick off max_min_fair by {err / CONTROL_CAPACITY!r} x "
-          f"capacity")
-    smoke_figures("control", t0, c0, clock)
-
-
 def nsm_phase(seed):
     """NSM collective stacks on a (pod=2, data=2) mesh of four chips."""
     import jax
@@ -437,7 +394,6 @@ def main(argv=None) -> int:
         eng.caches = None
         fab = fabric_phase(cfg, rcfg, mesh, eng, rng, clock)
         reference_check(reference, eng.params, fab, "fabric")
-        control_phase(rng, clock)
     smoke_figures("total", t0, 0.0, clock)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

@@ -81,20 +81,13 @@ class WaterFill(CongestionControl):
     bids ``inf`` and receives a fair share of the residual. A satisfied
     tenant bids its observed offered rate times ``headroom`` so its
     allocation can track demand growth between intervals.
-
-    ``backend="vectorized"`` runs the fill as one jitted array op
-    (``repro.kernels.ops.water_fill``) instead of the scalar loop —
-    same allocations within 1e-6 x capacity, flat cost per tenant.
     """
 
     def __init__(self, weights: Optional[Mapping[int, float]] = None,
-                 headroom: float = 1.25, min_rate: float = 0.0,
-                 backend: str = "object"):
-        from repro.control.vectorized import check_backend
+                 headroom: float = 1.25, min_rate: float = 0.0):
         self.weights = dict(weights or {})
         self.headroom = headroom
         self.min_rate = min_rate
-        self.backend = check_backend(backend)
 
     def allocate(self, obs, capacity):
         # deferral is EWMA-smoothed, so it decays toward zero but never
@@ -105,11 +98,7 @@ class WaterFill(CongestionControl):
         demands = {t: (INF if (o.deferred > eps or o.queue > 0)
                        else o.offered * self.headroom)
                    for t, o in obs.items()}
-        if self.backend == "vectorized":
-            from repro.control.vectorized import waterfill_allocate
-            alloc = waterfill_allocate(demands, capacity, self.weights)
-        else:
-            alloc = max_min_fair(capacity, demands, self.weights)
+        alloc = max_min_fair(capacity, demands, self.weights)
         if self.min_rate > 0:
             alloc = {t: max(r, self.min_rate) for t, r in alloc.items()}
         return alloc

@@ -47,7 +47,7 @@ class RateController:
                  weights: Optional[Dict[int, float]] = None,
                  alpha: float = 0.5, burst_s: float = 0.25,
                  push_mode: str = "full", delta_tol: float = 0.05,
-                 refresh_every: int = 32, backend: str = "object"):
+                 refresh_every: int = 32):
         """``capacity``: the ONE shared bottleneck in units/s — bytes/s
         when the enforcement points are CoreEngines, tokens/s when they
         are TenantSchedulers (don't mix units under one controller).
@@ -56,18 +56,12 @@ class RateController:
         ``burst_s``: pushed bucket burst, in seconds' worth of the
         allocated rate. ``delta_tol``: relative move that makes a target
         worth pushing in delta mode; ``refresh_every``: ticks between
-        delta-mode full re-pushes (soft-state bound). ``backend``:
-        "object" keeps per-tenant control state in Python objects,
-        "vectorized" in flat arrays (telemetry EWMA banks + the jitted
-        array water-fill) — same allocations, flat cost per tenant."""
-        from repro.control.vectorized import check_backend
+        delta-mode full re-pushes (soft-state bound)."""
         if push_mode not in ("full", "delta"):
             raise ValueError(f"push_mode must be 'full' or 'delta', "
                              f"got {push_mode!r}")
         self.capacity = float(capacity)
-        self.backend = check_backend(backend)
-        self.algo = algo if algo is not None \
-            else WaterFill(weights, backend=backend)
+        self.algo = algo if algo is not None else WaterFill(weights)
         self.alpha = alpha
         self.burst_s = burst_s
         # delta mode: only tenants whose per-point allocation moved beyond
@@ -98,8 +92,7 @@ class RateController:
         ``axes``: restrict telemetry to CommOps intersecting these mesh
         axes (None = meter everything). Returns self for chaining."""
         self._engines.append(
-            (engine, EngineTelemetry(engine, self.alpha, axes,
-                                     backend=self.backend)))
+            (engine, EngineTelemetry(engine, self.alpha, axes)))
         return self
 
     def attach_scheduler(self, scheduler):
@@ -107,8 +100,7 @@ class RateController:
         Several schedulers may share this controller's one ``capacity`` —
         the multi-engine cluster case. Returns self for chaining."""
         self._schedulers.append(
-            (scheduler, SchedulerTelemetry(scheduler, self.alpha,
-                                           backend=self.backend)))
+            (scheduler, SchedulerTelemetry(scheduler, self.alpha)))
         return self
 
     def detach_scheduler(self, scheduler) -> None:

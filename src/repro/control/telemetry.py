@@ -80,27 +80,18 @@ class EngineTelemetry:
     was exported/reset behind our back (live migration folds its ledger
     out of this engine), so its EWMA resets and the new value becomes the
     baseline instead of being read as a hugely negative rate.
-
-    ``backend="vectorized"`` keeps the EWMA state in flat arrays
-    (:class:`repro.control.vectorized.TelemetryBank`) instead of
-    per-tenant ``_Ewma`` objects — same observations, flat cost.
     """
 
     def __init__(self, engine, alpha: float = 0.5,
-                 axes_filter: Optional[Iterable[str]] = None,
-                 backend: str = "object"):
-        from repro.control.vectorized import TelemetryBank, check_backend
+                 axes_filter: Optional[Iterable[str]] = None):
         self.engine = engine
         self.alpha = alpha
         self.axes_filter = None if axes_filter is None else set(axes_filter)
-        self.backend = check_backend(backend)
         self._prev_offered: Dict[int, int] = {}
         self._prev_deferred: Dict[int, int] = {}
         self._prev_t: Optional[float] = None
         self._offered_ewma: Dict[int, _Ewma] = {}
         self._deferred_ewma: Dict[int, _Ewma] = {}
-        self._bank = TelemetryBank(alpha) if backend == "vectorized" \
-            else None
         self.obs: Dict[int, TenantObs] = {}
         self.updates = 0
 
@@ -113,13 +104,9 @@ class EngineTelemetry:
         self._offered_ewma.pop(tenant, None)
         self._deferred_ewma.pop(tenant, None)
         self.obs.pop(tenant, None)
-        if self._bank is not None:
-            self._bank.evict(tenant)
 
     def tracked_tenants(self) -> set:
         """Tenants with live EWMA/baseline state (leak regression hook)."""
-        if self._bank is not None:
-            return set(self._bank.tenants())
         return (set(self._prev_offered) | set(self._offered_ewma)
                 | set(self._deferred_ewma))
 
@@ -149,48 +136,29 @@ class EngineTelemetry:
             # first sample (or time stood still): establish the baseline
             self._prev_offered, self._prev_deferred = offered, deferred
             self._prev_t = now
-            if self._bank is not None:
-                self._bank.baseline(offered, deferred)
             self.obs = {t: TenantObs() for t in offered}
             return self.obs
         dt = now - self._prev_t
         self.obs = {}
-        if self._bank is not None:
-            union = set(offered) | set(self._prev_offered)
-            tenants, offs, dfrs, reset = self._bank.update(
-                offered, dt, deferred=deferred)
-            for i, t in enumerate(tenants):
-                if t not in union:
-                    continue
-                if reset[i]:
-                    if t in offered:
-                        self.obs[t] = TenantObs()
-                    continue
-                off, dfr = float(offs[i]), float(dfrs[i])
-                self.obs[t] = TenantObs(rate=max(off - dfr, 0.0),
-                                        offered=off, deferred=dfr)
-        else:
-            for t in set(offered) | set(self._prev_offered):
-                d_off = (offered.get(t, 0)
-                         - self._prev_offered.get(t, 0)) / dt
-                d_def = (deferred.get(t, 0)
-                         - self._prev_deferred.get(t, 0)) / dt
-                vanished = t not in offered and t in self._prev_offered
-                if d_off < 0 or d_def < 0 or vanished:
-                    # counter reset (migration fold / crash wipe):
-                    # rebaseline instead of reading a negative rate
-                    self._offered_ewma.pop(t, None)
-                    self._deferred_ewma.pop(t, None)
-                    if t in offered:
-                        self.obs[t] = TenantObs()
-                    continue
-                off = self._offered_ewma.setdefault(t, _Ewma(self.alpha)) \
-                    .update(d_off)
-                dfr = self._deferred_ewma.setdefault(t, _Ewma(self.alpha)) \
-                    .update(d_def)
-                dfr = min(dfr, off)
-                self.obs[t] = TenantObs(rate=max(off - dfr, 0.0),
-                                        offered=off, deferred=dfr)
+        for t in set(offered) | set(self._prev_offered):
+            d_off = (offered.get(t, 0) - self._prev_offered.get(t, 0)) / dt
+            d_def = (deferred.get(t, 0) - self._prev_deferred.get(t, 0)) / dt
+            vanished = t not in offered and t in self._prev_offered
+            if d_off < 0 or d_def < 0 or vanished:
+                # counter reset (migration fold / crash wipe):
+                # rebaseline instead of reading a negative rate
+                self._offered_ewma.pop(t, None)
+                self._deferred_ewma.pop(t, None)
+                if t in offered:
+                    self.obs[t] = TenantObs()
+                continue
+            off = self._offered_ewma.setdefault(t, _Ewma(self.alpha)) \
+                .update(d_off)
+            dfr = self._deferred_ewma.setdefault(t, _Ewma(self.alpha)) \
+                .update(d_def)
+            dfr = min(dfr, off)
+            self.obs[t] = TenantObs(rate=max(off - dfr, 0.0),
+                                    offered=off, deferred=dfr)
         self._prev_offered, self._prev_deferred = offered, deferred
         self._prev_t = now
         self.updates += 1
@@ -231,25 +199,16 @@ class SchedulerTelemetry:
     tenant's ledger out of the source scheduler mid-run — so its EWMA is
     reset and the new counter value becomes the baseline instead of being
     read as a hugely negative rate.
-
-    ``backend="vectorized"`` keeps the EWMA state in flat arrays
-    (:class:`repro.control.vectorized.TelemetryBank`) instead of
-    per-tenant ``_Ewma`` objects — same observations, flat cost.
     """
 
-    def __init__(self, scheduler, alpha: float = 0.5,
-                 backend: str = "object"):
+    def __init__(self, scheduler, alpha: float = 0.5):
         """``scheduler``: a live TenantScheduler; ``alpha``: EWMA gain in
         (0, 1] — 1.0 = no smoothing, use the raw per-interval rate."""
-        from repro.control.vectorized import TelemetryBank, check_backend
         self.scheduler = scheduler
         self.alpha = alpha
-        self.backend = check_backend(backend)
         self._prev_served: Dict[int, int] = {}
         self._prev_t: Optional[float] = None
         self._ewma: Dict[int, _Ewma] = {}
-        self._bank = TelemetryBank(alpha) if backend == "vectorized" \
-            else None
         self.obs: Dict[int, TenantObs] = {}
         self.updates = 0
 
@@ -260,13 +219,9 @@ class SchedulerTelemetry:
         self._prev_served.pop(tenant, None)
         self._ewma.pop(tenant, None)
         self.obs.pop(tenant, None)
-        if self._bank is not None:
-            self._bank.evict(tenant)
 
     def tracked_tenants(self) -> set:
         """Tenants with live EWMA/baseline state (leak regression hook)."""
-        if self._bank is not None:
-            return set(self._bank.tenants())
         return set(self._prev_served) | set(self._ewma)
 
     def update(self, now: Optional[float] = None) -> Dict[int, TenantObs]:
@@ -279,40 +234,22 @@ class SchedulerTelemetry:
                   for t in self.scheduler.queues}
         if self._prev_t is None or now <= self._prev_t:
             self._prev_served, self._prev_t = served, now
-            if self._bank is not None:
-                self._bank.baseline(served)
             self.obs = {t: TenantObs(queue=queues.get(t, 0.0))
                         for t in set(served) | set(queues)}
             return self.obs
         dt = now - self._prev_t
         self.obs = {}
-        if self._bank is not None:
-            union = set(served) | set(self._prev_served) | set(queues)
-            tenants, offs, _dfrs, reset = self._bank.update(
-                served, dt, extra=queues)
-            for i, t in enumerate(tenants):
-                if t not in union:
-                    continue
-                if reset[i]:
-                    if t in served or t in queues:
-                        self.obs[t] = TenantObs(queue=queues.get(t, 0.0))
-                    continue
-                r = float(offs[i])
-                self.obs[t] = TenantObs(rate=r, offered=r,
-                                        queue=queues.get(t, 0.0))
-        else:
-            for t in set(served) | set(self._prev_served) | set(queues):
-                raw = served.get(t, 0) - self._prev_served.get(t, 0)
-                if raw < 0 or (t not in served and t in self._prev_served):
-                    # counter reset: tenant migrated/dropped; rebaseline
-                    self._ewma.pop(t, None)
-                    if t in served or t in queues:
-                        self.obs[t] = TenantObs(queue=queues.get(t, 0.0))
-                    continue
-                r = self._ewma.setdefault(t, _Ewma(self.alpha)) \
-                    .update(raw / dt)
-                q = queues.get(t, 0.0)
-                self.obs[t] = TenantObs(rate=r, offered=r, queue=q)
+        for t in set(served) | set(self._prev_served) | set(queues):
+            raw = served.get(t, 0) - self._prev_served.get(t, 0)
+            if raw < 0 or (t not in served and t in self._prev_served):
+                # counter reset: tenant migrated/dropped; rebaseline
+                self._ewma.pop(t, None)
+                if t in served or t in queues:
+                    self.obs[t] = TenantObs(queue=queues.get(t, 0.0))
+                continue
+            r = self._ewma.setdefault(t, _Ewma(self.alpha)).update(raw / dt)
+            q = queues.get(t, 0.0)
+            self.obs[t] = TenantObs(rate=r, offered=r, queue=q)
         self._prev_served, self._prev_t = served, now
         self.updates += 1
         if tracing.TRACER.enabled:

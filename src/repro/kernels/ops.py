@@ -17,7 +17,6 @@ from repro.kernels.flash_attention import flash_attention as _flash_pallas
 from repro.kernels.quant_comm import dequantize_int8 as _dq_pallas
 from repro.kernels.quant_comm import quantize_int8 as _q_pallas
 from repro.kernels.ssd_scan import ssd_chunk_scan as _ssd_pallas
-from repro.kernels.waterfill import water_fill_pallas as _wf_pallas
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "impl",
@@ -59,19 +58,6 @@ def quantize(x, *, block=256, impl="pallas"):
     if impl == "ref":
         return ref.quantize_int8_ref(x, block)
     return _q_pallas(x, block=block)
-
-
-@functools.partial(jax.jit, static_argnames=("impl", "iters"))
-def water_fill(demands, weights, capacity, *, impl="pallas", iters=48):
-    """demands, weights: (n,); capacity scalar -> alloc (n,).
-
-    Weighted max-min water-fill over the whole tenant population — the
-    control plane's allocation inner loop. impl="ref" is the exact
-    sort-based progressive fill; impl="pallas" the fixed-iteration
-    bisection kernel (no sort on the hot path)."""
-    if impl == "ref":
-        return ref.water_fill_ref(demands, weights, capacity)
-    return _wf_pallas(demands, weights, capacity, iters=iters)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "impl", "dtype"))

@@ -84,43 +84,3 @@ def dequantize_int8_ref(q, scale, block: int, dtype=jnp.float32):
     r, c = q.shape
     xb = q.astype(jnp.float32).reshape(r, c // block, block)
     return (xb * scale[..., None]).reshape(r, c).astype(dtype)
-
-
-def water_fill_ref(demands, weights, capacity):
-    """Weighted max-min water-fill, exact sort-based progressive fill.
-
-    demands, weights: (n,); capacity: scalar. Returns alloc (n,) with
-    sum(alloc) <= capacity + eps. Tenants sorted by demand/weight ratio:
-    the affordable prefix is satisfied exactly (alloc == demand), the
-    rest split the leftover capacity by weight at one common water
-    level. ``inf`` demand = greedy (never satisfied, always at level).
-    Slots with demand <= 0 or weight <= 0 get 0 — that is how the fused
-    tick parks inactive tenant slots.
-    """
-    d = jnp.asarray(demands)
-    w = jnp.asarray(weights)
-    cap = jnp.asarray(capacity, dtype=d.dtype)
-    active = (d > 0) & (w > 0)
-    w = jnp.where(active, w, 0.0)
-    r = jnp.where(active, d / jnp.where(active, w, 1.0), jnp.inf)
-    order = jnp.argsort(r)
-    rs = r[order]
-    ws = w[order]
-    ds = jnp.where(active, d, 0.0)[order]
-    fin = jnp.isfinite(rs) & (ws > 0)
-    sat_demand = jnp.cumsum(jnp.where(fin, ds, 0.0))
-    cum_w = jnp.cumsum(ws)
-    tot_w = cum_w[-1] if ws.shape[0] else jnp.asarray(0.0, d.dtype)
-    # water needed to satisfy tenants through sorted position i: their
-    # demands outright, everyone after held at level r_i
-    fill_at = sat_demand + jnp.where(fin, rs, 0.0) * (tot_w - cum_w)
-    sat = fin & (fill_at <= cap * (1 + 1e-12) + 1e-12)
-    k = jnp.sum(sat)
-    last = jnp.maximum(k - 1, 0)
-    used_d = jnp.where(k > 0, sat_demand[last], 0.0)
-    used_w = jnp.where(k > 0, cum_w[last], 0.0)
-    w_rem = tot_w - used_w
-    lvl = jnp.where(w_rem > 0, (cap - used_d) / w_rem, jnp.inf)
-    lvl_safe = jnp.maximum(jnp.where(jnp.isfinite(lvl), lvl, 0.0), 0.0)
-    alloc_sorted = jnp.where(sat, ds, ws * lvl_safe)
-    return jnp.zeros_like(alloc_sorted).at[order].set(alloc_sorted)

@@ -406,23 +406,18 @@ class TraceReplayer:
 def make_replay_engine(*, capacity: float, batch_slots: int = 4,
                        max_seq: int = 32, control_every: int = 4,
                        push_mode: str = "full", delta_tol: float = 0.05,
-                       model: str = "llama3.2-3b", weights=None, mesh=None,
-                       backend: str = "object"):
+                       model: str = "llama3.2-3b", weights=None, mesh=None):
     """A smoke-scale ServeEngine + WFQ scheduler + attached RateController,
     wired the way the e2e scenarios expect (charge_prompt pricing, tokens/s
-    bottleneck = ``capacity``). ``backend="vectorized"`` selects the
-    array-backed control plane end to end (scheduler buckets, telemetry
-    EWMA banks, jitted water-fill) — same behavior, flat per-tenant cost."""
+    bottleneck = ``capacity``)."""
     from repro.configs import RunConfig, get_smoke_config
     from repro.control.controller import RateController
     from repro.launch.mesh import make_single_device_mesh
     from repro.serve.engine import ServeEngine
 
-    sched = TenantScheduler(policy="wfq", charge_prompt=True,
-                            bucket_backend=backend)
+    sched = TenantScheduler(policy="wfq", charge_prompt=True)
     ctrl = RateController(capacity, weights=weights, alpha=0.6,
-                          push_mode=push_mode, delta_tol=delta_tol,
-                          backend=backend)
+                          push_mode=push_mode, delta_tol=delta_tol)
     ctrl.attach_scheduler(sched)
     eng = ServeEngine(get_smoke_config(model),
                       RunConfig(attn_q_block=16, attn_kv_block=16),
@@ -439,7 +434,7 @@ def make_replay_cluster(*, capacity: float, engines: int = 3,
                         delta_tol: float = 0.05, model: str = "llama3.2-3b",
                         weights=None, mesh=None, autopilot=None,
                         place_every: int = 8, autopilot_kw=None,
-                        core_plane: bool = False, backend: str = "object"):
+                        core_plane: bool = False):
     """N smoke-scale ServeEngines behind ONE shared RateController — the
     multi-engine fabric the e2e scenarios drive.
 
@@ -465,14 +460,12 @@ def make_replay_cluster(*, capacity: float, engines: int = 3,
 
     mesh = mesh if mesh is not None else make_single_device_mesh()
     ctrl = RateController(capacity, weights=weights, alpha=0.6,
-                          push_mode=push_mode, delta_tol=delta_tol,
-                          backend=backend)
+                          push_mode=push_mode, delta_tol=delta_tol)
     cfg = get_smoke_config(model)
     rcfg = RunConfig(attn_q_block=16, attn_kv_block=16)
     engs = []
     for _ in range(int(engines)):
-        sched = TenantScheduler(policy="wfq", charge_prompt=True,
-                                bucket_backend=backend)
+        sched = TenantScheduler(policy="wfq", charge_prompt=True)
         eng = ServeEngine(cfg, rcfg, mesh,
                           params=engs[0].params if engs else None,
                           batch_slots=batch_slots, max_seq=max_seq,
@@ -862,8 +855,7 @@ def replay_scenario(name: str, *, n_tenants: int = 4, intervals: int = 20,
                     push_mode: str = "full", weights=None,
                     seed: int = 0, engines: Optional[int] = None,
                     autopilot=None, core_plane: bool = False,
-                    trace_path=None, watch=None,
-                    backend: str = "object") -> ReplayReport:
+                    trace_path=None, watch=None) -> ReplayReport:
     """Run one named scenario end-to-end and return the measured report.
 
     ``engines`` > 1 drives an ``EngineCluster`` (N ServeEngines behind one
@@ -901,11 +893,6 @@ def replay_scenario(name: str, *, n_tenants: int = 4, intervals: int = 20,
     the report's ``alerts*`` fields carry the outcome — steady fires
     zero, adversarial fires fairness burn on the hog, failover fires
     and resolves engine-dark (bench claim (k) pins all three).
-
-    ``backend="vectorized"`` runs the whole control plane on the array
-    backend (scheduler bucket store, telemetry EWMA banks, jitted
-    water-fill); every scenario claim must hold unchanged — the e2e
-    parity gate CI pins.
     """
     from repro.obs.tracing import trace_to
 
@@ -933,11 +920,10 @@ def replay_scenario(name: str, *, n_tenants: int = 4, intervals: int = 20,
             eng = make_replay_cluster(capacity=cap, engines=engines,
                                       push_mode=push_mode, weights=weights,
                                       autopilot=autopilot,
-                                      core_plane=core_plane,
-                                      backend=backend)
+                                      core_plane=core_plane)
         else:
             eng = make_replay_engine(capacity=cap, push_mode=push_mode,
-                                     weights=weights, backend=backend)
+                                     weights=weights)
     elif autopilot is not None and getattr(eng, "autopilot", None) is None \
             and hasattr(eng, "attach_autopilot"):
         from repro.control.placement import PlacementController

@@ -39,17 +39,9 @@ class Request:
 class TenantScheduler:
     """Fair multi-tenant admission: WFQ + optional token buckets + RR."""
 
-    def __init__(self, policy: str = "wfq", charge_prompt: bool = False,
-                 bucket_backend: str = "object"):
-        from repro.control.vectorized import BucketStore, check_backend
+    def __init__(self, policy: str = "wfq", charge_prompt: bool = False):
         assert policy in ("wfq", "rr")
         self.policy = policy
-        # bucket_backend="vectorized" keeps every tenant's bucket state in
-        # one BucketStore (flat float64 arrays); self.buckets then holds
-        # StoreBucket views with the identical TokenBucket interface
-        self.bucket_backend = check_backend(bucket_backend)
-        self._bucket_store = BucketStore() \
-            if bucket_backend == "vectorized" else None
         # charge_prompt: buckets price a request at prompt + decode tokens
         # instead of decode only, so admission rates, telemetry (which sees
         # served prompt+decode tokens) and controller capacity share one
@@ -81,22 +73,6 @@ class TenantScheduler:
         self._rr = itertools.count()
         self._rr_order: List[int] = []
 
-    # -- bucket backend ------------------------------------------------------
-    def _new_bucket(self, tenant_id: int, rate: float, burst: float):
-        if self._bucket_store is not None:
-            return self._bucket_store.add(tenant_id, rate, burst)
-        return TokenBucket(rate, burst)
-
-    def _restore_bucket(self, tenant_id: int, snap, now):
-        if self._bucket_store is not None:
-            return self._bucket_store.restore(tenant_id, snap, now)
-        return TokenBucket.restore(snap, now)
-
-    def _drop_bucket(self, tenant_id: int) -> None:
-        self.buckets.pop(tenant_id, None)
-        if self._bucket_store is not None:
-            self._bucket_store.drop(tenant_id)
-
     # -- tenant management -------------------------------------------------
     def add_tenant(self, tenant_id: int, weight: float = 1.0,
                    rate_tokens_per_s: Optional[float] = None,
@@ -110,8 +86,8 @@ class TenantScheduler:
         self.served_tokens[tenant_id] = 0
         self._rr_order.append(tenant_id)
         if rate_tokens_per_s is not None:
-            self.buckets[tenant_id] = self._new_bucket(
-                tenant_id, rate_tokens_per_s, burst or rate_tokens_per_s)
+            self.buckets[tenant_id] = TokenBucket(
+                rate_tokens_per_s, burst or rate_tokens_per_s)
 
     def set_rate(self, tenant_id: int,
                  rate_tokens_per_s: Optional[float],
@@ -129,12 +105,12 @@ class TenantScheduler:
         greet the tenant whenever it first shows up (see ``drop_tenant``).
         """
         if rate_tokens_per_s is None:
-            self._drop_bucket(tenant_id)
+            self.buckets.pop(tenant_id, None)
             return
         b = self.buckets.get(tenant_id)
         if b is None:
-            self.buckets[tenant_id] = b = self._new_bucket(
-                tenant_id, rate_tokens_per_s, burst or rate_tokens_per_s)
+            self.buckets[tenant_id] = b = TokenBucket(
+                rate_tokens_per_s, burst or rate_tokens_per_s)
             if now is not None:
                 b.updated = now
         else:
@@ -162,7 +138,7 @@ class TenantScheduler:
         """
         self.queues.pop(tenant_id, None)
         self.weights.pop(tenant_id, None)
-        self._drop_bucket(tenant_id)
+        self.buckets.pop(tenant_id, None)
         self.vtime.pop(tenant_id, None)
         self.served_tokens.pop(tenant_id, None)
         self.admitted_requests.pop(tenant_id, None)
@@ -268,8 +244,7 @@ class TenantScheduler:
         others = [v for t, v in self.vtime.items() if t != tenant_id]
         self.vtime[tenant_id] = min(others) if others else 0.0
         if state.bucket is not None:
-            self.buckets[tenant_id] = self._restore_bucket(
-                tenant_id, state.bucket, now)
+            self.buckets[tenant_id] = TokenBucket.restore(state.bucket, now)
         hist_payload = state.payload.get("admit_wait_hist")
         if hist_payload is not None:
             self.admit_wait_hist.absorb(
@@ -351,8 +326,7 @@ class TenantScheduler:
         if state.bucket is not None:
             # now=None keeps the snapshot's own timestamp (virtual-clock
             # safe: no free refill between checkpoint and restore)
-            self.buckets[tenant_id] = self._restore_bucket(
-                tenant_id, state.bucket, now)
+            self.buckets[tenant_id] = TokenBucket.restore(state.bucket, now)
         hist_payload = state.payload.get("admit_wait_hist")
         if hist_payload is not None:
             # REPLACE, never absorb: a re-restore after a failed attempt
@@ -367,9 +341,6 @@ class TenantScheduler:
         self.queues.clear()
         self.weights.clear()
         self.buckets.clear()
-        if self._bucket_store is not None:
-            from repro.control.vectorized import BucketStore
-            self._bucket_store = BucketStore()
         self.vtime.clear()
         self.served_tokens.clear()
         self.admitted_requests.clear()

@@ -235,23 +235,6 @@ def test_internlm2_prefill_fits_one_chip(internlm2, one_chip):
     assert _device_bytes(compiled) < V5E_HBM
 
 
-@pytest.mark.parametrize("dtype", ["float32", "float64"])
-def test_fused_control_tick_100k_tenants(one_chip, dtype):
-    import jax
-    import jax.numpy as jnp
-    from repro.control.vectorized import _fused_tick_jitted
-    n = 100_000
-    with jax.enable_x64(dtype == "float64"):
-        f = jnp.dtype(dtype)
-        vec = jax.ShapeDtypeStruct((n,), f)
-        args = [vec] * 9 + [jax.ShapeDtypeStruct((n,), jnp.bool_),
-                            jax.ShapeDtypeStruct((3, n), f),
-                            jax.ShapeDtypeStruct((7,), f)]
-        compiled = _fused_tick_jitted().lower(
-            *_on(one_chip, args), iters=48, scheduler_buckets=True).compile()
-    assert _device_bytes(compiled) < V5E_HBM
-
-
 @pytest.mark.parametrize("policy", ["xla", "ring", "hierarchical",
                                     "compressed", "shm-first"])
 def test_nsm_psum_policies_on_pod_mesh(pod_mesh, policy):
@@ -313,14 +296,4 @@ def test_int8_codec_kernels(one_chip):
     assert "tpu_custom_call" in hlo
     hlo = _compile_kernel(one_chip, dequantize_int8,
                           ((4096, 4096), jnp.int8), ((4096, 16), jnp.float32))
-    assert "tpu_custom_call" in hlo
-
-
-def test_water_fill_kernel_f32(one_chip):
-    import jax.numpy as jnp
-    from repro.kernels.waterfill import water_fill_pallas
-    vec = ((100_000,), jnp.float32)
-    hlo = _compile_kernel(one_chip,
-                          lambda d, w: water_fill_pallas(d, w, 1000.0),
-                          vec, vec)
     assert "tpu_custom_call" in hlo

@@ -12,7 +12,9 @@ from repro.control import (
     Aimd, Dctcp, RateController, SharedBottleneckSim, SimTenant, WaterFill,
     max_min_fair,
 )
-from repro.control.telemetry import EngineTelemetry, TenantObs
+from repro.control.telemetry import (
+    EngineTelemetry, SchedulerTelemetry, TenantObs,
+)
 from repro.core.engine import CoreEngine, TokenBucket
 from repro.serve.multiplex import bursty_trace, fair_replay, jain_index
 from repro.serve.scheduler import Request, TenantScheduler
@@ -89,6 +91,60 @@ def test_max_min_fair_work_conserving_and_bounded():
     assert sum(alloc.values()) == pytest.approx(100.0)
     assert max_min_fair(0.0, {1: 5}) == {1: 0.0}
     assert max_min_fair(10.0, {}) == {}
+
+
+def _sort_based_fill(demands, weights, capacity):
+    """Exact weighted water-fill, computed independently of
+    ``max_min_fair``: sort tenants by demand per unit weight, satisfy the
+    longest prefix whose fill fits, and split what is left by weight at
+    one common level. Demand or weight <= 0 gets 0; ``inf`` is greedy."""
+    d = np.asarray(demands, dtype=np.float64)
+    w = np.asarray(weights, dtype=np.float64)
+    active = (d > 0) & (w > 0)
+    w = np.where(active, w, 0.0)
+    ratio = np.where(active, d / np.where(active, w, 1.0), np.inf)
+    order = np.argsort(ratio, kind="stable")
+    rs, ws = ratio[order], w[order]
+    ds = np.where(active, d, 0.0)[order]
+    finite = np.isfinite(rs)
+    sat_demand = np.cumsum(np.where(finite, ds, 0.0))
+    cum_w = np.cumsum(ws)
+    total_w = cum_w[-1]
+    # capacity needed to satisfy sorted prefix [0, i] outright, with
+    # everyone after it held at level ratio[i]; non-decreasing in i
+    fill_at = sat_demand + np.where(finite, rs, 0.0) * (total_w - cum_w)
+    sat = finite & (fill_at <= capacity * (1 + 1e-12) + 1e-12)
+    k = int(sat.sum())
+    used_d = sat_demand[k - 1] if k else 0.0
+    rest_w = total_w - (cum_w[k - 1] if k else 0.0)
+    level = max((capacity - used_d) / rest_w, 0.0) if rest_w > 0 else 0.0
+    out = np.empty_like(ds)
+    out[order] = np.where(sat, ds, ws * level)
+    return out
+
+
+@pytest.mark.parametrize("weighting", ["equal", "mixed"])
+@pytest.mark.parametrize("n", [1, 8, 100, 1000, 10000])
+def test_max_min_fair_matches_sort_based_fill(n, weighting):
+    """The progressive fill equals an exact sort-based fill on a seeded
+    mix of backlogged (``inf``), satisfied and idle demands, never
+    over-fills, and fills capacity whenever a tenant is backlogged."""
+    cap = 1e6
+    rng = np.random.default_rng(n)
+    weights = (np.ones(n) if weighting == "equal"
+               else rng.choice([1.0, 2.0, 4.0], size=n))
+    demands = rng.uniform(0.2, 2.0, size=n) * 1.25 * (cap / n)
+    kind = rng.random(n)
+    demands[kind < 0.1] = math.inf
+    demands[(kind >= 0.1) & (kind < 0.15)] = 0.0
+    alloc = max_min_fair(cap, dict(enumerate(demands)),
+                         dict(enumerate(weights)))
+    got = np.array([alloc[t] for t in range(n)])
+    want = _sort_based_fill(demands, weights, cap)
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-9 * cap)
+    assert got.sum() <= cap * (1 + 1e-12)
+    if np.isinf(demands).any():
+        assert got.sum() == pytest.approx(cap, rel=1e-9)
 
 
 # --- dispatch enforcement -----------------------------------------------------
@@ -183,6 +239,33 @@ def test_telemetry_deferred_marks_backlogged():
     obs = tel.update(now=1.0 + 1e-3)
     assert obs[7].backlogged
     assert obs[7].rate < obs[7].offered
+
+
+@pytest.mark.parametrize("reset", ["decreased", "vanished"])
+def test_engine_telemetry_rebaselines_on_counter_reset(reset):
+    """A CoreEngine counter that falls or disappears between samples (a
+    migration folded the tenant's ledger out) reads as a rebaseline: no
+    negative rate, and the EWMA restarts from the next interval."""
+    eng = CoreEngine(enforcement="account")
+    tel = EngineTelemetry(eng, alpha=0.5)
+    tel.update(now=0.0)
+    eng.dispatch("shm_move", _Payload(500), ("pod",), tenant_id=3, now=0.5)
+    assert tel.update(now=1.0)[3].rate == pytest.approx(500.0)
+    eng.export_tenant(3, now=1.0)                 # ledger folds out
+    if reset == "decreased":
+        eng.dispatch("shm_move", _Payload(100), ("pod",), tenant_id=3,
+                     now=1.5)
+    obs = tel.update(now=2.0)
+    if reset == "decreased":
+        assert obs[3] == TenantObs()
+    else:
+        assert 3 not in obs
+        assert 3 not in tel.tracked_tenants()
+    eng.dispatch("shm_move", _Payload(200), ("pod",), tenant_id=3, now=2.5)
+    obs = tel.update(now=3.0)
+    # a fresh EWMA: the 500 B/s before the reset does not leak in
+    assert obs[3].rate == pytest.approx(200.0)
+    assert obs[3].offered == pytest.approx(200.0)
 
 
 # --- congestion-control algorithms -------------------------------------------
@@ -288,6 +371,110 @@ def test_controller_prometheus_export():
     assert 'nk_allocated_rate{tenant="1"}' in text
 
 
+@pytest.mark.parametrize("n", [1000, 10000])
+def test_controller_tick_matches_max_min_fair_at_scale(n):
+    """Telemetry -> bids -> fill -> push over a whole tenant population:
+    three ticks of seeded served counts, ~10% of tenants queued. The
+    allocations are ``max_min_fair`` of the bids WaterFill forms
+    (queued: ``inf``; otherwise the served rate x headroom), and every
+    scheduler bucket carries its tenant's allocation."""
+    cap = 1e6
+    rng = np.random.default_rng(n)
+    weights = rng.choice([1.0, 2.0, 4.0], size=n)
+    steps = np.maximum(np.round(rng.uniform(0.2, 2.0, size=n) * (cap / n)),
+                       1.0).astype(np.int64)
+    queued = rng.random(n) < 0.1
+    sched = TenantScheduler(policy="wfq", charge_prompt=True)
+    ctrl = RateController(cap, weights=dict(enumerate(weights.tolist())),
+                          alpha=0.5)
+    ctrl.attach_scheduler(sched)
+    for t in range(n):
+        sched.add_tenant(t, weight=float(weights[t]))
+        if queued[t]:
+            sched.submit(Request(t, [1], 1, req_id=t))
+    for tick in range(3):
+        for t in range(n):
+            sched.served_tokens[t] = int(steps[t]) * (tick + 1)
+        alloc = ctrl.tick(float(tick))
+    bids = {t: (math.inf if queued[t] else float(steps[t]) * 1.25)
+            for t in range(n)}
+    want = max_min_fair(cap, bids, dict(enumerate(weights.tolist())))
+    assert set(alloc) == set(want)
+    for t in range(n):
+        assert abs(alloc[t] - want[t]) <= 1e-6 * cap
+        assert sched.buckets[t].rate == alloc[t]
+    assert sum(alloc.values()) == pytest.approx(cap, rel=1e-9)
+
+
+def test_scheduler_telemetry_eviction():
+    sched = TenantScheduler()
+    tel = SchedulerTelemetry(sched, alpha=0.5)
+    for t in (1, 2):
+        sched.add_tenant(t)
+        sched.served_tokens[t] = 10
+    tel.update(now=0.0)
+    sched.served_tokens[1] = 30
+    sched.served_tokens[2] = 40
+    tel.update(now=1.0)
+    assert tel.tracked_tenants() >= {1, 2}
+    sched.drop_tenant(1)
+    tel.evict_tenant(1)
+    assert 1 not in tel.tracked_tenants()
+    assert 2 in tel.tracked_tenants()
+    # the survivor's EWMA is untouched by the eviction
+    obs = tel.update(now=2.0)
+    assert 1 not in obs and 2 in obs
+
+
+def test_controller_evict_tenant():
+    sched = TenantScheduler()
+    ctrl = RateController(1000.0, alpha=0.5)
+    ctrl.attach_scheduler(sched)
+    for t in (1, 2):
+        sched.add_tenant(t)
+        sched.served_tokens[t] = 5
+    ctrl.tick(0.0)
+    sched.served_tokens[1] = 25
+    sched.served_tokens[2] = 25
+    ctrl.tick(1.0)
+    assert 1 in ctrl.allocations
+    sched.drop_tenant(1)
+    ctrl.evict_tenant(1)
+    tel = ctrl._schedulers[0][1]
+    assert 1 not in tel.tracked_tenants()
+    assert 1 not in ctrl.allocations
+    # a tenant the scheduler still holds is NOT evicted (migration source
+    # that only moved one plane keeps live telemetry)
+    ctrl.evict_tenant(2)
+    assert 2 in tel.tracked_tenants()
+
+
+def test_migration_finalize_evicts_source_telemetry():
+    from test_placement import make_fake_cluster
+
+    cluster = make_fake_cluster(2, controller=RateController(
+        512.0, alpha=0.6))
+    for t in range(2):
+        cluster.add_tenant(t)
+        for r in range(3):
+            cluster.submit(Request(t, [1, 2], 4, req_id=10 * t + r,
+                                   arrival=0.0))
+    for i in range(8):
+        cluster.step(now=0.1 * (i + 1))
+    src = cluster.placement[0]
+    tel_by_sched = {id(s): tel
+                    for s, tel in cluster.controller._schedulers}
+    src_tel = tel_by_sched[id(cluster.engines[src].scheduler)]
+    assert 0 in src_tel.tracked_tenants()
+    cluster.migrate(0, 1 - src, now=1.0)
+    for i in range(12):
+        cluster.step(now=1.0 + 0.1 * (i + 1))
+    assert cluster.placement[0] == 1 - src
+    assert 0 not in src_tel.tracked_tenants()      # the leak, plugged
+    dst_tel = tel_by_sched[id(cluster.engines[1 - src].scheduler)]
+    assert 0 in dst_tel.tracked_tenants()
+
+
 # --- scheduler-side fairness --------------------------------------------------
 
 
@@ -379,6 +566,32 @@ def test_scheduler_admission_ledger():
     assert led["admitted_requests"] == 1
     assert led["deferred_polls"] >= 1
     assert led["mean_admit_wait_s"] == pytest.approx(1.0)
+
+
+@pytest.mark.parametrize("path", ["export", "checkpoint"])
+def test_scheduler_tenant_state_roundtrip(path):
+    """A partly drained bucket, the WFQ weight and the queue cross to
+    another scheduler unchanged: by migration (export -> import) and by
+    checkpoint (snapshot -> restore)."""
+    now = 1.0
+    src, dst = TenantScheduler(), TenantScheduler()
+    src.add_tenant(1, weight=2.0, rate_tokens_per_s=100.0, burst=50.0)
+    src.submit(Request(1, [1, 2], 3, req_id=9))
+    assert src.buckets[1].consume(20.0, now=now)
+    want = src.buckets[1].snapshot(now=now)
+    assert want == pytest.approx({"rate": 100.0, "capacity": 50.0,
+                                  "tokens": 30.0, "updated": now})
+    if path == "export":
+        dst.import_tenant(1, src.export_tenant(1, now=now), now=now)
+        assert 1 not in src.buckets and 1 not in src.queues
+    else:
+        dst.restore_tenant(1, src.snapshot_tenant(1, now=now), now=now)
+        assert src.buckets[1].snapshot(now=now) == want  # non-destructive
+    assert dst.buckets[1].snapshot(now=now) == want
+    assert dst.weights[1] == 2.0
+    assert [r.req_id for r in dst.queues[1]] == [9]
+    # refill resumes at the carried rate from the carried level
+    assert dst.buckets[1].wait_time(50.0, now=now) == pytest.approx(0.2)
 
 
 def test_delta_push_cuts_chatter_in_fluid_sim():

@@ -106,14 +106,3 @@ def test_quantize_pallas_matches_ref():
     q8r, sr = ops.quantize(x, block=128, impl="ref")
     np.testing.assert_array_equal(np.asarray(q8), np.asarray(q8r))
     np.testing.assert_allclose(s, sr, rtol=1e-6)
-
-
-def test_water_fill_kernel_refuses_f64_on_tpu(monkeypatch):
-    """The TPU water-fill kernel is 32-bit only: a float64 call there
-    raises instead of running at another precision."""
-    from repro.kernels.waterfill import water_fill_pallas
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    with jax.enable_x64(True):
-        d = jnp.ones((8,), jnp.float64)
-        with pytest.raises(TypeError, match="32-bit only"):
-            water_fill_pallas(d, d, 4.0)

@@ -337,21 +337,3 @@ def test_replay_delta_push_is_quiet_on_stable_trace():
     assert delta.max_min_deviation() < 0.12
     # the skipped pushes are accounted, proving the gate actually ran
     assert delta.push_skipped > delta.set_rate_calls
-
-
-@pytest.mark.slow
-def test_replay_vectorized_backend_matches_object_end_to_end():
-    """The array control plane is a drop-in: the same steady scenario run
-    with ``backend="vectorized"`` (flat-array telemetry banks, jitted
-    water-fill, BucketStore admission buckets) meets the same fairness
-    claims AND lands within a few percent of the object backend's
-    per-tenant served rates — the e2e parity gate for the fused tick."""
-    obj = replay_scenario("steady", n_tenants=4, intervals=16,
-                          backend="object")
-    vec = replay_scenario("steady", n_tenants=4, intervals=16,
-                          backend="vectorized")
-    assert vec.jain() >= 0.95
-    assert vec.max_min_deviation() < 0.10
-    for t in range(4):
-        a, b = obj.per_tenant[t].achieved_rate, vec.per_tenant[t].achieved_rate
-        assert b == pytest.approx(a, rel=0.02), f"tenant {t}: {a} vs {b}"

@@ -5,7 +5,7 @@
         benchmarks/bench_thresholds.json
 
 The LAST argument is the thresholds file; every earlier argument is a
-results document (bench_fairness, bench_control_scale, ...). Several
+results document (bench_fairness, ...). Several
 results files are merged — metric maps unioned (a duplicate metric name
 across files is an error: two benches must not claim the same row), and
 the overall ``ok`` flag is the AND across files — so one shared
