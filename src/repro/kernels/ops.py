@@ -12,7 +12,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
-from repro.kernels.decode_attention import decode_attention as _decode_pallas
 from repro.kernels.flash_attention import flash_attention as _flash_pallas
 from repro.kernels.quant_comm import dequantize_int8 as _dq_pallas
 from repro.kernels.quant_comm import quantize_int8 as _q_pallas
@@ -33,14 +32,6 @@ def mha_forward(q, k, v, *, causal=True, window=0, impl="pallas",
     o = _flash_pallas(qf, kf, vf, causal=causal, window=window,
                       q_block=q_block, kv_block=kv_block)
     return o.reshape(b, h, s, d)
-
-
-@functools.partial(jax.jit, static_argnames=("impl", "kv_block"))
-def decode_step_attention(q, k, v, pos, *, impl="pallas", kv_block=512):
-    """q: (B,H,d); k,v: (B,T,H,d); pos: (B,). Returns (o, m, l)."""
-    if impl == "ref":
-        return ref.decode_attention_ref(q, k, v, pos)
-    return _decode_pallas(q, k, v, pos, kv_block=kv_block)
 
 
 @functools.partial(jax.jit, static_argnames=("impl", "head_block"))

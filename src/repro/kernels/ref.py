@@ -1,7 +1,10 @@
-"""Pure-jnp oracles for every Pallas kernel (the correctness contract).
+"""Pure-jnp oracles for the Pallas kernels (the correctness contract), and
+the partial softmax the context-parallel decode combines across shards.
 
 Each ``*_ref`` mirrors its kernel's semantics exactly; tests sweep shapes and
 dtypes asserting allclose between kernel (interpreted on the CPU) and oracle.
+The flash-decode kernel's oracle is the model's own jnp decode attention
+(``models.attention.decode_attention``).
 """
 from __future__ import annotations
 
@@ -33,7 +36,8 @@ def flash_attention_ref(q, k, v, *, causal=True, window=0, scale=None):
 
 def decode_attention_ref(q, k, v, pos, *, scale=None):
     """q: (B,H,d); k,v: (B,T,H,d); pos: (B,). Returns (o, m, l) — partial
-    softmax stats so shards can LSE-combine (context-parallel decode)."""
+    softmax stats so shards can LSE-combine: the contract of the
+    context-parallel decode (``models.attention.decode_attention_cp``)."""
     b, h, d = q.shape
     t = k.shape[1]
     scale = scale or 1.0 / math.sqrt(d)

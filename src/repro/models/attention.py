@@ -25,7 +25,11 @@ Design notes (see DESIGN.md §4):
   is sharded over the model axis on the *sequence* dim (context-parallel
   decode), so XLA lowers the max/sum reductions into the log-sum-exp
   combine across shards (the explicit shard_map variant lives in
-  repro/serve/engine.py for the ring stack).
+  repro/serve/engine.py for the ring stack). Where a segment's stacked
+  cache is written in place on one device and the program lowers for a
+  TPU, the Pallas flash-decode kernel (repro/kernels/decode_attention.py)
+  reads each slot's cache from the stack only up to its position
+  (``decode_kernel_applies``).
 """
 from __future__ import annotations
 
@@ -38,6 +42,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import MLAConfig, ModelConfig
 from repro.distribution.sharding import ParamDesc, ShardingCtx, padded_heads
+from repro.kernels import decode_attention as flash_decode
 from repro.models.layers import (
     apply_norm, apply_rope, f32, norm_schema, rope_tables, yarn_mscale,
 )
@@ -361,21 +366,57 @@ def decode_attention_cp(q, k_c, v_c, pos, *, kv_map, window, n_real_heads,
     return o[:, None] if o.ndim == 3 else o
 
 
-def gqa_attention(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg, *,
-                  positions, kv_x=None, causal=True, window=0,
-                  cache: Optional[Dict] = None, decode_pos=None,
-                  cache_layer=None, return_cache=False, cross_decode=False):
-    """Unified GQA attention.
+def decode_kernel_applies(cfg: ModelConfig, shd: ShardingCtx, window: int,
+                          seq: int) -> bool:
+    """Whether a GQA self-attention decode against a segment's stacked
+    cache, written in place (``cache_layer`` given), attends with the
+    flash-decode kernel where the program lowers for a TPU: a full
+    (unwindowed) cache of whole kernel blocks, query heads that group over
+    the KV heads, and a program on one device (the kernel is not
+    partitioned, so a sharded batch would gather the cache)."""
+    return (not window and cfg.num_heads % cfg.num_kv_heads == 0
+            and (shd.mesh is None or shd.mesh.size == 1)
+            and seq % flash_decode.kv_block_for(seq) == 0)
+
+
+def _decode_from_stack(q, k_c, v_c, layer, pos, *, kv_map, n_real_heads):
+    """Decode attention of layer ``layer`` of a stacked (L,B,S,KV,hd)
+    cache: the flash-decode kernel where the program lowers for a TPU,
+    which reads each slot only up to its position; elsewhere the layer
+    sliced out of the stack and ``decode_attention`` over all of it."""
+    def kernel(q, k_c, v_c, layer, pos):
+        return flash_decode.decode_attention(q[:, 0], k_c, v_c, layer,
+                                             pos)[:, None]
+
+    def sliced(q, k_c, v_c, layer, pos):
+        return decode_attention(q, k_c[layer].astype(q.dtype),
+                                v_c[layer].astype(q.dtype), pos,
+                                kv_map=kv_map, n_real_heads=n_real_heads)
+    return jax.lax.platform_dependent(q, k_c, v_c, layer, pos, tpu=kernel,
+                                      default=sliced)
+
+
+def gqa_attention(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg, **kw):
+    """Unified GQA attention, under the name scope ``attn``.
 
     Training/prefill: ``positions`` is (S,) or (B,S); returns (out[, cache]).
     Decode: pass ``cache`` + ``decode_pos`` (B,); x is (B,1,D). With
     ``cache_layer``, ``cache`` holds the segment's stacked (L,B,S,KV,hd)
     k/v and this layer is index ``cache_layer``: the new rows are written
-    into the stack in place, attention reads the layer back from it, and
-    the returned cache is the stack.
+    into the stack in place, attention reads the layer from it
+    (``_decode_from_stack`` where ``decode_kernel_applies``), and the
+    returned cache is the stack.
     Cross-attention: ``kv_x`` is the encoder output (prefill/train);
     ``cross_decode`` reads the cached encoder k/v without updating.
     """
+    with jax.named_scope("attn"):
+        return _gqa(p, x, cfg, shd, rcfg, **kw)
+
+
+def _gqa(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg, *, positions,
+         kv_x=None, causal=True, window=0, cache: Optional[Dict] = None,
+         decode_pos=None, cache_layer=None, return_cache=False,
+         cross_decode=False):
     h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     hp = p["wq"].shape[1]
     kv_map = q_to_kv_map(h, hp, kv)
@@ -447,17 +488,22 @@ def gqa_attention(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg, *,
                                          cache_layer)
                  for name, rows in (("k", knew), ("v", vnew))}
     k_c, v_c = new_cache["k"], new_cache["v"]
-    if cache_layer is not None:
-        k_c, v_c = k_c[cache_layer], v_c[cache_layer]
-    if not ring:
-        # linear cache: context-parallel flash-decode over the model axis
-        o = decode_attention_cp(q, k_c.astype(x.dtype), v_c.astype(x.dtype),
-                                decode_pos, kv_map=kv_map, window=window,
-                                n_real_heads=h, shd=shd)
+    if cache_layer is not None and decode_kernel_applies(cfg, shd, window,
+                                                         n_slots):
+        o = _decode_from_stack(q.astype(x.dtype), k_c, v_c, cache_layer,
+                               decode_pos, kv_map=kv_map, n_real_heads=h)
     else:
-        o = decode_attention(q, k_c.astype(x.dtype), v_c.astype(x.dtype),
-                             decode_pos, kv_map=kv_map, window=window,
-                             kv_pos=kv_pos, n_real_heads=h)
+        if cache_layer is not None:
+            k_c, v_c = k_c[cache_layer], v_c[cache_layer]
+        k_c, v_c = k_c.astype(x.dtype), v_c.astype(x.dtype)
+        if not ring:
+            # linear cache: context-parallel flash-decode over the model axis
+            o = decode_attention_cp(q, k_c, v_c, decode_pos, kv_map=kv_map,
+                                    window=window, n_real_heads=h, shd=shd)
+        else:
+            o = decode_attention(q, k_c, v_c, decode_pos, kv_map=kv_map,
+                                 window=window, kv_pos=kv_pos,
+                                 n_real_heads=h)
     o = o * mask[None, None, :, None]
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
     return out, new_cache

@@ -28,6 +28,7 @@ from repro.distribution.sharding import (
     ParamDesc, ShardingCtx, abstract_params, init_params, param_shardings,
 )
 from repro.models import blocks as blocks_mod
+from repro.models.attention import decode_kernel_applies
 from repro.models.blocks import apply_block, block_cache_schema, block_schema
 from repro.models.layers import (
     apply_norm, embed_schema, embed_tokens, lm_logits, norm_schema,
@@ -143,6 +144,24 @@ def decode_writes_in_place(seg: Segment, cache_seg, shd: ShardingCtx,
     ring = "k" in cache_seg and bool(seg.window) \
         and cache_seg["k"].shape[2] <= seg.window
     return not ring and (shd.mesh is None or shd.tp == 1)
+
+
+def decode_kernel_layers(cfg: ModelConfig, shd: ShardingCtx, rcfg,
+                         max_seq: int, platform: str) -> int:
+    """Layers whose decode attends with the flash-decode kernel in a
+    program that lowers for ``platform``: on a TPU, those of the segments
+    that write a stacked K/V cache in place (``decode_writes_in_place``)
+    where ``attention.decode_kernel_applies``; latent (MLA) caches, ring
+    windows, cross-attention and SSM state keep the jnp attention, as does
+    every layer elsewhere."""
+    if platform != "tpu":
+        return 0
+    return sum(
+        seg.count for seg, c in zip(build_schedule(cfg),
+                                    cache_schema(cfg, 1, max_seq))
+        if c is not None and set(c) == {"k", "v"}
+        and decode_writes_in_place(seg, c, shd, rcfg)
+        and decode_kernel_applies(cfg, shd, seg.window, max_seq))
 
 
 def run_segment(seg: Segment, p_seg, x, cfg, shd, rcfg, *, mode,

@@ -93,6 +93,12 @@ METRIC_HELP: Dict[str, str] = {
     "nk_engine_decode_steps_total": "Decode steps taken per engine",
     "nk_decode_cache_inplace_segments":
         "Cache segments the decode step writes in place (rest: masked select)",
+    "nk_decode_attention_kernel_layers":
+        "Layers whose decode attends with the flash-decode kernel",
+    "nk_decode_kv_blocks_read_total":
+        "K/V cache blocks the flash-decode kernel read, over layers and slots",
+    "nk_decode_kv_blocks_total":
+        "K/V cache blocks a read of every position would have taken",
     "nk_moe_experts_held": "Routed experts this engine's expert layers hold",
     "nk_moe_assignments_total":
         "Routed (token, layer, expert) assignments, held or not",

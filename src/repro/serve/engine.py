@@ -23,9 +23,10 @@ from jax.sharding import NamedSharding, PartitionSpec
 from repro.configs.base import ModelConfig, RunConfig
 from repro.distribution.sharding import ShardingCtx, init_params
 from repro.fabric import SchedulerServeModule
+from repro.kernels.decode_attention import blocks_read
 from repro.models.model import (
-    build_schedule, cache_schema, decode_writes_in_place, forward_decode,
-    forward_prefill, model_schema,
+    build_schedule, cache_schema, decode_kernel_layers,
+    decode_writes_in_place, forward_decode, forward_prefill, model_schema,
 )
 from repro.obs import tracing
 from repro.serve.scheduler import Request, TenantScheduler
@@ -36,6 +37,13 @@ from repro.serve.scheduler import Request, TenantScheduler
 ROUTED = {"moe_assignments": "nk_moe_assignments_total",
           "moe_assignments_held": "nk_moe_assignments_held_total",
           "moe_experts_touched": "nk_moe_experts_touched_total"}
+
+
+def lowering_platform(mesh) -> str:
+    """The platform the engine's programs lower for: that of the mesh's
+    devices, or of JAX's default device where there is no mesh."""
+    device = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
+    return device.platform
 
 
 def _install_slot(caches, one, slot):
@@ -114,6 +122,14 @@ class ServeEngine(SchedulerServeModule):
             decode_writes_in_place(seg, c, self.shd, rcfg)
             for seg, c in zip(build_schedule(cfg),
                               cache_schema(cfg, batch_slots, max_seq)))
+        # layers whose decode attends with the flash-decode kernel, on the
+        # platform the programs lower for; and the K blocks it has read and
+        # that a read of every position would have taken (V reads as
+        # many), summed from the positions each step sends
+        self.platform = lowering_platform(mesh)
+        self.decode_kernel_layers = decode_kernel_layers(
+            cfg, self.shd, rcfg, max_seq, self.platform)
+        self._kv_blocks = np.zeros(2, np.int64)
 
         # an expert layer that holds a share of the router's experts: its
         # programs also sum the routed counts (ROUTED) on the device
@@ -199,7 +215,8 @@ class ServeEngine(SchedulerServeModule):
         self.caches = None
 
     def counters(self) -> Dict[str, float]:
-        """The decode program's static facts and, for an expert layer that
+        """The decode program's static facts, the cache blocks its
+        attention kernel has read so far and, for an expert layer that
         holds a share, the routed counts of every prefill and decode so
         far (Prometheus naming):
         ``registry.register_provider(engine, name="engine")``.
@@ -210,6 +227,14 @@ class ServeEngine(SchedulerServeModule):
         assignments."""
         out = {"nk_decode_cache_inplace_segments":
                float(self.decode_inplace_segments)}
+        # the kernel's counters exist where it can run: the gauge where the
+        # programs lower for a TPU, the blocks where some layer reads them
+        if self.platform == "tpu":
+            out["nk_decode_attention_kernel_layers"] = \
+                float(self.decode_kernel_layers)
+        if self.decode_kernel_layers:
+            out["nk_decode_kv_blocks_read_total"] = float(self._kv_blocks[0])
+            out["nk_decode_kv_blocks_total"] = float(self._kv_blocks[1])
         if self.counts_routing:
             self._routed_total += np.asarray(self._routed)
             self._routed = self._zero_counts()
@@ -302,6 +327,9 @@ class ServeEngine(SchedulerServeModule):
                     if s.active:
                         tokens[i, 0] = s.req.generated[-1]
                         pos[i] = s.pos
+                if self.decode_kernel_layers:
+                    self._kv_blocks += self.decode_kernel_layers * np.array(
+                        blocks_read(pos, self.max_seq))
                 tokens, pos = jnp.asarray(tokens), jnp.asarray(pos)
             with region("engine", "decode"):
                 nxt, self.caches = self._decode(self.params, self.caches,

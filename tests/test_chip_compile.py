@@ -155,6 +155,15 @@ def test_internlm2_decode_writes_the_cache_in_place(internlm2_decode,
     assert _stack_copies(internlm2_decode, internlm2_caches) == []
 
 
+def test_internlm2_decode_attends_with_the_kernel(internlm2_decode):
+    """Each layer's attention is the flash-decode kernel over the stacked
+    cache: a Mosaic call, and no layer of K or V, [1,16,2048,8,128],
+    sliced out of the stack."""
+    hlo = internlm2_decode.as_text()
+    assert "tpu_custom_call" in hlo
+    assert "bf16[1,16,2048,8,128]" not in hlo
+
+
 def test_internlm2_install_writes_the_slot_in_place(internlm2, one_chip,
                                                     internlm2_caches):
     """An admission's prefill cache goes into its slot of the donated
@@ -279,13 +288,18 @@ def test_flash_attention_kernel(one_chip):
 
 
 def test_decode_attention_kernel(one_chip):
+    """The flash-decode kernel over internlm2's stacked cache: the stack is
+    handed to the kernel as a bitcast, never copied."""
+    import re
     import jax.numpy as jnp
     from repro.kernels.decode_attention import decode_attention
-    kv = ((8, 2048, 16, 128), jnp.bfloat16)
+    kv = ((24, 16, 2048, 8, 128), jnp.bfloat16)
     hlo = _compile_kernel(one_chip, decode_attention,
-                          ((8, 16, 128), jnp.bfloat16), kv, kv,
-                          ((8,), jnp.int32))
+                          ((16, 16, 128), jnp.bfloat16), kv, kv,
+                          ((), jnp.int32), ((16,), jnp.int32))
     assert "tpu_custom_call" in hlo
+    assert "bf16[24,16,16384,128]" in hlo and "bitcast(" in hlo
+    assert re.findall(r"= bf16\[24,16,\S* copy\(", hlo) == []
 
 
 def test_int8_codec_kernels(one_chip):

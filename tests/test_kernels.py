@@ -1,5 +1,7 @@
 """Per-kernel validation vs the pure-jnp oracles (the Pallas interpreter on
 the CPU), with shape/dtype sweeps and hypothesis property checks."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,6 +9,8 @@ import pytest
 from _hyp import given, settings, st
 
 from repro.kernels import ops, ref
+from repro.kernels.decode_attention import decode_attention as flash_decode
+from repro.models.attention import decode_attention, q_to_kv_map
 
 KS = jax.random.split(jax.random.PRNGKey(0), 8)
 
@@ -30,19 +34,33 @@ def test_flash_attention_vs_ref(shape, causal, window, dtype):
                                np.asarray(o2, np.float32), rtol=tol, atol=tol)
 
 
-@pytest.mark.parametrize("t,kv_block", [(300, 128), (512, 512), (64, 32)])
-def test_decode_attention_vs_ref(t, kv_block):
-    b, h, d = 3, 8, 64
-    q = jax.random.normal(KS[3], (b, h, d), jnp.float32)
-    k = jax.random.normal(KS[4], (b, t, h, d), jnp.float32)
-    v = jax.random.normal(KS[5], (b, t, h, d), jnp.float32)
-    pos = jnp.array([0, t // 2, t - 1])
-    o1, m1, l1 = ops.decode_step_attention(q, k, v, pos, impl="pallas",
-                                           kv_block=kv_block)
-    o2, m2, l2 = ref.decode_attention_ref(q, k, v, pos)
-    np.testing.assert_allclose(o1, o2, rtol=2e-4, atol=2e-4)
-    np.testing.assert_allclose(m1, m2, rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(l1, l2, rtol=1e-4, atol=1e-4)
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("g", [1, 2, 4])
+def test_decode_attention_vs_ref(g, dtype):
+    """The flash-decode kernel on a stacked (L, B, S, KV, hd) cache, at a
+    traced layer index, against the jnp decode attention on that layer
+    alone, for ragged positions: idle slots at 0 (one between busy slots,
+    which hand their first blocks on to each other), the last position of
+    the first block, the first of the second, the last of the cache, and
+    1. Every layer holds other data, so reading the wrong one shows."""
+    n_layers, kv, hd, kv_block = 3, 2, 64, 16
+    s = 4 * kv_block
+    h = kv * g
+    pos = jnp.array([0, kv_block - 1, 0, kv_block, s - 1, 1], jnp.int32)
+    b = pos.shape[0]
+    q = jax.random.normal(KS[3], (b, h, hd), dtype)
+    k = jax.random.normal(KS[4], (n_layers, b, s, kv, hd), dtype)
+    v = jax.random.normal(KS[5], (n_layers, b, s, kv, hd), dtype)
+    layer = jnp.int32(1)
+    got = jax.jit(functools.partial(flash_decode, kv_block=kv_block))(
+        q, k, v, layer, pos)
+    want = decode_attention(q[:, None], k[layer], v[layer], pos,
+                            kv_map=q_to_kv_map(h, h, kv))[:, 0]
+    assert got.shape == (b, h, hd) and got.dtype == dtype
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
 
 
 def test_decode_lse_combine_across_shards():

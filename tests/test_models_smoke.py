@@ -197,3 +197,87 @@ def test_model_sharded_decode_keeps_masked_select(name, mesh1, rcfg_small):
     kept[rows] = False
     np.testing.assert_array_equal(
         first[kept], np.asarray(caches[0]["k"], np.float32)[kept])
+
+
+@pytest.mark.parametrize("name,layers", [
+    ("internlm2-1.8b", 24), ("arctic-480b", 35), ("deepseek-v2-236b", 0),
+    ("hymba-1.5b", 0), ("whisper-small", 0), ("mamba2-370m", 0)])
+def test_decode_kernel_layers(name, layers):
+    """On a TPU the flash-decode kernel runs in every layer whose segment
+    writes a stacked K/V cache in place (dense and expert GQA); latent
+    attention, ring windows beside SSM state, cross-attention and SSM
+    state keep the jnp path, as does every layer on the CPU, on a mesh
+    that shards the cache's sequence or its slots, and on a cache not made
+    of whole blocks."""
+    from repro.configs import get_config
+    from repro.launch.mesh import make_host_mesh
+    from repro.models.model import decode_kernel_layers
+    cfg, rcfg, one = get_config(name), RunConfig(), ShardingCtx(None)
+    assert decode_kernel_layers(cfg, one, rcfg, 2048, "tpu") == layers
+    assert decode_kernel_layers(cfg, one, rcfg, 2048, "cpu") == 0
+    for data, model in ((1, 2), (2, 1)):
+        assert decode_kernel_layers(cfg, ShardingCtx(make_host_mesh(
+            data, model)), rcfg, 2048, "tpu") == 0
+    assert decode_kernel_layers(cfg, one, rcfg, 2048 + 64, "tpu") == 0
+
+
+def _tpu_branches(monkeypatch, block):
+    """Take every platform choice as for a TPU, with the Pallas kernels
+    interpreted, so the decode attends with the flash-decode kernel on the
+    CPU, reading the cache in blocks of ``block`` positions; and let the
+    engine count the layers that run it as on a TPU."""
+    from repro.kernels import decode_attention as kernel_mod
+    from repro.serve import engine as engine_mod
+
+    def platform_dependent(*args, **branches):
+        return branches.get("tpu", branches.get("cpu"))(*args)
+    monkeypatch.setattr(jax.lax, "platform_dependent", platform_dependent)
+    monkeypatch.setattr(kernel_mod, "kv_block_for",
+                        lambda seq, kv_block=None: min(block, seq))
+    monkeypatch.setattr(engine_mod, "lowering_platform", lambda mesh: "tpu")
+
+
+def test_decode_kernel_keeps_the_engines_greedy_tokens(mesh1, rcfg_small,
+                                                       monkeypatch):
+    """internlm2 at smoke width, served by the engine: on the CPU no layer
+    runs the flash-decode kernel, so the engine exports none of its
+    counters; with
+    the kernel in every layer (interpreted, 16-position blocks, so slots
+    cross blocks) the greedy tokens are the jnp attention's, and the
+    counters sum the blocks each step reads at the positions it sent."""
+    from repro.serve.engine import ServeEngine
+    from repro.serve.scheduler import Request
+    cfg, max_seq, block = get_smoke_config("internlm2-1.8b"), 64, 16
+    params = build_params(cfg, mesh1, jax.random.PRNGKey(0))
+
+    def serve():
+        eng = ServeEngine(cfg, rcfg_small, mesh1, params=params,
+                          batch_slots=3, max_seq=max_seq)
+        sent, decode = [], eng._decode
+
+        def recording_decode(params, caches, tokens, pos):
+            sent.append(np.asarray(pos))
+            return decode(params, caches, tokens, pos)
+        eng._decode = recording_decode
+        for i, (n, out) in enumerate(((20, 12), (5, 30), (33, 8), (3, 9))):
+            eng.submit(Request(tenant_id=i % 2, prompt=[
+                (7 * i + 3 * t) % cfg.vocab_size for t in range(n)],
+                max_new_tokens=out, req_id=i))
+        eng.run_until_drained()
+        return ({r.req_id: r.generated for r in eng.completed},
+                eng.counters(), sent)
+
+    want, plain, _ = serve()
+    assert plain == {"nk_decode_cache_inplace_segments": 1.0}
+
+    _tpu_branches(monkeypatch, block)
+    got, counted, sent = serve()
+    assert got == want and len(got) == 4
+    layers = cfg.num_layers
+    assert counted["nk_decode_attention_kernel_layers"] == layers
+    assert counted["nk_decode_kv_blocks_read_total"] == layers * sum(
+        int(((pos // block + 1) * (pos > 0)).sum()) for pos in sent)
+    assert counted["nk_decode_kv_blocks_total"] == \
+        layers * len(sent) * 3 * max_seq // block
+    assert 0 < counted["nk_decode_kv_blocks_read_total"] \
+        < counted["nk_decode_kv_blocks_total"]

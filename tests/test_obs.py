@@ -218,6 +218,35 @@ def test_engine_gauges_its_in_place_decode_segments(model_axis, in_place,
         ("nk_decode_cache_inplace_segments", ()): in_place}
 
 
+@pytest.mark.parametrize("name,layers", [("internlm2-1.8b", 2),
+                                         ("deepseek-v2-236b", 0)])
+def test_engine_exports_the_decode_kernels_counters_on_a_tpu(
+        name, layers, rcfg_small, monkeypatch):
+    """Where the programs lower for a TPU the engine gauges the layers
+    whose decode runs the flash-decode kernel (none for latent attention),
+    and counts the cache blocks it reads only where some layer does."""
+    from repro.configs import get_smoke_config
+    from repro.serve import engine as engine_mod
+    monkeypatch.setattr(engine_mod, "lowering_platform", lambda mesh: "tpu")
+    cfg = get_smoke_config(name)
+    eng = engine_mod.ServeEngine(cfg, rcfg_small, None, batch_slots=2,
+                                 max_seq=32)
+    assert eng.decode_kernel_layers == layers
+    reg = MetricsRegistry()
+    reg.register_provider(eng, name="engine")
+    text = reg.export_prometheus()
+    assert "# TYPE nk_decode_attention_kernel_layers gauge" in text
+    got = parse_prometheus_text(text)
+    assert got[("nk_decode_attention_kernel_layers", ())] == layers
+    blocks = {("nk_decode_kv_blocks_read_total", ()),
+              ("nk_decode_kv_blocks_total", ())}
+    if layers:
+        assert "# TYPE nk_decode_kv_blocks_read_total counter" in text
+        assert {k: got[k] for k in blocks} == dict.fromkeys(blocks, 0.0)
+    else:
+        assert not blocks & set(got)
+
+
 # ---------------------------------------------------------------------------
 # latency histograms
 # ---------------------------------------------------------------------------
