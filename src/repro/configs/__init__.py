@@ -31,12 +31,13 @@ from repro.configs.internlm2_1_8b import CONFIG as _internlm2
 from repro.configs.nemotron_4_340b import CONFIG as _nemotron
 from repro.configs.granite_8b import CONFIG as _granite
 from repro.configs.hymba_1_5b import CONFIG as _hymba
+from repro.configs.nemotron_3_nano_30b_a3b import CONFIG as _nemotron_h
 
 ARCHS = {
     c.name: c
     for c in [
         _chameleon, _whisper, _arctic, _deepseek, _mamba2,
-        _llama, _internlm2, _nemotron, _granite, _hymba,
+        _llama, _internlm2, _nemotron, _granite, _hymba, _nemotron_h,
     ]
 }
 
@@ -58,7 +59,7 @@ def get_shape(name: str) -> ShapeConfig:
 
 
 def iter_cells() -> Iterator[Tuple[ModelConfig, ShapeConfig, bool, str]]:
-    """All 40 (arch x shape) cells: (cfg, shape, applicable, skip_reason)."""
+    """Every (arch x shape) cell: (cfg, shape, applicable, skip_reason)."""
     for arch in ARCHS.values():
         for shape in SHAPES.values():
             ok, why = shape_applicable(arch, shape)

@@ -37,8 +37,15 @@ class MoEConfig:
     top_k_groups: int = 1            # groups a token's top_k is drawn from
     routed_scale: float = 1.0        # multiplies the top-k weights
     renormalize_top_k: bool = True   # top-k weights divided by their sum
+    # router scores: softmax over the router's outputs, or sigmoid of each
+    # (DeepSeek-V3, Nemotron-H), whose top-k is chosen on the score plus a
+    # learned per-expert correction bias and weighted by the score alone
+    score: str = "softmax"
 
     def __post_init__(self):
+        if self.score not in ("softmax", "sigmoid"):
+            raise ValueError(f"router score must be 'softmax' or 'sigmoid', "
+                             f"got {self.score!r}")
         if self.router_experts == 0:
             object.__setattr__(self, "router_experts", self.num_experts)
         if self.first_expert + self.num_experts > self.router_experts:
@@ -57,20 +64,36 @@ class MoEConfig:
 
 @dataclass(frozen=True)
 class SSMConfig:
-    """Mamba-2 (SSD) block configuration."""
+    """Mamba-2 (SSD) block configuration.
+
+    The inner width is ``num_heads x head_dim`` where ``num_heads`` is
+    given (Nemotron-H: 64 heads of 64 beside a d_model of 2688), else
+    ``expand x d_model``. B and C come in ``n_groups`` groups of
+    ``state_dim``, head h reading group ``h // (heads / n_groups)``, and
+    the gated RMSNorm is taken over each group's share of the inner
+    width. ``conv_bias``: the depthwise conv adds a bias per channel."""
 
     state_dim: int            # N
     head_dim: int = 64        # P
-    expand: int = 2           # d_inner = expand * d_model
+    expand: int = 2           # d_inner = expand * d_model, where num_heads is 0
     conv_width: int = 4
     chunk: int = 256          # SSD chunk length
     dt_min: float = 0.001
     dt_max: float = 0.1
+    num_heads: int = 0        # 0 => expand * d_model // head_dim
+    n_groups: int = 1
+    conv_bias: bool = False
+
+    def __post_init__(self):
+        if self.num_heads and self.num_heads % self.n_groups:
+            raise ValueError("B/C groups must split the heads evenly")
 
     def d_inner(self, d_model: int) -> int:
+        if self.num_heads:
+            return self.num_heads * self.head_dim
         return self.expand * d_model
 
-    def num_heads(self, d_model: int) -> int:
+    def heads(self, d_model: int) -> int:
         return self.d_inner(d_model) // self.head_dim
 
 
@@ -99,6 +122,10 @@ class MLAConfig:
 # ---------------------------------------------------------------------------
 # Model config
 # ---------------------------------------------------------------------------
+
+# a layer_pattern's letters and the block kind each one runs
+# (models.blocks.apply_block)
+PATTERN_KINDS = {"M": "ssm", "E": "experts", "*": "attn"}
 
 
 @dataclass(frozen=True)
@@ -132,6 +159,11 @@ class ModelConfig:
 
     # --- SSM / hybrid ---
     ssm: Optional[SSMConfig] = None
+    # Nemotron-H's published hybrid_override_pattern: block i is "M" (a
+    # Mamba-2 mixer), "E" (an expert layer) or "*" (attention), each one
+    # x + mixer(RMSNorm(x)); the model's blocks are the pattern's first
+    # num_layers (models.model.build_schedule)
+    layer_pattern: str = ""
     # hybrid (hymba): per-layer attention windows; layers listed here use
     # full/global attention, all others use sliding-window attention.
     attn_window: int = 0             # 0 => full causal attention
@@ -150,6 +182,17 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim", self.d_model // max(self.num_heads, 1))
+        if self.layer_pattern and (
+                len(self.layer_pattern) < self.num_layers
+                or set(self.layer_pattern) - set(PATTERN_KINDS)):
+            raise ValueError(
+                f"layer_pattern must give each of the {self.num_layers} "
+                f"blocks one of {sorted(PATTERN_KINDS)}")
+
+    @property
+    def blocks(self) -> str:
+        """The pattern's letter of each block (``layer_pattern``), or ""."""
+        return self.layer_pattern[:self.num_layers]
 
     # --- derived quantities -------------------------------------------------
     @property
@@ -184,6 +227,8 @@ class ModelConfig:
             e = m.num_experts * self._expert_ffn(m.expert_ff)
             e += m.num_shared_experts * self._expert_ffn(m.shared_ff or m.expert_ff)
             e += self.d_model * m.router_experts                 # router
+            if m.score == "sigmoid":
+                e += m.router_experts                # correction bias
             if m.parallel_dense:
                 e += self._expert_ffn(self.d_ff)
             return e
@@ -200,12 +245,13 @@ class ModelConfig:
             return 0
         s = self.ssm
         di = s.d_inner(self.d_model)
-        nh = s.num_heads(self.d_model)
+        nh = s.heads(self.d_model)
+        conv = di + 2 * s.n_groups * s.state_dim      # x, B and C channels
         # in_proj produces (x, z, B, C, dt); out_proj back to d_model
-        p = self.d_model * (2 * di + 2 * s.state_dim + nh)
+        p = self.d_model * (di + conv + nh)
         p += di * self.d_model
-        p += s.conv_width * (di + 2 * s.state_dim)   # depthwise conv
-        p += 2 * nh                                   # A_log, D
+        p += (s.conv_width + s.conv_bias) * conv      # depthwise conv
+        p += 3 * nh + di                      # A_log, D, dt_bias; gated norm
         return p
 
     def ffn_active_params_per_layer(self) -> int:
@@ -215,6 +261,8 @@ class ModelConfig:
         a = m.top_k * self._expert_ffn(m.expert_ff)
         a += m.num_shared_experts * self._expert_ffn(m.shared_ff or m.expert_ff)
         a += self.d_model * m.router_experts
+        if m.score == "sigmoid":
+            a += m.router_experts
         if m.parallel_dense:
             a += self._expert_ffn(self.d_ff)
         return a
@@ -236,7 +284,12 @@ class ModelConfig:
         if not self.tie_embeddings:
             n += self.vocab_size * self.d_model
         body = 0
-        for i in range(self.num_layers):
+        for kind in self.blocks:
+            body += self.d_model + {
+                "ssm": self.ssm_params_per_layer,
+                "experts": self.ffn_params_per_layer,
+                "attn": self.attn_params_per_layer}[PATTERN_KINDS[kind]]()
+        for i in range(0 if self.layer_pattern else self.num_layers):
             if self.moe is not None and i < self.dense_layer_prefix:
                 dense = ModelConfig(
                     name="_tmp", family="dense", num_layers=1, d_model=self.d_model,
@@ -262,6 +315,8 @@ class ModelConfig:
         return n
 
     def num_layers_moe(self) -> int:
+        if self.layer_pattern:
+            return self.blocks.count("E")
         return 0 if self.moe is None else self.num_layers - self.dense_layer_prefix
 
 
@@ -360,7 +415,11 @@ def reduce_for_smoke(cfg: ModelConfig) -> ModelConfig:
     """Family-preserving reduction for CPU smoke tests (tiny dims)."""
     kw: dict = dict(
         name=cfg.name + "-smoke",
-        num_layers=min(cfg.num_layers, 2 + (cfg.dense_layer_prefix or 0)),
+        # a layer pattern keeps its shortest prefix that holds every kind
+        num_layers=min(cfg.num_layers, 1 + max(
+            cfg.layer_pattern.index(c) for c in set(cfg.layer_pattern)))
+        if cfg.layer_pattern else
+        min(cfg.num_layers, 2 + (cfg.dense_layer_prefix or 0)),
         d_model=64,
         num_heads=4,
         num_kv_heads=min(cfg.num_kv_heads, 2) if cfg.num_kv_heads < cfg.num_heads else 4,
@@ -376,14 +435,19 @@ def reduce_for_smoke(cfg: ModelConfig) -> ModelConfig:
             expert_groups=min(cfg.moe.expert_groups, 2),
             top_k_groups=min(cfg.moe.top_k_groups, 1),
             # the scale that offsets small scores over many experts, cut
-            # with them (deepseek: 16 over 160 -> 0.4 over 4)
+            # with them (deepseek: 16 over 160 -> 0.4 over 4); weights
+            # renormalised to sum to 1 keep theirs
             routed_scale=cfg.moe.routed_scale * 4 / cfg.moe.router_experts
-            if cfg.moe.routed_scale != 1.0 else 1.0)
+            if cfg.moe.routed_scale != 1.0 and not cfg.moe.renormalize_top_k
+            else cfg.moe.routed_scale)
         kw["dense_prefix_ff"] = 128 if cfg.dense_layer_prefix else 0
         if cfg.dense_layer_prefix:
             kw["num_layers"] = max(kw["num_layers"], cfg.dense_layer_prefix + 2)
     if cfg.ssm is not None:
-        kw["ssm"] = dataclasses.replace(cfg.ssm, state_dim=16, head_dim=16, chunk=32)
+        kw["ssm"] = dataclasses.replace(
+            cfg.ssm, state_dim=16, head_dim=16, chunk=32,
+            num_heads=8 if cfg.ssm.num_heads else 0,
+            n_groups=min(cfg.ssm.n_groups, 2))
     if cfg.global_attn_layers:
         kw["global_attn_layers"] = (0,)
         kw["attn_window"] = 32

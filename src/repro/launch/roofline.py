@@ -369,7 +369,7 @@ def cache_bytes_global(cfg, shape, dtype_bytes: int = 2) -> float:
                 * dtype_bytes
         if cfg.ssm is not None:
             ss = cfg.ssm
-            total += b * ss.num_heads(cfg.d_model) * ss.head_dim \
+            total += b * ss.heads(cfg.d_model) * ss.head_dim \
                 * ss.state_dim * 4
     return total
 

@@ -6,8 +6,10 @@ One ``apply_block`` entry point per layer, dispatched on a static ``kind``:
                                                       granite/chameleon)
   moe           pre-norm attn + MoE (+shared/dense)  (arctic, deepseek body)
   dense_prefix  attn + dense MLP w/ prefix d_ff      (deepseek layer 0)
-  ssm           Mamba-2 block only                   (mamba2)
+  ssm           Mamba-2 block only                   (mamba2, Nemotron-H's M)
   hybrid        parallel attn+SSD heads, then MLP    (hymba)
+  attn          pre-norm attn only                   (Nemotron-H's *)
+  experts       pre-norm MoE only                    (Nemotron-H's E)
   enc           bidirectional attn + MLP             (whisper encoder)
   dec           self-attn + cross-attn + MLP         (whisper decoder)
 
@@ -40,10 +42,15 @@ def block_schema(cfg: ModelConfig, mesh, kind: str) -> Dict:
     nk = cfg.norm
     pd = cfg.param_dtype
     s: Dict = {}
-    if kind in ("dense", "moe", "dense_prefix", "enc", "dec", "hybrid"):
+    if kind in ("dense", "moe", "dense_prefix", "enc", "dec", "hybrid",
+                "attn"):
         s["ln1"] = norm_schema(d, nk, pd)
         s["attn"] = mla_schema(cfg, mesh) if _is_mla(cfg) else \
             attn_schema(cfg, mesh)
+    if kind == "attn":
+        return s
+    if kind == "experts":
+        return {"ln1": norm_schema(d, nk, pd), "moe": moe_schema(cfg, mesh)}
     if kind == "dec":
         s["ln_cross"] = norm_schema(d, nk, pd)
         s["cross"] = attn_schema(cfg, mesh, cross=True)
@@ -73,7 +80,7 @@ def block_cache_schema(cfg: ModelConfig, kind: str, batch: int, seq: int,
     window layers keep a ring buffer of ``window`` slots."""
     kv, hd = cfg.num_kv_heads, cfg.head_dim
     s: Dict = {}
-    if kind in ("dense", "moe", "dense_prefix", "dec", "hybrid"):
+    if kind in ("dense", "moe", "dense_prefix", "dec", "hybrid", "attn"):
         if _is_mla(cfg):
             r = attn_mod.mla_cache_width(cfg.mla)
             s["lat"] = ParamDesc((batch, seq, r), ("batch", "kv_seq", None),
@@ -112,7 +119,7 @@ def apply_block(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg, kind: str, *,
 
     ``cache_layer``: decode against a segment's stacked k/v or latent
     cache, this layer being that index (``gqa_attention``,
-    ``mla_attention``)."""
+    ``mla_attention``). An ``experts`` block keeps no cache (None)."""
     aux: Dict = {}
     decode = mode == "decode"
     want_cache = mode in ("prefill", "decode")
@@ -124,9 +131,14 @@ def apply_block(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg, kind: str, *,
                                   cache=cache, decode=decode)
         x = x + y
         return x, c2, aux
+    if kind == "experts":
+        h = apply_norm(p["ln1"], x, cfg.norm, cfg.norm_eps)
+        y, aux = apply_moe(p["moe"], h, cfg, shd, rcfg)
+        return shd.constrain_act(x + y), None, aux
 
     # ---- attention half ----
-    if kind in ("dense", "moe", "dense_prefix", "enc", "dec", "hybrid"):
+    if kind in ("dense", "moe", "dense_prefix", "enc", "dec", "hybrid",
+                "attn"):
         h = apply_norm(p["ln1"], x, cfg.norm, cfg.norm_eps)
         akw: Dict = dict(positions=positions, window=window,
                          causal=(kind != "enc"))
@@ -160,6 +172,8 @@ def apply_block(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg, kind: str, *,
             if new_cache is not None and sc2 is not None:
                 new_cache.update(sc2)
         x = x + a
+    if kind == "attn":
+        return shd.constrain_act(x), new_cache, aux
 
     # ---- cross attention (whisper decoder) ----
     if kind == "dec":

@@ -4,7 +4,8 @@ A model is a list of *segments*; each segment is ``count`` layers of one
 block kind. Homogeneous segments are scanned (``lax.scan`` over stacked
 params — one traced body regardless of depth); heterogeneous layers
 (deepseek's dense layer 0, hymba's 3 global-attention layers) break the
-stack into segments. Caches mirror the segment structure; a decode step
+stack into segments. A ``layer_pattern`` (Nemotron-H) gives one segment a
+run of equal block kinds. Caches mirror the segment structure; a decode step
 writes a segment's new K/V rows into its stacked cache in place where
 ``decode_writes_in_place`` allows, and rewrites each layer's cache with a
 masked select elsewhere.
@@ -17,13 +18,16 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig, RunConfig, ShapeConfig
+from repro.configs.base import (
+    PATTERN_KINDS, ModelConfig, RunConfig, ShapeConfig,
+)
 from repro.distribution.sharding import (
     ParamDesc, ShardingCtx, abstract_params, init_params, param_shardings,
 )
@@ -45,6 +49,11 @@ class Segment:
 
 
 def build_schedule(cfg: ModelConfig) -> Tuple[Segment, ...]:
+    if cfg.layer_pattern:
+        # one segment a run of equal letters (Nemotron-H's alternate, so
+        # each block is a segment of one, unrolled)
+        return tuple(Segment(PATTERN_KINDS[c], len(list(run)), True)
+                     for c, run in itertools.groupby(cfg.blocks))
     if cfg.family == "ssm":
         return (Segment("ssm", cfg.num_layers, True),)
     if cfg.family == "encdec":
@@ -99,11 +108,13 @@ def model_schema(cfg: ModelConfig, mesh) -> Dict:
 
 def cache_schema(cfg: ModelConfig, batch: int, max_seq: int,
                  dtype: str = "bfloat16") -> Tuple:
+    """Each segment's cache descriptors, stacked over its layers; None for
+    a segment that keeps none (an expert layer of a layer pattern)."""
     segs = []
     for seg in build_schedule(cfg):
         sch = block_cache_schema(cfg, seg.kind, batch, max_seq, seg.window,
                                  dtype)
-        segs.append(_stack_schema(sch, seg.count))
+        segs.append(_stack_schema(sch, seg.count) if sch else None)
     return tuple(segs)
 
 

@@ -19,7 +19,8 @@ no drops; the exchange with the chips that hold the rest is not part of
 this layer.
 
 Supports DeepSeek-V2 shared experts (always-on dense branch of size
-num_shared*shared_ff), its group-limited greedy routing, and Arctic's
+num_shared*shared_ff), its group-limited greedy routing, sigmoid scores
+with a selection-only correction bias (Nemotron-H), and Arctic's
 parallel dense-residual branch. Aux losses: switch load-balance + router
 z-loss. Stable name scopes: ``moe``, ``moe/route``, ``moe/experts``,
 ``moe/shared``.
@@ -49,6 +50,9 @@ def moe_schema(cfg: ModelConfig, mesh) -> Dict:
         "w_out": ParamDesc((m.num_experts, m.expert_ff, d),
                            ("experts", None, "embed"), pd),
     }
+    if m.score == "sigmoid":
+        s["correction_bias"] = ParamDesc((m.router_experts,), ("experts",),
+                                         "float32", "zeros")
     if glu:
         s["w_gate"] = ParamDesc((m.num_experts, d, m.expert_ff),
                                 ("experts", "embed", None), pd)
@@ -65,26 +69,34 @@ def _capacity(tokens: int, m) -> int:
     return max(8, min(c, tokens)) if tokens >= 8 else max(1, min(c, tokens))
 
 
-def route_topk(router_w, x_flat, m) -> Tuple[jax.Array, jax.Array, Dict]:
+def route_topk(router_w, x_flat, m, correction_bias=None
+               ) -> Tuple[jax.Array, jax.Array, Dict]:
     """Returns (gate_weights (T,k), expert_idx (T,k), aux metrics).
 
-    Softmax scores over the router's ``router_experts`` outputs (f32).
-    With ``expert_groups`` > 1, group-limited greedy (DeepSeek-V2): each
-    group scores its best expert, the ``top_k_groups`` best groups are
-    kept and every other group's scores are zeroed before the top-k.
-    The top-k weights are the scores, divided by their sum where
-    ``renormalize_top_k``, times ``routed_scale``."""
+    Scores over the router's ``router_experts`` outputs (f32): a softmax,
+    or (``score`` "sigmoid") the sigmoid of each logit, whose experts are
+    chosen on the score plus ``correction_bias`` (R,) and weighted by the
+    score alone. With ``expert_groups`` > 1, group-limited greedy
+    (DeepSeek-V2): each group scores its best expert, the
+    ``top_k_groups`` best groups are kept and every other group's scores
+    are zeroed before the top-k. The top-k weights are the scores,
+    divided by their sum where ``renormalize_top_k``, times
+    ``routed_scale``."""
     logits = jnp.einsum("td,de->te", f32(x_flat), f32(router_w))
-    probs = jax.nn.softmax(logits, axis=-1)
+    sigmoid = m.score == "sigmoid"
+    probs = jax.nn.sigmoid(logits) if sigmoid else \
+        jax.nn.softmax(logits, axis=-1)
     T, E = probs.shape
-    scores = probs
+    scores = probs + f32(correction_bias) if sigmoid else probs
     if m.expert_groups > 1:
-        grouped = probs.reshape(T, m.expert_groups, E // m.expert_groups)
+        grouped = scores.reshape(T, m.expert_groups, E // m.expert_groups)
         _, keep = jax.lax.top_k(jnp.max(grouped, -1), m.top_k_groups)
         kept = jnp.any(jax.nn.one_hot(keep, m.expert_groups,
                                       dtype=jnp.bool_), axis=1)
         scores = jnp.where(kept[..., None], grouped, 0.0).reshape(T, E)
     gate, eidx = jax.lax.top_k(scores, m.top_k)
+    if sigmoid:                     # the bias chooses; it does not weigh
+        gate = jnp.take_along_axis(probs, eidx, axis=-1)
     if m.renormalize_top_k:
         gate = gate / jnp.maximum(jnp.sum(gate, -1, keepdims=True), 1e-9)
     if m.routed_scale != 1.0:
@@ -158,7 +170,8 @@ def _apply_held(p, x, cfg: ModelConfig, shd: ShardingCtx):
     b, s, d = x.shape
     xf = x.reshape(b * s, d)
     with jax.named_scope("route"):
-        gate, eidx, _ = route_topk(p["router"], xf, m)
+        gate, eidx, _ = route_topk(p["router"], xf, m,
+                                   p.get("correction_bias"))
         local = eidx - m.first_expert                             # (T,k)
         held = (local >= 0) & (local < m.num_experts)
         onehot = jax.nn.one_hot(jnp.where(held, local, -1), m.num_experts,
@@ -210,7 +223,8 @@ def _apply_capacity(p, x, cfg: ModelConfig, shd: ShardingCtx):
 
     xf = x.reshape(T, d)
     with jax.named_scope("route"):
-        gate, eidx, aux = route_topk(p["router"], xf, m)
+        gate, eidx, aux = route_topk(p["router"], xf, m,
+                                     p.get("correction_bias"))
     xg = xf.reshape(G, T_g, d)
     gate_g = gate.reshape(G, T_g, k)
     eidx_g = eidx.reshape(G, T_g, k)

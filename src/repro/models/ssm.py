@@ -6,44 +6,56 @@ chunks. The intra-chunk part is the compute hot spot targeted by the Pallas
 kernel (repro/kernels/ssd_scan.py); this module is the production JAX path
 and the oracle's substrate.
 
-Layout: x (B, L, H, P) heads; B/C (B, L, N) single group; dt (B, L, H).
-State: (B, H, P, N).
+Layout: x (B, L, H, P) heads; B/C (B, L, G, N) in G groups, head h reading
+group h // (H / G) (one group: (B, L, N)); dt (B, L, H). State: (B, H, P, N).
+Name scopes: ``ssm``, with ``in`` (projections), ``conv``, ``scan``
+(prefill) or ``update`` (decode), and ``out`` (gated norm, out-projection).
 """
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, SSMConfig
 from repro.distribution.sharding import ParamDesc, ShardingCtx
-from repro.models.layers import apply_norm, f32, norm_schema
+from repro.models.layers import f32, norm_schema
+
+# the conv's channel streams: x (the inner width), then B and C
+STREAMS = ("x", "B", "C")
+
+
+def _widths(cfg: ModelConfig) -> Dict[str, int]:
+    s = cfg.ssm
+    gn = s.n_groups * s.state_dim
+    return {"x": s.d_inner(cfg.d_model), "B": gn, "C": gn}
 
 
 def ssm_schema(cfg: ModelConfig, mesh) -> Dict:
     s = cfg.ssm
     d = cfg.d_model
     di = s.d_inner(d)
-    nh = s.num_heads(d)
-    n = s.state_dim
+    nh = s.heads(d)
     w = s.conv_width
     pd = cfg.param_dtype
-    return {
-        "w_x": ParamDesc((d, di), ("embed", "ffn"), pd),
+    out = {
         "w_z": ParamDesc((d, di), ("embed", "ffn"), pd),
-        "w_B": ParamDesc((d, n), ("embed", None), pd),
-        "w_C": ParamDesc((d, n), ("embed", None), pd),
         "w_dt": ParamDesc((d, nh), ("embed", "ssm_heads"), pd),
         "w_out": ParamDesc((di, d), ("ffn", "embed"), pd),
-        "conv_x": ParamDesc((w, di), ("conv", "ffn"), pd, "small_normal", 0.5),
-        "conv_B": ParamDesc((w, n), ("conv", None), pd, "small_normal", 0.5),
-        "conv_C": ParamDesc((w, n), ("conv", None), pd, "small_normal", 0.5),
         "A_log": ParamDesc((nh,), ("ssm_heads",), "float32", "zeros"),
         "D": ParamDesc((nh,), ("ssm_heads",), "float32", "ones"),
         "dt_bias": ParamDesc((nh,), ("ssm_heads",), "float32", "zeros"),
         "norm": norm_schema(di, "rmsnorm", pd),
     }
+    for k, c in _widths(cfg).items():
+        dim = "ffn" if k == "x" else None
+        out[f"w_{k}"] = ParamDesc((d, c), ("embed", dim), pd)
+        out[f"conv_{k}"] = ParamDesc((w, c), ("conv", dim), pd,
+                                     "small_normal", 0.5)
+        if s.conv_bias:
+            out[f"conv_bias_{k}"] = ParamDesc((c,), (dim,), pd, "zeros")
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -83,12 +95,23 @@ def _segsum(x: jax.Array) -> jax.Array:
     return jnp.where(mask, diff, -jnp.inf)
 
 
+def _grouped(B, C, ndim: int):
+    """B and C of one group, without the group axis (``ndim - 1`` dims),
+    with a group axis of 1 before the last; grouped ones as they are."""
+    if B.ndim == ndim - 1:
+        return B[..., None, :], C[..., None, :]
+    return B, C
+
+
 def ssd_chunked(xdt, dA, B, C, chunk: int,
                 initial_state: Optional[jax.Array] = None):
     """SSD scan. xdt: (b,l,h,p) = x*dt; dA: (b,l,h) = dt*A (negative);
-    B, C: (b,l,n). Returns (y (b,l,h,p), final_state (b,h,p,n))."""
+    B, C: (b,l,n) or, in g groups, (b,l,g,n). Returns (y (b,l,h,p),
+    final_state (b,h,p,n))."""
+    B, C = _grouped(B, C, 4)
     b, l_real, h, p = xdt.shape
-    n = B.shape[-1]
+    g, n = B.shape[-2:]
+    j = h // g                                    # heads a group
     # pad to a chunk multiple: trailing zeros in xdt and dA=0 (decay exp(0)=1)
     # leave the recurrence and final state untouched; outputs are sliced.
     l = -(-l_real // chunk) * chunk
@@ -96,66 +119,71 @@ def ssd_chunked(xdt, dA, B, C, chunk: int,
         pad = ((0, 0), (0, l - l_real))
         xdt = jnp.pad(xdt, pad + ((0, 0), (0, 0)))
         dA = jnp.pad(dA, pad + ((0, 0),))
-        B = jnp.pad(B, pad + ((0, 0),))
-        C = jnp.pad(C, pad + ((0, 0),))
+        B = jnp.pad(B, pad + ((0, 0), (0, 0)))
+        C = jnp.pad(C, pad + ((0, 0), (0, 0)))
     nc = l // chunk
-    xc = xdt.reshape(b, nc, chunk, h, p)
-    dAc = dA.reshape(b, nc, chunk, h).transpose(0, 3, 1, 2)   # (b,h,c,Q)
-    Bc = B.reshape(b, nc, chunk, n)
-    Cc = C.reshape(b, nc, chunk, n)
+    xc = xdt.reshape(b, nc, chunk, g, j, p)
+    dAc = dA.reshape(b, nc, chunk, g, j).transpose(0, 3, 4, 1, 2)  # b,g,j,c,Q
+    Bc = B.reshape(b, nc, chunk, g, n)
+    Cc = C.reshape(b, nc, chunk, g, n)
 
-    A_cum = jnp.cumsum(dAc, axis=-1)                           # (b,h,c,Q)
-    L = jnp.exp(_segsum(dAc))                                  # (b,h,c,Q,Q)
+    A_cum = jnp.cumsum(dAc, axis=-1)                           # (b,g,j,c,Q)
+    L = jnp.exp(_segsum(dAc))                                  # (...,c,Q,Q)
 
-    # --- intra-chunk (quadratic, attention-like) ---
-    G = jnp.einsum("bcln,bcsn->bcls", Cc, Bc,
-                   preferred_element_type=jnp.float32)         # (b,c,Q,Q)
-    M = G[:, None] * L                                         # (b,h,c,Q,Q)? no:
-    # G is (b,c,Q,Q); L is (b,h,c,Q,Q) -> broadcast over h
-    M = jnp.einsum("bcls,bhcls->bhcls", G, L)
-    y_diag = jnp.einsum("bhcls,bcshp->bclhp", M.astype(xdt.dtype), xc,
+    # --- intra-chunk (quadratic, attention-like), C.B once per group ---
+    G = jnp.einsum("bclgn,bcsgn->bgcls", Cc, Bc,
+                   preferred_element_type=jnp.float32)         # (b,g,c,Q,Q)
+    M = jnp.einsum("bgcls,bgjcls->bgjcls", G, L)
+    y_diag = jnp.einsum("bgjcls,bcsgjp->bclgjp", M.astype(xdt.dtype), xc,
                         preferred_element_type=jnp.float32)
 
     # --- chunk states ---
-    decay_states = jnp.exp(A_cum[..., -1:] - A_cum)            # (b,h,c,Q)
-    xd = jnp.einsum("bhcl,bclhp->bclhp", decay_states.astype(xdt.dtype), xc)
-    states = jnp.einsum("bcln,bclhp->bchpn", Bc, xd,
-                        preferred_element_type=jnp.float32)    # (b,c,h,p,n)
+    decay_states = jnp.exp(A_cum[..., -1:] - A_cum)            # (b,g,j,c,Q)
+    xd = jnp.einsum("bgjcl,bclgjp->bclgjp", decay_states.astype(xdt.dtype),
+                    xc)
+    states = jnp.einsum("bclgn,bclgjp->bcgjpn", Bc, xd,
+                        preferred_element_type=jnp.float32)    # b,c,g,j,p,n
 
     # --- inter-chunk recurrence (linear scan over chunks) ---
-    chunk_decay = jnp.exp(A_cum[..., -1])                      # (b,h,c)
-    s0 = (jnp.zeros((b, h, p, n), jnp.float32) if initial_state is None
-          else f32(initial_state))
+    chunk_decay = jnp.exp(A_cum[..., -1])                      # (b,g,j,c)
+    s0 = (jnp.zeros((b, g, j, p, n), jnp.float32) if initial_state is None
+          else f32(initial_state).reshape(b, g, j, p, n))
 
     def step(carry, xs):
-        st, dec = xs                                           # (b,h,p,n),(b,h)
+        st, dec = xs                                       # (b,g,j,p,n),(b,g,j)
         new = carry * dec[..., None, None] + st
-        return new, carry                                      # emit entering state
+        return new, carry                                  # emit entering state
 
-    sc = states.transpose(1, 0, 2, 3, 4)                       # (c,b,h,p,n)
-    dc = chunk_decay.transpose(2, 0, 1)                        # (c,b,h)
+    sc = states.transpose(1, 0, 2, 3, 4, 5)                    # (c,b,g,j,p,n)
+    dc = chunk_decay.transpose(3, 0, 1, 2)                     # (c,b,g,j)
     final_state, entering = jax.lax.scan(step, s0, (sc, dc))
-    entering = entering.transpose(1, 0, 2, 3, 4)               # (b,c,h,p,n)
+    entering = entering.transpose(1, 0, 2, 3, 4, 5)            # b,c,g,j,p,n
 
     # --- inter-chunk output ---
-    state_decay = jnp.exp(A_cum)                               # (b,h,c,Q)
-    y_off = jnp.einsum("bcln,bchpn->bclhp", Cc,
+    state_decay = jnp.exp(A_cum)                               # (b,g,j,c,Q)
+    y_off = jnp.einsum("bclgn,bcgjpn->bclgjp", Cc,
                        entering.astype(xdt.dtype),
                        preferred_element_type=jnp.float32)
-    y_off = y_off * state_decay.transpose(0, 2, 3, 1)[..., None]
+    y_off = y_off * state_decay.transpose(0, 3, 4, 1, 2)[..., None]
     y = (y_diag + y_off).reshape(b, l, h, p)[:, :l_real]
-    return y, final_state
+    return y, final_state.reshape(b, h, p, n)
 
 
 def ssd_decode_step(x_t, dt_t, A, B_t, C_t, state):
     """One-token SSD update. x_t: (b,h,p); dt_t: (b,h); A: (h,) negative;
-    B_t, C_t: (b,n); state: (b,h,p,n). Returns (y (b,h,p), state')."""
-    dA = jnp.exp(f32(dt_t) * A)                                # (b,h)
-    xdt = f32(x_t) * f32(dt_t)[..., None]
-    upd = jnp.einsum("bhp,bn->bhpn", xdt, f32(B_t))
-    state = f32(state) * dA[..., None, None] + upd
-    y = jnp.einsum("bhpn,bn->bhp", state, f32(C_t))
-    return y.astype(x_t.dtype), state
+    B_t, C_t: (b,n) or, in g groups, (b,g,n); state: (b,h,p,n). Returns
+    (y (b,h,p), state')."""
+    B_t, C_t = _grouped(B_t, C_t, 3)
+    b, h, p = x_t.shape
+    g, n = B_t.shape[-2:]
+    # elementwise products, not einsums: the update x B is then one fusion
+    # with the decay, and the whole-state outer product is never written
+    dA = jnp.exp(f32(dt_t) * A).reshape(b, g, h // g, 1, 1)
+    xdt = (f32(x_t) * f32(dt_t)[..., None]).reshape(b, g, h // g, p, 1)
+    state = f32(state).reshape(b, g, h // g, p, n) * dA \
+        + xdt * f32(B_t)[:, :, None, None, :]
+    y = jnp.sum(state * f32(C_t)[:, :, None, None, :], axis=-1)
+    return y.reshape(b, h, p).astype(x_t.dtype), state.reshape(b, h, p, n)
 
 
 # ---------------------------------------------------------------------------
@@ -163,81 +191,104 @@ def ssd_decode_step(x_t, dt_t, A, B_t, C_t, state):
 # ---------------------------------------------------------------------------
 
 
+def _gated_out(p, y, z, s: SSMConfig, eps: float, dtype):
+    """RMSNorm over each B/C group's share of the inner width of
+    y * silu(z), then the out-projection (the gate comes before the norm,
+    as in Mamba-2 and Nemotron-H)."""
+    with jax.named_scope("out"):
+        gated = y.astype(dtype) * jax.nn.silu(f32(z)).astype(dtype)
+        gf = f32(gated).reshape(*gated.shape[:-1], s.n_groups, -1)
+        gf = gf * jax.lax.rsqrt(jnp.mean(gf * gf, -1, keepdims=True) + eps)
+        normed = (gf.reshape(gated.shape) * f32(p["norm"]["scale"])
+                  ).astype(dtype)
+        return jnp.einsum("ble,ed->bld", normed, p["w_out"])
+
+
+def _conv_bias(p, k, y):
+    b = p.get(f"conv_bias_{k}")
+    return y if b is None else y + b
+
+
 def ssm_block(p, x, cfg: ModelConfig, shd: ShardingCtx, rcfg, *,
               cache: Optional[Dict] = None, decode: bool = False):
     """x: (B,L,D) (train) or (B,1,D) (decode). Returns (y, cache')."""
+    with jax.named_scope("ssm"):
+        return _ssm(p, x, cfg, cache=cache, decode=decode)
+
+
+def _ssm(p, x, cfg: ModelConfig, *, cache, decode):
     s = cfg.ssm
-    d = cfg.d_model
-    di = s.d_inner(d)
-    nh = s.num_heads(d)
+    nh = s.heads(cfg.d_model)
     hp = s.head_dim
+    w = s.conv_width
     A = -jnp.exp(f32(p["A_log"]))
 
-    z = jnp.einsum("bld,de->ble", x, p["w_z"])
-    xs = jnp.einsum("bld,de->ble", x, p["w_x"])
-    Bs = jnp.einsum("bld,dn->bln", x, p["w_B"])
-    Cs = jnp.einsum("bld,dn->bln", x, p["w_C"])
-    dts = jnp.einsum("bld,dh->blh", x, p["w_dt"])
-    dt = jax.nn.softplus(f32(dts) + f32(p["dt_bias"]))
+    with jax.named_scope("in"):
+        z = jnp.einsum("bld,de->ble", x, p["w_z"])
+        pre = {k: jnp.einsum("bld,de->ble", x, p[f"w_{k}"]) for k in STREAMS}
+        dts = jnp.einsum("bld,dh->blh", x, p["w_dt"])
+        dt = jax.nn.softplus(f32(dts) + f32(p["dt_bias"]))
+
+    def groups(u):                    # (..., G*N) -> (..., G, N)
+        return u.reshape(*u.shape[:-1], s.n_groups, s.state_dim)
 
     if not decode:
-        xs_pre, Bs_pre, Cs_pre = xs, Bs, Cs      # pre-conv streams (cache tails)
-        xs = jax.nn.silu(f32(causal_conv(xs, p["conv_x"]))).astype(x.dtype)
-        Bs = jax.nn.silu(f32(causal_conv(Bs, p["conv_B"]))).astype(x.dtype)
-        Cs = jax.nn.silu(f32(causal_conv(Cs, p["conv_C"]))).astype(x.dtype)
-        xh = xs.reshape(*xs.shape[:2], nh, hp)
-        xdt = (f32(xh) * dt[..., None]).astype(x.dtype)
-        dA = dt * A
-        y, state = ssd_chunked(xdt, dA, Bs, Cs, s.chunk)
-        yD = y + f32(xh) * f32(p["D"])[None, None, :, None]
-        yflat = yD.reshape(*yD.shape[:2], di).astype(x.dtype)
-        gated = yflat * jax.nn.silu(f32(z)).astype(x.dtype)
-        out = jnp.einsum("ble,ed->bld", apply_norm(p["norm"], gated, "rmsnorm"),
-                         p["w_out"])
+        with jax.named_scope("conv"):
+            cv = {k: jax.nn.silu(f32(_conv_bias(p, k, causal_conv(
+                u, p[f"conv_{k}"])))).astype(x.dtype)
+                for k, u in pre.items()}
+        with jax.named_scope("scan"):
+            xh = cv["x"].reshape(*x.shape[:2], nh, hp)
+            xdt = (f32(xh) * dt[..., None]).astype(x.dtype)
+            y, state = ssd_chunked(xdt, dt * A, groups(cv["B"]),
+                                   groups(cv["C"]), s.chunk)
+            y = y + f32(xh) * f32(p["D"])[None, None, :, None]
+        out = _gated_out(p, y.reshape(*x.shape[:2], -1), z, s, cfg.norm_eps,
+                         x.dtype)
         new_cache = None
         if cache is not None:
-            # preload conv tails (pre-conv streams) for streaming continuation
-            w = s.conv_width
-            new_cache = {
-                "state": state.astype(cache["state"].dtype),
-                "conv_x": xs_pre[:, -(w - 1):].astype(cache["conv_x"].dtype),
-                "conv_B": Bs_pre[:, -(w - 1):].astype(cache["conv_B"].dtype),
-                "conv_C": Cs_pre[:, -(w - 1):].astype(cache["conv_C"].dtype),
-            }
+            # the last w-1 pre-conv inputs of each stream, for the streaming
+            # conv to continue from; a prompt shorter than that is preceded
+            # by zeros, as the conv itself pads it
+            new_cache = {"state": state.astype(cache["state"].dtype)}
+            for k, u in pre.items():
+                tail = jnp.pad(u, ((0, 0), (w - 1, 0), (0, 0)))[:, -(w - 1):]
+                new_cache[f"conv_{k}"] = tail.astype(cache[f"conv_{k}"].dtype)
         return out, new_cache
 
     # ---- decode ----
     assert cache is not None
-    xc, cx = conv_step(xs[:, 0], cache["conv_x"].astype(x.dtype), p["conv_x"])
-    Bc, cB = conv_step(Bs[:, 0], cache["conv_B"].astype(x.dtype), p["conv_B"])
-    Cc, cC = conv_step(Cs[:, 0], cache["conv_C"].astype(x.dtype), p["conv_C"])
-    xc = jax.nn.silu(f32(xc)).astype(x.dtype)
-    Bc = jax.nn.silu(f32(Bc)).astype(x.dtype)
-    Cc = jax.nn.silu(f32(Cc)).astype(x.dtype)
-    xh = xc.reshape(-1, nh, hp)
-    y, state = ssd_decode_step(xh, dt[:, 0], A, Bc, Cc,
-                               f32(cache["state"]))
-    y = y + f32(xh).astype(x.dtype) * f32(p["D"])[None, :, None].astype(x.dtype)
-    yflat = y.reshape(-1, 1, di)
-    gated = yflat * jax.nn.silu(f32(z)).astype(x.dtype)
-    out = jnp.einsum("ble,ed->bld", apply_norm(p["norm"], gated, "rmsnorm"),
-                     p["w_out"])
-    new_cache = {"state": state.astype(cache["state"].dtype),
-                 "conv_x": cx.astype(cache["conv_x"].dtype),
-                 "conv_B": cB.astype(cache["conv_B"].dtype),
-                 "conv_C": cC.astype(cache["conv_C"].dtype)}
+    new_cache = {}
+    with jax.named_scope("conv"):
+        cv = {}
+        for k, u in pre.items():
+            y_k, new_cache[f"conv_{k}"] = conv_step(
+                u[:, 0], cache[f"conv_{k}"].astype(x.dtype), p[f"conv_{k}"])
+            cv[k] = jax.nn.silu(f32(_conv_bias(p, k, y_k))).astype(x.dtype)
+            new_cache[f"conv_{k}"] = new_cache[f"conv_{k}"].astype(
+                cache[f"conv_{k}"].dtype)
+    with jax.named_scope("update"):
+        xh = cv["x"].reshape(-1, nh, hp)
+        y, state = ssd_decode_step(xh, dt[:, 0], A, groups(cv["B"]),
+                                   groups(cv["C"]), f32(cache["state"]))
+        y = y + xh * f32(p["D"])[None, :, None].astype(x.dtype)
+        new_cache["state"] = state.astype(cache["state"].dtype)
+    out = _gated_out(p, y.reshape(-1, 1, nh * hp), z, s, cfg.norm_eps,
+                     x.dtype)
     return out, new_cache
 
 
 def ssm_cache_schema(cfg: ModelConfig, batch: int, dtype: str) -> Dict:
+    """A layer's recurrent state (f32) and the conv's tails: the last
+    ``conv_width - 1`` pre-conv inputs of each stream, in ``dtype``."""
     s = cfg.ssm
-    di = s.d_inner(cfg.d_model)
-    nh = s.num_heads(cfg.d_model)
     w = s.conv_width
-    return {
-        "state": ParamDesc((batch, nh, s.head_dim, s.state_dim),
-                           ("batch", "ssm_heads", None, None), "float32", "zeros"),
-        "conv_x": ParamDesc((batch, w - 1, di), ("batch", None, "ffn"), dtype, "zeros"),
-        "conv_B": ParamDesc((batch, w - 1, s.state_dim), ("batch", None, None), dtype, "zeros"),
-        "conv_C": ParamDesc((batch, w - 1, s.state_dim), ("batch", None, None), dtype, "zeros"),
-    }
+    out = {"state": ParamDesc((batch, s.heads(cfg.d_model), s.head_dim,
+                               s.state_dim),
+                              ("batch", "ssm_heads", None, None), "float32",
+                              "zeros")}
+    for k, c in _widths(cfg).items():
+        out[f"conv_{k}"] = ParamDesc((batch, w - 1, c),
+                                     ("batch", None, "ffn" if k == "x"
+                                      else None), dtype, "zeros")
+    return out

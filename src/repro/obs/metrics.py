@@ -106,6 +106,9 @@ METRIC_HELP: Dict[str, str] = {
         "Routed assignments that land on a held expert",
     "nk_moe_experts_touched_total":
         "(program, layer, held expert) triples with at least one token",
+    "nk_ssm_layers": "Layers that keep a recurrent (SSM) state in the cache",
+    "nk_ssm_state_bytes":
+        "Bytes of SSM state and conv tails the engine's cache holds",
     "nk_placement_ticks_total": "Placement autopilot ticks",
     "nk_placement_plans_applied_total": "Non-empty placement plans applied",
     "nk_placement_moves_total": "Autopilot migrations applied",

@@ -118,10 +118,18 @@ class ServeEngine(SchedulerServeModule):
         # cache segments whose new K/V rows the decode program writes in
         # place (the rest take the masked select): fixed by the model and
         # the mesh, so counted once here
+        schema = cache_schema(cfg, batch_slots, max_seq)
         self.decode_inplace_segments = sum(
             decode_writes_in_place(seg, c, self.shd, rcfg)
-            for seg, c in zip(build_schedule(cfg),
-                              cache_schema(cfg, batch_slots, max_seq)))
+            for seg, c in zip(build_schedule(cfg), schema))
+        # layers that keep a recurrent state in the cache, and the bytes of
+        # it: the f32 state and the conv tails, every slot
+        ssm = [c for c in schema if c is not None and "state" in c]
+        self.ssm_layers = sum(c["state"].shape[0] for c in ssm)
+        self.ssm_state_bytes = sum(
+            int(np.prod(d.shape)) * np.dtype(d.dtype).itemsize
+            for c in ssm for k, d in c.items()
+            if k == "state" or k.startswith("conv_"))
         # layers whose decode attends with the flash-decode kernel, on the
         # platform the programs lower for; and the K blocks it has read and
         # that a read of every position would have taken (V reads as
@@ -215,7 +223,8 @@ class ServeEngine(SchedulerServeModule):
         self.caches = None
 
     def counters(self) -> Dict[str, float]:
-        """The decode program's static facts, the cache blocks its
+        """The decode program's static facts, the SSM state the cache
+        holds, the cache blocks its
         attention kernel has read so far and, for an expert layer that
         holds a share, the routed counts of every prefill and decode so
         far (Prometheus naming):
@@ -235,6 +244,9 @@ class ServeEngine(SchedulerServeModule):
         if self.decode_kernel_layers:
             out["nk_decode_kv_blocks_read_total"] = float(self._kv_blocks[0])
             out["nk_decode_kv_blocks_total"] = float(self._kv_blocks[1])
+        if self.ssm_layers:
+            out["nk_ssm_layers"] = float(self.ssm_layers)
+            out["nk_ssm_state_bytes"] = float(self.ssm_state_bytes)
         if self.counts_routing:
             self._routed_total += np.asarray(self._routed)
             self._routed = self._zero_counts()

@@ -5,8 +5,9 @@ not attached: shapes only, nothing runs. The tests pin what the serve path,
 the control tick, the NSM stacks and the Pallas kernels need to lower for
 the chip at real widths: internlm2-1.8b's decode and prefill within one
 chip's 16 GiB, DeepSeek-V2's decode at one chip's share with its latent
-cache written in place, the engine's install of a prefill cache into its
-slot in place for both, the fused tick at 100k tenants, every psum routing
+cache written in place, Nemotron-3-Nano's decode at one chip's share with
+each Mamba-2 block's f32 state updated in one pass, the engine's install of
+a prefill cache into its slot in place for all three, the fused tick at 100k tenants, every psum routing
 policy on a (pod=2, data=2) mesh, and each kernel as a Mosaic
 ``tpu_custom_call``.
 
@@ -230,6 +231,76 @@ def test_deepseek_v2_install_writes_the_slot_in_place(deepseek_v2, one_chip):
     compiled = _install(cfg, one_chip, caches)
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
     assert _stack_copies(compiled, caches) == []
+
+
+@pytest.fixture(scope="module")
+def nemotron_h(one_chip):
+    """Nemotron-3-Nano at one chip's share (its first 20 blocks, 16 of the
+    router's 128 experts, the benchmark's cut) and its cache of 256 slots
+    x 2048: 9 mixers' f32 state and conv tails, 3 attention layers' K/V."""
+    import dataclasses
+    from repro.configs import RunConfig, get_config
+    from repro.distribution.sharding import ShardingCtx, abstract_params
+    from repro.models.model import model_schema
+    cfg = get_config("nemotron-3-nano-30b-a3b")
+    cfg = dataclasses.replace(cfg, num_layers=20, moe=dataclasses.replace(
+        cfg.moe, num_experts=16))
+    shd, rcfg = ShardingCtx(one_chip), RunConfig()
+    params = _on(one_chip, abstract_params(model_schema(cfg, one_chip)))
+    return cfg, rcfg, shd, params, _caches(cfg, one_chip, 256)
+
+
+# the decode's temp at 256 slots: 62,368,768 bytes compiled for a described
+# v5e; one layer's whole state held apart from the cache (the outer product
+# x B written out before the decay adds it: 536,870,912 bytes, as an einsum
+# made it) would put it far above
+NEMOTRON_DECODE_TEMP = 96 << 20
+
+
+def test_nemotron_h_decode_updates_the_state_in_one_pass(nemotron_h,
+                                                         one_chip):
+    """The decode fits the chip; each mixer's state is read and written
+    once, with no temp the size of a layer's state and no copy of a whole
+    cache leaf; the three attention layers attend with the flash-decode
+    kernel (2 KV heads, 16 query heads each)."""
+    import jax
+    import jax.numpy as jnp
+    from repro.models.model import forward_decode
+    cfg, rcfg, shd, params, caches = nemotron_h
+    slots = jax.tree.leaves(caches)[0].shape[1]
+    tokens, pos = _on(one_chip, (
+        jax.ShapeDtypeStruct((slots, 1), jnp.int32),
+        jax.ShapeDtypeStruct((slots,), jnp.int32)))
+
+    def step(params, caches, tokens, pos):
+        logits, caches, counts = forward_decode(
+            params, caches, tokens, pos, cfg, shd, rcfg, return_counts=True)
+        return jnp.argmax(logits, axis=-1), caches, counts
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, caches, tokens, pos).compile()
+    assert _device_bytes(compiled) < V5E_HBM
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < NEMOTRON_DECODE_TEMP
+    assert _stack_copies(compiled, caches) == []
+    assert compiled.as_text().count("tpu_custom_call") == 3
+
+
+def test_nemotron_h_prefill_and_install(nemotron_h, one_chip):
+    """A 512-token prefill (four SSD chunks of 128) fits beside the
+    weights, and its state, conv tails and K/V go into a slot of the
+    donated 256-slot cache in place."""
+    import jax
+    import jax.numpy as jnp
+    from repro.models.model import forward_prefill
+    cfg, rcfg, shd, params, caches = nemotron_h
+    tokens = _on(one_chip, jax.ShapeDtypeStruct((1, 512), jnp.int32))
+    compiled = jax.jit(lambda p, t: forward_prefill(
+        p, t, cfg, shd, rcfg, max_seq=2048, return_counts=True)).lower(
+        params, tokens).compile()
+    assert _device_bytes(compiled) < V5E_HBM
+    installed = _install(cfg, one_chip, caches)
+    assert installed.memory_analysis().temp_size_in_bytes < 64 << 20
+    assert _stack_copies(installed, caches) == []
 
 
 def test_internlm2_prefill_fits_one_chip(internlm2, one_chip):

@@ -201,14 +201,16 @@ def test_model_sharded_decode_keeps_masked_select(name, mesh1, rcfg_small):
 
 @pytest.mark.parametrize("name,layers", [
     ("internlm2-1.8b", 24), ("arctic-480b", 35), ("deepseek-v2-236b", 0),
-    ("hymba-1.5b", 0), ("whisper-small", 0), ("mamba2-370m", 0)])
+    ("hymba-1.5b", 0), ("whisper-small", 0), ("mamba2-370m", 0),
+    ("nemotron-3-nano-30b-a3b", 6)])
 def test_decode_kernel_layers(name, layers):
     """On a TPU the flash-decode kernel runs in every layer whose segment
     writes a stacked K/V cache in place (dense and expert GQA); latent
     attention, ring windows beside SSM state, cross-attention and SSM
     state keep the jnp path, as does every layer on the CPU, on a mesh
     that shards the cache's sequence or its slots, and on a cache not made
-    of whole blocks."""
+    of whole blocks. A layer pattern counts its attention blocks (6 of
+    Nemotron-3-Nano's 52), not its Mamba-2 or expert blocks."""
     from repro.configs import get_config
     from repro.launch.mesh import make_host_mesh
     from repro.models.model import decode_kernel_layers
